@@ -17,6 +17,7 @@ from benchmarks.common import (
     facade_config,
     load_datasets,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.api import OverlapIndex
 
 
@@ -49,4 +50,5 @@ def run(full: bool = False, out: dict | None = None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import Timer, emit
+from repro.compile_cache import enable_compile_cache
 from repro.api import Config, IndexConfig, OverlapIndex
 from repro.data.synthetic import embedding_datastore
 from repro.kernels import ops as kops
@@ -65,4 +66,5 @@ def run(full: bool = False, out: dict | None = None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -58,6 +58,7 @@ from benchmarks.common import (
     record,
     write_artifact,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.api import OverlapIndex
 from repro.core import knn_exact
 
@@ -277,5 +278,6 @@ if __name__ == "__main__":
                     help="disable the telemetry registry (repro.obs) — for "
                     "measuring the metrics layer's own overhead")
     a = ap.parse_args()
+    enable_compile_cache()
     run(full=a.full, kernel=not a.no_kernel, quantize=a.quantize,
         smoke=a.smoke, shards=a.shards, route=a.route, obs=not a.no_obs)

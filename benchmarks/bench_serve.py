@@ -52,6 +52,7 @@ import time
 import numpy as np
 
 from benchmarks.common import emit, record, write_artifact
+from repro.compile_cache import enable_compile_cache
 
 # the sweep: offered QPS as multiples of measured capacity; >= SHED_BOUND
 # ratios are past the knee, where the two modes must diverge
@@ -307,4 +308,5 @@ if __name__ == "__main__":
                     help="JSONL span log for trace-sampled requests "
                     "(default: $REPRO_OBS_EVENTS)")
     a = ap.parse_args()
+    enable_compile_cache()
     raise SystemExit(run(smoke=a.smoke, events=a.events or None))

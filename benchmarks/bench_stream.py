@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import Timer, emit, record, write_artifact
+from repro.compile_cache import enable_compile_cache
 from repro.api import Config, IndexConfig, OverlapIndex, StreamConfig
 from repro.core import knn_exact
 
@@ -164,4 +165,5 @@ if __name__ == "__main__":
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="CI-sized run")
+    enable_compile_cache()
     run(smoke=ap.parse_args().smoke)

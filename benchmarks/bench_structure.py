@@ -9,6 +9,7 @@ import time
 import numpy as np
 
 from benchmarks.common import METHODS, emit, facade_config, load_datasets
+from repro.compile_cache import enable_compile_cache
 from repro.api import OverlapIndex
 
 
@@ -41,4 +42,5 @@ def run(full: bool = False, out: dict | None = None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -20,6 +20,8 @@ from pathlib import Path
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 from benchmarks import (  # noqa: E402
     bench_construction,
     bench_retrieval,
@@ -47,12 +49,10 @@ def main() -> None:
     results: dict[str, dict] = {}
     suites = {args.only: SUITES[args.only]} if args.only else SUITES
     print("name,us_per_call,derived")
+    enable_compile_cache()
     for name, fn in suites.items():
         out: dict = {}
-        try:
-            fn(full=args.full, out=out)
-        except Exception as e:  # keep the harness running
-            print(f"{name}/ERROR,0.00,{type(e).__name__}: {e}")
+        fn(full=args.full, out=out)  # a failing suite fails the run
         results[name] = out
     path = Path(args.json_out)
     path.parent.mkdir(parents=True, exist_ok=True)

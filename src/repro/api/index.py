@@ -436,9 +436,12 @@ class OverlapIndex:
                 d, i, s, isl, rows = outs[:5]
                 router = outs[5] if len(outs) > 5 else None
                 # home = the routed index, computed with the DEVICE routing
-                # op (same kernel flag) so tie-breaks match the executor
+                # op (same kernel flag) so tie-breaks match the executor —
+                # on one device: the compiler cannot partition a Pallas call
+                # over the sharded layouts' replicated centers
                 _, home = route_points(
-                    self.device.index_centers, qj, kernel=key.kernel
+                    jnp.asarray(self.forest.index_centers), qj,
+                    kernel=key.kernel,
                 )
             with obs.span("host_transfer"):
                 d, i = np.asarray(d), np.asarray(i)

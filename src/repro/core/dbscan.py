@@ -32,8 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.metric import _pairwise_sq_l2_jnp
 from repro.kernels import ops
+from repro.kernels.ref import pairwise_sq_l2_ref
 
 Array = jax.Array
 
@@ -76,7 +76,7 @@ def _dbscan_device(
     def _count_body(_, ib):
         if kernel:
             return None, ops.eps_count(_block_rows(ib), x, eps_sq)
-        d = _pairwise_sq_l2_jnp(_block_rows(ib), x)
+        d = pairwise_sq_l2_ref(_block_rows(ib), x)
         return None, jnp.sum(d <= eps_sq, axis=1)
 
     _, counts = jax.lax.scan(_count_body, None, jnp.arange(nb))
@@ -92,7 +92,7 @@ def _dbscan_device(
                 return None, ops.eps_min_label(
                     _block_rows(ib), x, labels, core, eps_sq
                 )
-            d = _pairwise_sq_l2_jnp(_block_rows(ib), x)
+            d = pairwise_sq_l2_ref(_block_rows(ib), x)
             adj = (d <= eps_sq) & core[None, :]
             cand = jnp.where(adj, labels[None, :], sentinel)
             return None, jnp.min(cand, axis=1)
@@ -125,7 +125,7 @@ def _dbscan_device(
         if kernel:
             dmin, lab = ops.eps_nearest_core(_block_rows(ib), x, labels, core)
             return None, jnp.where(dmin <= eps_sq, lab, sentinel)
-        d = _pairwise_sq_l2_jnp(_block_rows(ib), x)
+        d = pairwise_sq_l2_ref(_block_rows(ib), x)
         d = jnp.where(core[None, :], d, jnp.inf)
         j = jnp.argmin(d, axis=1)
         dmin = jnp.take_along_axis(d, j[:, None], axis=1)[:, 0]

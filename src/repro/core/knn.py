@@ -38,12 +38,15 @@ exactness — the main phase merely prunes against a k-th best that ignores
 delta members (visits a superset), and the delta phase prunes against the
 true running k-th best.
 
-Under-filled selections: when the selected indexes hold fewer than k
-objects, the k-th best distance stays +inf and the bounded scan naturally
-SPILLS into the next-nearest non-selected buckets until k answers exist —
-matching the paper's §4.3 intent ("particularly when the required number
-of objects has not yet been reached").  The exact-within-selection
-contract therefore applies when the selection holds >= k objects.
+Under-filled selections: when the selected indexes (forest members plus
+delta members) hold fewer than k objects, the query's selection widens to
+every index (``widen_underfilled``), so it gets the exact global answer —
+the paper's §4.3 intent of searching on "particularly when the required
+number of objects has not yet been reached".  The scan visits only buckets
+with a finite lower bound, i.e. selected ones.  Both rules read the
+GLOBAL selection, so a sharded executor, whose shards may hold few or
+none of a query's selected buckets, visits what the single-device one
+visits and returns the same answer.
 """
 from __future__ import annotations
 
@@ -245,16 +248,17 @@ def _scan_phase(
     main phase's result and keeps merging into the same (Q, kk) state.
 
     ``qmask`` (Q,) bool — optional per-query kill switch: a False query
-    visits NOTHING in this phase (not even the +inf-bound spill that an
-    empty carry would otherwise trigger).  The routed layout uses it to
-    turn a pruned (query, host) pair into genuine zero work on that host;
+    visits NOTHING in this phase.  The routed layout uses it to turn a
+    pruned (query, host) pair into genuine zero work on that host;
     ``None`` (every other caller) compiles to the unmasked predicate.
     """
 
     def active_mask(c: _Carry) -> Array:
         kth = jnp.sqrt(c.top_d[:, -1])  # inf until kk found
         cur_lb = jax.lax.dynamic_slice_in_dim(lb_sorted, c.t * beam, beam, axis=1)
-        act = cur_lb <= kth[:, None]  # (Q, beam)
+        # +inf bounds are unselected rows: never visited, even while the
+        # carry is short of kk (``widen_underfilled`` owns that case)
+        act = (cur_lb <= kth[:, None]) & jnp.isfinite(cur_lb)  # (Q, beam)
         if qmask is not None:
             act = act & qmask[:, None]
         return act
@@ -307,6 +311,27 @@ def route_select(
     else:
         raise ValueError(f"mode {mode!r}")
     return sel, route_dists, route_cmps
+
+
+def index_members(forest: DeviceForest, delta: DeltaView | None = None) -> Array:
+    """(I,) i32 live objects per index over the bucket rows (and delta
+    rows, one per index) this executor holds.  Rows owned by the sharded
+    layout's sentinel index I (alignment padding) count nowhere."""
+    n_idx = forest.index_centers.shape[0]
+    per_bucket = jnp.sum(forest.bucket_mask, axis=1, dtype=jnp.int32)
+    counts = jax.ops.segment_sum(
+        per_bucket, forest.bucket_index, num_segments=n_idx + 1
+    )[:n_idx]
+    if delta is not None:
+        counts = counts + jnp.sum(delta.mask, axis=1, dtype=jnp.int32)
+    return counts
+
+
+def widen_underfilled(sel: Array, members: Array, kk: int) -> Array:
+    """(Q, I) selection with every index selected for the queries whose
+    selection holds fewer than ``kk`` of the ``members`` (I,) objects."""
+    held = jnp.sum(jnp.where(sel, members[None, :], 0), axis=1)
+    return sel | (held < kk)[:, None]
 
 
 class PhaseBounds(NamedTuple):
@@ -503,8 +528,7 @@ def merge_shard_topk(
     """
     d_all = jax.lax.all_gather(top_d, axis_name, axis=1, tiled=True)  # (Q, S*k)
     i_all = jax.lax.all_gather(top_i, axis_name, axis=1, tiled=True)
-    neg, pos = jax.lax.top_k(-d_all, k)
-    return -neg, jnp.take_along_axis(i_all, pos, axis=1)
+    return kref.topk_by_distance_then_id(d_all, i_all, k)
 
 
 def scan_stats(
@@ -568,6 +592,7 @@ def knn_search_impl(
     kk = min(k, n_cap)
 
     sel, route_dists, route_cmps = route_select(forest, q, mode=mode, kernel=kernel)
+    sel = widen_underfilled(sel, index_members(forest, delta), kk)
     out = local_scan(
         forest, q, sel, kk=kk, beam=beam, kernel=kernel,
         delta=delta, delta_sel=sel,
@@ -630,6 +655,7 @@ def knn_search_explain_impl(
     kk = min(k, n_cap)
 
     sel, route_dists, route_cmps = route_select(forest, q, mode=mode, kernel=kernel)
+    sel = widen_underfilled(sel, index_members(forest, delta), kk)
     bounds = bucket_bounds(forest, q, sel, beam=beam, kernel=kernel)
     dbounds = None
     if delta is not None:

@@ -12,6 +12,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
+from repro.kernels import ref as kref
+
 Array = jax.Array
 
 # ---------------------------------------------------------------------------
@@ -65,28 +68,17 @@ def pairwise(q: Array, x: Array, *, metric: str = "l2", use_kernel: bool = True)
     """
     if metric in ("l2", "sq_l2"):
         if use_kernel:
-            # Deferred import: kernels depend on core for oracle definitions.
-            from repro.kernels import ops as kops
-
             sq = kops.pairwise_sq_l2(q, x)
         else:
-            sq = _pairwise_sq_l2_jnp(q, x)
+            sq = kref.pairwise_sq_l2_ref(q, x)
         return sq if metric == "sq_l2" else jnp.sqrt(jnp.maximum(sq, 0.0))
     if metric == "l1":
         return jnp.sum(jnp.abs(q[:, None, :] - x[None, :, :]), axis=-1)
     if metric == "cosine":
         qn = q / (jnp.linalg.norm(q, axis=-1, keepdims=True) + 1e-12)
         xn = x / (jnp.linalg.norm(x, axis=-1, keepdims=True) + 1e-12)
-        return 1.0 - qn @ xn.T
+        return 1.0 - jnp.matmul(qn, xn.T, precision=jax.lax.Precision.HIGHEST)
     raise ValueError(f"unknown metric {metric!r}")
-
-
-def _pairwise_sq_l2_jnp(q: Array, x: Array) -> Array:
-    """||q||^2 + ||x||^2 - 2 q.x — the expansion the MXU kernel implements."""
-    qq = jnp.sum(q * q, axis=-1)[:, None]
-    xx = jnp.sum(x * x, axis=-1)[None, :]
-    cross = q @ x.T
-    return jnp.maximum(qq + xx - 2.0 * cross, 0.0)
 
 
 def distances_to_point(x: Array, p: Array, *, metric: str = "l2") -> Array:

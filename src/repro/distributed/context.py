@@ -9,26 +9,7 @@ from __future__ import annotations
 import contextlib
 from typing import Iterator
 
-import jax
 from jax.sharding import Mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Covers all three API generations: experimental-only (pre-promotion,
-    ``check_rep``), top-level with ``check_rep`` (transition window), and
-    top-level with ``check_vma``.
-    """
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    params = inspect.signature(impl).parameters
-    kw = {"check_vma" if "check_vma" in params else "check_rep": check_vma}
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 _ACTIVE: list[Mesh | None] = [None]
 

@@ -119,8 +119,9 @@ def sharded_search(
     be separate because XLA's SPMD partitioner miscompiles a sort whose
     result feeds a ``while_loop`` inside the same manually-sharded region
     under an outer ``jit`` (shards silently read other queries' visit
-    orders; jax 0.4.x CPU).  Crossing an island boundary turns the sorted
-    order into an ordinary sharded operand, which partitions correctly —
+    orders; seen on the CPU backend).  Crossing an island boundary turns
+    the sorted order into an ordinary sharded operand, which partitions
+    correctly —
     and the split costs nothing: both islands fuse into the same jitted
     executable, and the eager path runs the same ops ``local_scan`` runs.
 
@@ -159,6 +160,18 @@ def sharded_search(
         sel, route_d, route_c = cknn.route_select(
             forest_l, q_l, mode=mode, kernel=kernel
         )
+        # under-filled selections widen on the GLOBAL member count, exactly
+        # as the single-device executor decides (core.knn.widen_underfilled)
+        members = cknn.index_members(forest_l)
+        if delta_l is not None:
+            i_l = delta_l.x.shape[0]
+            owned = jax.lax.dynamic_update_slice_in_dim(
+                jnp.zeros((S * i_l,), jnp.int32),
+                jnp.sum(delta_l.mask, axis=1, dtype=jnp.int32),
+                jax.lax.axis_index(axis) * i_l, axis=0,
+            )
+            members = members + owned[:n_idx]
+        sel = cknn.widen_underfilled(sel, jax.lax.psum(members, axis), kk)
         if hs_l is not None:
             # routing tier: this shard bounds/scans only the queries that
             # elected it — (Q, 1) local column broadcast over the I indexes.
@@ -223,7 +236,7 @@ def sharded_search(
     bounds_out = (row, row, col, col, row)
     if have_delta:
         bounds_out += (col, col, row)
-    bounds_fn = dctx.shard_map(
+    bounds_fn = jax.shard_map(
         bounds_island,
         mesh=mesh,
         in_specs=(fspec, P(), dspec, hspec),
@@ -234,7 +247,7 @@ def sharded_search(
     if explain:
         per_island = True
         scan_out += (row,)
-    scan_fn = dctx.shard_map(
+    scan_fn = jax.shard_map(
         scan_island,
         mesh=mesh,
         in_specs=(fspec, P(), dspec, col, col,
@@ -319,7 +332,7 @@ def sharded_ingest(
         return new_delta, acc_any
 
     dspec = delta_buffer_specs(axis)
-    fn = dctx.shard_map(
+    fn = jax.shard_map(
         island,
         mesh=mesh,
         in_specs=(P(), dspec, P(), P(), P()),

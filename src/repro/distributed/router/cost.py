@@ -12,10 +12,10 @@ not just the merge:
   bounds   each participating host bounds its non-empty buckets of the
            query's selected indexes (one D-dim pivot read per bound —
            the paper's ``bound_distances`` counter, in bytes).
-  scan     expected member distances: the selected members the host owns —
-           floored at min(kk, host size), because a participating host's
-           bounded scan spills until its carry holds kk candidates even
-           when the query selected nothing it owns.
+  scan     expected member distances: the selected members the host owns
+           (a host scans selected buckets only; a query whose selection
+           holds fewer than kk members selects everything, and then the
+           pruning rule prunes nothing).
   router   targeted dispatch additionally pays the routing tier itself
            (distance rows to S host centers and I delta pivots), which the
            homogeneous path never computes — so when pruning saves
@@ -68,10 +68,7 @@ def price_dispatch(
     # per-(query, host) work if the host participates
     b_qh = sel_f @ table.nbuckets_hi.T.astype(jnp.float32)  # bound evals
     m_qh = sel_f @ table.count_hi.T.astype(jnp.float32)  # selected members
-    spill = jnp.minimum(
-        jnp.float32(kk), table.host_counts.astype(jnp.float32)
-    )  # (S,) scan floor: a participating host fills its kk-carry regardless
-    work_qh = (n_idx + b_qh + jnp.maximum(m_qh, spill[None])) * vec_bytes
+    work_qh = (n_idx + b_qh + m_qh) * vec_bytes
     work_t = jnp.sum(jnp.where(elig, work_qh, 0.0))
     work_a = jnp.sum(work_qh)
 

@@ -23,6 +23,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import knn as cknn
 from repro.core.metric import pairwise
@@ -75,9 +76,24 @@ def routed_search(
         n_cap += delta.x.shape[0] * delta.x.shape[1]
     kk = min(k, n_cap)
 
-    d_sq, _ = cknn.route_points(forest.index_centers, q, kernel=kernel)
-    d_center = jnp.sqrt(d_sq)
-    sel, _, _ = cknn.route_select(forest, q, mode=mode, kernel=kernel)
+    def routing_island(forest_l, q_l):
+        d_sq_l, _ = cknn.route_points(forest_l.index_centers, q_l, kernel=kernel)
+        sel_l, _, _ = cknn.route_select(forest_l, q_l, mode=mode, kernel=kernel)
+        return d_sq_l[None], sel_l[None]
+
+    # the routing kernels run per shard, as in the islands (the compiler
+    # cannot partition a Pallas call); every shard computes the same
+    # replicated rows and shard 0's copy is read — the very selection the
+    # bounds island derives
+    d_sq, sel = jax.shard_map(
+        routing_island,
+        mesh=mesh,
+        in_specs=(knn_island.forest_specs(forest, axis), P()),
+        out_specs=(P(axis), P(axis)),
+        check_vma=False,
+    )(forest, q)
+    d_center = jnp.sqrt(d_sq[0])
+    sel = sel[0]
     d_host = pairwise(q, table.host_centers, metric="l2", use_kernel=False)
     dkw = {}
     if delta is not None:

@@ -19,16 +19,16 @@ metric lower bounds, adapted to forest-mode selection):
                 member count first reaches ``kk``: at least ``kk`` selected
                 members lie within ``ub_sel``, so the merged kth-best
                 distance cannot exceed it.  Fewer than ``kk`` selected
-                members total -> ``+inf`` (nothing is pruned; the scan's
-                underfill spill may reach anything).
+                members total -> ``+inf`` (nothing is pruned; the query's
+                selection widens to every index).
   lower bound   Per host, the selection-INDEPENDENT floor over everything
                 the host could ever contribute — ``d(q, host_center) -
                 host_radius`` for its forest members and ``d(q,
                 delta_pivot_i) - delta_radius_i`` over its owned non-empty
                 delta rows (delta radii are dynamic, so they fold in here
                 rather than being baked into the table).  Selection
-                independence matters: an underfilled scan spills into
-                non-selected buckets, and those members must still be
+                independence matters: an underfilled query's selection
+                widens to every index, and those members must still be
                 covered by the bound.
 
 A host is pruned iff its lower bound strictly exceeds ``ub_sel`` plus a

@@ -7,11 +7,11 @@ matrices.  On TPU this is an MXU matmul plus rank-1 norm updates:
     d2[i, j] = ||q_i||^2 + ||x_j||^2 - 2 <q_i, x_j>
 
 Grid: (Q/bq, N/bn, D/bd) with accumulation over the contraction axis (last
-grid dimension; same output block revisited, ``dimension_semantics``
-marks it "arbitrary" on real TPU).  Per-step VMEM working set is
-``bq*bd + bn*bd + bq*bn`` f32 — defaults (256, 256, 256) give 768 KB,
-comfortably inside the ~16 MB v5e VMEM while keeping MXU tiles
-128-aligned.
+grid dimension; same output block revisited, so ``dimension_semantics``
+marks it "arbitrary" and the two output axes "parallel").  Per-step VMEM
+working set is ``bq*bd + bn*bd + bq*bn`` f32 — defaults (256, 256, 256)
+give 768 KB, comfortably inside the ~16 MB v5e VMEM while keeping MXU
+tiles 128-aligned.
 
 The int8 variant dequantizes the datastore tile in-register (per-row scale),
 halving (vs bf16) or quartering (vs f32) the HBM traffic of a datastore
@@ -22,7 +22,13 @@ The ``eps_*`` kernels below fuse DBSCAN's eps-neighbor-graph reductions
 the same tiled distance stream: grid (Q/bq, N/bn) with D whole inside the
 block (padded to 128) and the N axis sequential over a (bq, 1)-shaped
 running output, so the per-query distance row is thresholded/reduced
-in-register and the (Q, N) block never reaches HBM.
+in-register and the (Q, N) block never reaches HBM.  Per-point operands
+along N (labels, core flags, int8 scales) travel as lane-major (1, N) rows
+in (1, bn) blocks.
+
+Every contraction runs at ``Precision.HIGHEST``: the chip's default f32
+matmul takes bf16 passes, too coarse for distances that decide eps
+membership, pruning bounds and exact top-k answers.
 """
 from __future__ import annotations
 
@@ -31,8 +37,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+# (Q, N, D) grid: output tiles are independent, D accumulates into them
+_ACCUMULATE_D = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
+# (Q, N) grid of the eps kernels: N reduces into a (bq, 1) running output
+_REDUCE_N = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def _cross(q, x):
+    """(bq, D) x (bn, D) -> (bq, bn) inner products on the MXU in f32."""
+    return jax.lax.dot_general(
+        q, x, (((1,), (1,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
 
 
 def _pairwise_kernel(q_ref, x_ref, o_ref):
@@ -47,10 +70,7 @@ def _pairwise_kernel(q_ref, x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)  # (bn, bd)
     qq = jnp.sum(q * q, axis=1)  # (bq,)
     xx = jnp.sum(x * x, axis=1)  # (bn,)
-    cross = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (bq, bn)
-    o_ref[...] += qq[:, None] + xx[None, :] - 2.0 * cross
+    o_ref[...] += qq[:, None] + xx[None, :] - 2.0 * _cross(q, x)
 
 
 def _pairwise_int8_kernel(q_ref, x_ref, scale_ref, o_ref):
@@ -61,13 +81,10 @@ def _pairwise_int8_kernel(q_ref, x_ref, scale_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = q_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32) * scale_ref[...].astype(jnp.float32)[:, None]
+    x = x_ref[...].astype(jnp.float32) * scale_ref[...].T  # (1, bn) row -> column
     qq = jnp.sum(q * q, axis=1)
     xx = jnp.sum(x * x, axis=1)
-    cross = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    o_ref[...] += qq[:, None] + xx[None, :] - 2.0 * cross
+    o_ref[...] += qq[:, None] + xx[None, :] - 2.0 * _cross(q, x)
 
 
 def _pad_to(a: Array, axis: int, mult: int, value: float = 0.0) -> Array:
@@ -108,6 +125,7 @@ def pairwise_sq_l2_pallas(
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], xp.shape[0]), jnp.float32),
+        compiler_params=_ACCUMULATE_D,
         interpret=interpret,
     )(qp, xp)
     return jnp.maximum(out[:qn, :n], 0.0)
@@ -124,10 +142,7 @@ def _tile_sq_l2(q_ref, x_ref):
     x = x_ref[...].astype(jnp.float32)
     qq = jnp.sum(q * q, axis=1)
     xx = jnp.sum(x * x, axis=1)
-    cross = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    return jnp.maximum(qq[:, None] + xx[None, :] - 2.0 * cross, 0.0)
+    return jnp.maximum(qq[:, None] + xx[None, :] - 2.0 * _cross(q, x), 0.0)
 
 
 def _eps_count_kernel(q_ref, x_ref, eps_ref, o_ref, *, bn, n_real):
@@ -152,10 +167,8 @@ def _eps_min_label_kernel(q_ref, x_ref, lab_ref, core_ref, eps_ref, o_ref, *, bn
 
     d2 = _tile_sq_l2(q_ref, x_ref)
     gidx = j * bn + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    adj = (
-        (d2 <= eps_ref[0, 0]) & (core_ref[...] != 0)[None, :] & (gidx < n_real)
-    )
-    cand = jnp.where(adj, lab_ref[...][None, :], jnp.int32(n_real))
+    adj = (d2 <= eps_ref[0, 0]) & (core_ref[...] != 0) & (gidx < n_real)
+    cand = jnp.where(adj, lab_ref[...], jnp.int32(n_real))
     o_ref[...] = jnp.minimum(o_ref[...], jnp.min(cand, axis=1, keepdims=True))
 
 
@@ -171,14 +184,22 @@ def _eps_nearest_core_kernel(
 
     d2 = _tile_sq_l2(q_ref, x_ref)
     gidx = j * bn + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    d2 = jnp.where((core_ref[...] != 0)[None, :] & (gidx < n_real), d2, jnp.inf)
-    a = jnp.argmin(d2, axis=1)  # first-index-wins inside the tile
-    dmin = jnp.take_along_axis(d2, a[:, None], axis=1)  # (bq, 1)
-    lab = lab_ref[...][a][:, None]
+    d2 = jnp.where((core_ref[...] != 0) & (gidx < n_real), d2, jnp.inf)
+    dmin = jnp.min(d2, axis=1, keepdims=True)  # (bq, 1)
+    # first-index-wins inside the tile: the label at the lowest position
+    # attaining the minimum (an argmin + gather, as masked reductions)
+    none = jnp.int32(n_real)
+    first = jnp.min(jnp.where(d2 == dmin, gidx, none), axis=1, keepdims=True)
+    lab = jnp.min(jnp.where(gidx == first, lab_ref[...], none), axis=1, keepdims=True)
     # strict <: the earliest tile keeps ties, matching a full-row argmin
     better = dmin < o_d_ref[...]
     o_lab_ref[...] = jnp.where(better, lab, o_lab_ref[...])
     o_d_ref[...] = jnp.where(better, dmin, o_d_ref[...])
+
+
+def _n_row(a: Array, bn: int) -> Array:
+    """Per-point (N,) operand -> lane-major (1, Np) int32 row."""
+    return _pad_to(a.astype(jnp.int32), 0, bn)[None, :]
 
 
 def _eps_operands(q, x, bq, bn):
@@ -189,7 +210,7 @@ def _eps_operands(q, x, bq, bn):
     grid = (qp.shape[0] // bq, xp.shape[0] // bn)
     qspec = pl.BlockSpec((bq, qp.shape[1]), lambda i, j: (i, 0))
     xspec = pl.BlockSpec((bn, xp.shape[1]), lambda i, j: (j, 0))
-    nspec = pl.BlockSpec((bn,), lambda i, j: (j,))  # per-row N-axis operands
+    nspec = pl.BlockSpec((1, bn), lambda i, j: (0, j))  # per-point (1, N) rows
     espec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))  # replicated scalar
     ospec = pl.BlockSpec((bq, 1), lambda i, j: (i, 0))
     return qp, xp, grid, qspec, xspec, nspec, espec, ospec
@@ -214,6 +235,7 @@ def eps_count_pallas(
         in_specs=[qspec, xspec, espec],
         out_specs=ospec,
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], 1), jnp.int32),
+        compiler_params=_REDUCE_N,
         interpret=interpret,
     )(qp, xp, jnp.asarray(eps_sq, jnp.float32).reshape(1, 1))
     return out[:qn, 0]
@@ -241,11 +263,12 @@ def eps_min_label_pallas(
         in_specs=[qspec, xspec, nspec, nspec, espec],
         out_specs=ospec,
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], 1), jnp.int32),
+        compiler_params=_REDUCE_N,
         interpret=interpret,
     )(
         qp, xp,
-        _pad_to(labels.astype(jnp.int32), 0, bn),
-        _pad_to(core.astype(jnp.int32), 0, bn),
+        _n_row(labels, bn),
+        _n_row(core, bn),
         jnp.asarray(eps_sq, jnp.float32).reshape(1, 1),
     )
     return out[:qn, 0]
@@ -276,11 +299,12 @@ def eps_nearest_core_pallas(
             jax.ShapeDtypeStruct((qp.shape[0], 1), jnp.float32),
             jax.ShapeDtypeStruct((qp.shape[0], 1), jnp.int32),
         ],
+        compiler_params=_REDUCE_N,
         interpret=interpret,
     )(
         qp, xp,
-        _pad_to(labels.astype(jnp.int32), 0, bn),
-        _pad_to(core.astype(jnp.int32), 0, bn),
+        _n_row(labels, bn),
+        _n_row(core, bn),
     )
     return dmin[:qn, 0], lab[:qn, 0]
 
@@ -305,7 +329,7 @@ def pairwise_sq_l2_int8_pallas(
     qp = _pad_to(qp, 1, bd)
     xp = _pad_to(x_q, 0, bn)
     xp = _pad_to(xp, 1, bd)
-    sp = _pad_to(scale.astype(jnp.float32), 0, bn)
+    sp = _pad_to(scale.astype(jnp.float32), 0, bn)[None, :]  # (1, Np) row
     grid = (qp.shape[0] // bq, xp.shape[0] // bn, qp.shape[1] // bd)
     out = pl.pallas_call(
         _pairwise_int8_kernel,
@@ -313,10 +337,11 @@ def pairwise_sq_l2_int8_pallas(
         in_specs=[
             pl.BlockSpec((bq, bd), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn, bd), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], xp.shape[0]), jnp.float32),
+        compiler_params=_ACCUMULATE_D,
         interpret=interpret,
     )(qp, xp, sp)
     return jnp.maximum(out[:qn, :n], 0.0)

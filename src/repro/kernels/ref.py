@@ -3,6 +3,12 @@
 Each kernel in kernels/ is validated against these references over
 shape/dtype sweeps in tests/test_kernels_*.py (interpret mode on CPU,
 compiled on real TPU).
+
+This module owns the squared-L2 expansion ``|q|^2 + |x|^2 - 2 q.x`` for the
+jnp side (``core.metric`` uses it too).  Its contractions run at
+``Precision.HIGHEST``: on a TPU the default f32 matmul takes bf16 passes,
+which would move distances, pruning bounds and eps tests far past f32
+rounding; on a CPU the flag changes nothing.
 """
 from __future__ import annotations
 
@@ -11,6 +17,8 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def pairwise_sq_l2_ref(q: Array, x: Array) -> Array:
     """(Q, D) x (N, D) -> (Q, N) squared L2, via the MXU-friendly expansion."""
@@ -18,7 +26,8 @@ def pairwise_sq_l2_ref(q: Array, x: Array) -> Array:
     x = x.astype(jnp.float32)
     qq = jnp.sum(q * q, axis=-1)[:, None]
     xx = jnp.sum(x * x, axis=-1)[None, :]
-    return jnp.maximum(qq + xx - 2.0 * (q @ x.T), 0.0)
+    cross = jnp.matmul(q, x.T, precision=_HIGHEST)
+    return jnp.maximum(qq + xx - 2.0 * cross, 0.0)
 
 
 def eps_count_ref(q: Array, x: Array, eps_sq: Array) -> Array:
@@ -96,15 +105,25 @@ def bucket_scan_topk_ref(
     d2 = (
         jnp.sum(q * q, axis=-1)[:, None, None]
         + jnp.sum(bx * bx, axis=-1)
-        - 2.0 * jnp.einsum("qbcd,qd->qbc", bx, q)
+        - 2.0 * jnp.einsum("qbcd,qd->qbc", bx, q, precision=_HIGHEST)
     )
     d2 = jnp.where(live, jnp.maximum(d2, 0.0), jnp.inf)
     cand_d = d2.reshape(qn, -1)
     cand_i = jnp.where(live, bids, -1).reshape(qn, -1)
     merged_d = jnp.concatenate([top_d, cand_d], axis=1)
     merged_i = jnp.concatenate([top_i, cand_i], axis=1)
-    neg, pos = jax.lax.top_k(-merged_d, kk)
-    return -neg, jnp.take_along_axis(merged_i, pos, axis=1)
+    return topk_by_distance_then_id(merged_d, merged_i, kk)
+
+
+def topk_by_distance_then_id(d: Array, ids: Array, k: int) -> tuple[Array, Array]:
+    """The ``k`` smallest (distance, id) pairs per row, ascending.  Equal
+    distances go to the smaller id, whatever order the candidates arrived
+    in: the tie rule of every top-k merge on the search path (this oracle,
+    ``topk.extract_topk`` in the kernels, the cross-shard merge), so an
+    answer depends neither on visit order nor on which shard held a member.
+    Masked candidates carry (+inf, -1)."""
+    d, ids = jax.lax.sort((d, ids), dimension=1, num_keys=2)
+    return d[:, :k], ids[:, :k]
 
 
 def pairwise_sq_l2_int8_ref(q: Array, x_q: Array, scale: Array) -> Array:

@@ -300,7 +300,7 @@ def moe_ffn(p: PyTree, x: Array, cfg: ModelConfig) -> tuple[Array, dict[str, Arr
             return out
 
         cell_spec = P(cell_axes, None)
-        out = dctx.shard_map(
+        out = jax.shard_map(
             island_a2a,
             mesh=mesh,
             in_specs=(
@@ -329,7 +329,7 @@ def moe_ffn(p: PyTree, x: Array, cfg: ModelConfig) -> tuple[Array, dict[str, Arr
         if m.num_shared
         else None
     )
-    out = dctx.shard_map(
+    out = jax.shard_map(
         island,
         mesh=mesh,
         in_specs=(

@@ -157,13 +157,13 @@ def attribute_visits(
     ``delta_rows_per_shard`` likewise for the delta phase.  ``home`` is
     each query's routed index; ``result_ids`` the final top-k (−1 pad).
 
-    A query whose eligible buckets hold fewer than k members keeps scanning
-    past the +inf lower bounds (inf <= inf), so decoded visits CAN land on
-    ineligible rows and — under the sharded layout — on shard-alignment
-    padding rows (owner = sentinel index I).  Padding rows hold no members,
-    so such visits are always wasted; they stay in the per-query wasted
-    counts (conservation against ``buckets_visited`` holds) but out of the
-    (visited, home) pair matrices, since no real index owns them.
+    The scan visits only rows with a finite lower bound (a query whose
+    selection holds fewer than k members has every index selected), so
+    decoded visits land on eligible rows.  Shard-alignment padding rows
+    (owner = sentinel index I) are never eligible; should a decoded visit
+    land on one it is counted wasted (conservation against
+    ``buckets_visited`` holds) and kept out of the (visited, home) pair
+    matrices, since no real index owns it.
     """
     order = np.asarray(order)
     visits = np.asarray(visits)

@@ -4,7 +4,8 @@ kNN-LM retrieval (the paper's datastore) fused into every decode step.
 
 The traffic model (see serve/README.md for the full lifecycle):
 
-* **continuous batching** — a fixed decode batch of ``num_slots``;
+* **continuous batching** — a fixed decode batch of ``num_slots`` (one
+  idle lane more for a single slot, see ``lanes``);
   finished/expired/empty slots are refilled from the request queue between
   steps.  The jitted decode step never recompiles because shapes are
   static, and per-slot cache positions make mid-flight refill *safe*: one
@@ -146,9 +147,15 @@ class ServeEngine:
         self.max_len = max_len
         self.datastore = datastore
         self.greedy = greedy
-        self.cache = model.init_cache(num_slots, max_len)
+        # decode lanes: a batch of ONE row takes XLA's vector-times-matrix
+        # path, whose sums round differently from the matrix path any
+        # larger batch takes (on TPU v5e and on CPU), so a 1-slot engine
+        # keeps a spare idle lane and a request's tokens do not depend on
+        # the slot count
+        self.lanes = max(num_slots, 2)
+        self.cache = model.init_cache(self.lanes, max_len)
         self.slot_req: list[Request | None] = [None] * num_slots
-        self.slot_pos = np.zeros(num_slots, np.int32)
+        self.slot_pos = np.zeros(self.lanes, np.int32)
         self.queue: list[Request] = []
         self.ingest_queue: list[IngestRequest] = []
         self._decode = jax.jit(self._decode_step)
@@ -414,7 +421,7 @@ class ServeEngine:
 
     def _batch_axis(self, leaf) -> int:
         # stage caches are stacked (n, B, ...) when scanned; (B, ...) when not
-        return 1 if leaf.ndim >= 2 and leaf.shape[1] == self.num_slots else 0
+        return 1 if leaf.ndim >= 2 and leaf.shape[1] == self.lanes else 0
 
     # --- scheduler ----------------------------------------------------------
     def step(self) -> list[Request | IngestRequest]:
@@ -440,7 +447,7 @@ class ServeEngine:
         # slot at max(live positions) would skip past the refilled
         # slot's prompt and corrupt its decode.  Empty slots step at
         # their stale position and decode garbage, ignored.
-        tokens = np.zeros((self.num_slots, 1), np.int32)
+        tokens = np.zeros((self.lanes, 1), np.int32)
         for s in live:
             tokens[s, 0] = self.slot_req[s].out_tokens[-1]
         t_step = time.perf_counter()

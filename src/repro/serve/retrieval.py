@@ -343,7 +343,7 @@ def knn_logits(
             )
 
         scale_spec = P(dctx.MODEL_AXIS) if ds.scale is not None else None
-        d2, vals = dctx.shard_map(
+        d2, vals = jax.shard_map(
             island,
             mesh=mesh,
             in_specs=(P(), P(dctx.MODEL_AXIS, None), P(dctx.MODEL_AXIS), scale_spec),

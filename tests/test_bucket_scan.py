@@ -83,6 +83,22 @@ def test_bucket_scan_matches_ref(qn, nb, cap, dim, beam, kk, rng):
     assert np.all((diffs >= -1e-6) | np.isnan(diffs))
 
 
+def test_bucket_scan_splits_query_batches_past_smem(rng, monkeypatch):
+    """A batch whose (Q * beam) bucket selections exceed one call's SMEM
+    share runs as several row chunks, with the unsplit answer."""
+    from repro.kernels import bucket_scan
+
+    qn, nb, cap, dim, beam, kk = 37, 6, 5, 7, 3, 6  # a shape no other test traces
+    q, bx, ids, bsel, act, top_d, top_i = _problem(rng, qn, nb, cap, dim, beam, kk)
+    monkeypatch.setattr(bucket_scan, "SMEM_WORDS", 16)  # 8 rows per call
+    kd, ki = bucket_scan_topk_pallas(
+        q, bx, ids, bsel, act, top_d, top_i, interpret=True
+    )
+    rd, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-5, atol=1e-5)
+    _check_ids_achieve_values(q, bx, ids, kd, ki)
+
+
 def test_bucket_scan_fewer_than_k_reachable(rng):
     """Heavily padded buckets + sparse activity: inf/-1 tail, no garbage."""
     q, bx, ids, bsel, act, top_d, top_i = _problem(
@@ -119,9 +135,10 @@ def test_bucket_scan_dry_pool_keeps_ids_unique(rng):
 
 
 def test_bucket_scan_duplicate_distances(rng):
-    """Exactly tied candidates: values must agree with the oracle even when
-    tie-broken ids legitimately differ."""
-    qn, nb, cap, dim, beam, kk = 3, 5, 4, 6, 3, 6
+    """Exactly tied candidates: equal distances go to the smaller id, in
+    the kernel and in the oracle alike, whatever the bucket visit order —
+    what keeps answers identical across device layouts."""
+    qn, nb, cap, dim, beam, kk = 3, 5, 4, 6, 3, 10
     q = jnp.asarray(rng.normal(size=(qn, dim)), jnp.float32)
     # duplicate the same member row across buckets -> equal distances
     row = rng.normal(size=(dim,)).astype(np.float32)
@@ -129,16 +146,23 @@ def test_bucket_scan_duplicate_distances(rng):
     bx[2:] = rng.normal(size=(nb - 2, cap, dim))
     bx = jnp.asarray(bx, jnp.float32)
     ids = jnp.asarray(np.arange(nb * cap, dtype=np.int32).reshape(nb, cap))
-    bsel = jnp.asarray(rng.integers(0, nb, size=(qn, beam)), jnp.int32)
+    # visit the duplicate buckets in DESCENDING id order: position order
+    # would then put the larger ids first
+    bsel = jnp.asarray(np.tile([1, 0, 3], (qn, 1)), jnp.int32)
     act = jnp.ones((qn, beam), bool)
     top_d = jnp.full((qn, kk), jnp.inf)
     top_i = jnp.full((qn, kk), -1, jnp.int32)
-    rd, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    rd, ri = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
     kd, ki = bucket_scan_topk_pallas(
         q, bx, ids, bsel, act, top_d, top_i, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-5, atol=1e-5)
     _check_ids_achieve_values(q, bx, ids, kd, ki)
+    kd, ki, ri = np.asarray(kd), np.asarray(ki), np.asarray(ri)
+    np.testing.assert_array_equal(ki, ri)
+    tied = np.diff(kd, axis=1) == 0
+    assert tied.any()  # the shape must exercise the rule
+    assert (np.diff(ki, axis=1)[tied] > 0).all()
 
 
 def test_bucket_scan_int8_matches_ref(rng):

@@ -6,7 +6,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import SHAPES, get_config
-from repro.distributed import context as dctx
 from repro.distributed.collectives import (
     compressed_psum,
     ring_allgather_pipelined,
@@ -23,7 +22,7 @@ def mesh1d():
 
 def _run_island(mesh, fn, *args, in_specs=None, out_specs=P()):
     n = len(jax.devices())
-    return dctx.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=in_specs or tuple(P() for _ in args),
         out_specs=out_specs, check_vma=False,

@@ -74,14 +74,12 @@ def test_forest_mode_exact_within_selected(built):
         member_mask = np.isin(forest.bucket_index, list(sel))
         mem_ids = forest.bucket_ids[member_mask][forest.bucket_mask[member_mask]]
         if len(mem_ids) < 8:
-            # under-filled selection: the scan spills to the next-nearest
-            # buckets by design (paper §4.3: "when the required number of
-            # objects has not yet been reached") — results come from a
-            # SUPERSET of the selection, so they can only be closer
-            sub = x[mem_ids]
-            d_true = np.sort(np.sqrt(((sub - q[qi]) ** 2).sum(-1)))
-            assert np.all(d[qi][: len(mem_ids)] <= d_true + 2e-3)
-            assert np.all(np.isfinite(d[qi]))  # spill filled up to k
+            # under-filled selection: it widens to every index by design
+            # (paper §4.3: "when the required number of objects has not
+            # yet been reached") — results come from a SUPERSET of the
+            # selection, so they can only be closer
+            d_all = np.sort(np.sqrt(((x - q[qi]) ** 2).sum(-1)))[:8]
+            np.testing.assert_allclose(d[qi], d_all, rtol=2e-3, atol=2e-3)
             continue
         sub = x[mem_ids]
         d_true = np.sort(np.sqrt(((sub - q[qi]) ** 2).sum(-1)))[:8]

@@ -190,3 +190,28 @@ def test_engine_greedy_matches_manual_decode(rng):
         want.append(int(jnp.argmax(lg[0])))
         pos += 1
     assert got == want
+
+
+def test_tokens_do_not_depend_on_slot_count():
+    """The engine oracle at the published smollm-135m widths (depth cut to
+    2 layers, datastore to 16,384 keys): each request's tokens from a
+    4-slot engine equal a 1-slot engine's.  A one-row decode batch would
+    round differently and flip near-tied kNN-LM argmaxes here."""
+    from repro.configs.smollm_135m import CONFIG
+
+    cfg = CONFIG.replace(
+        num_layers=2, retrieval=RetrievalConfig(enabled=True, datastore_size=16_384)
+    )
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    keys, values = embedding_datastore(16_384, cfg.d_model, seed=0)
+    ds = build_flat_datastore(keys, values % cfg.vocab_size)
+    prompts = np.random.default_rng(2).integers(0, cfg.vocab_size, (8, 16))
+
+    def serve(slots):
+        engine = ServeEngine(model, params, num_slots=slots, max_len=33, datastore=ds)
+        for i, p in enumerate(prompts):
+            engine.submit(Request(rid=i, prompt=p.astype(np.int32), max_new_tokens=16))
+        return [r.out_tokens for r in sorted(engine.run(), key=lambda r: r.rid)]
+
+    assert serve(4) == serve(1)

@@ -32,6 +32,7 @@ from repro.api import (
     StreamConfig,
     make_backend,
 )
+from repro.data.synthetic import tracking_like
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 4,
@@ -74,6 +75,11 @@ def datasets(blob_data):
                                   xi_min=0.3, xi_max=0.7)),
         "tracks": (_tracks(), dict(method="vbm", eps=0.8, min_pts=8,
                                    xi_min=0.4, xi_max=0.8)),
+        # DB1's shape at 6,000 rows: 24 long tracks, so routing often picks
+        # an index far from a query's true neighbours and most shards hold
+        # none of its selected buckets
+        "db1": (tracking_like(6_000), dict(method="vbm", eps=6.0, min_pts=16,
+                                           xi_min=0.4, xi_max=0.8)),
     }
 
 
@@ -122,7 +128,7 @@ def _assert_same_results(res, ref, what=""):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("quantize", [False, True], ids=["f32", "int8"])
-@pytest.mark.parametrize("name", ["blobs", "tracks"])
+@pytest.mark.parametrize("name", ["blobs", "tracks", "db1"])
 def test_search_bitwise_across_layouts(pair, datasets, name, quantize):
     single, sharded = pair(name, quantize=quantize, fresh=True)
     assert sharded.backend.shards == 4
