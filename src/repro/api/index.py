@@ -287,15 +287,14 @@ class OverlapIndex:
     def _search_planned(self, q, *, k=None, mode=None, beam=None, kernel=None):
         # phase spans nest under whichever outer span is active ("search"
         # from both public entries), giving search/plan_lookup and
-        # search/device_execute histograms.  NB: device_execute times the
-        # DISPATCH — on an async accelerator completion lands in the
-        # caller's host_transfer span (the first blocking read).
+        # search/dispatch.  dispatch only enqueues the program: the wait
+        # for the device is the caller's device_wait span.
         with self.obs.span("plan_lookup"):
             key = self._plan_key(k, mode, beam, kernel)
             plan = self.plans.plan(key, self.backend)
             plan.calls += 1
             delta = None if self._delta is None else delta_view(self._delta)
-        with self.obs.span("device_execute"):
+        with self.obs.span("dispatch"):
             outs = plan.executor(
                 self.backend.search_operands(self.device),
                 jnp.asarray(q, jnp.float32), delta,
@@ -305,46 +304,61 @@ class OverlapIndex:
         router = outs[4] if len(outs) > 4 else None
         return d, i, s, isl, router, plan
 
+    def _fetch(self, x, get=jax.device_get):
+        """One blocking device-to-host fetch of ``x`` (a pytree of device
+        arrays; ``None`` fetches nothing), counted into
+        ``search.host_fetches`` and ``search.host_fetch_bytes``."""
+        if x is None:
+            return None
+        obs = self.obs
+        if obs.enabled:
+            obs.counter("search.host_fetches").inc()
+            obs.counter("search.host_fetch_bytes").inc(
+                sum(a.nbytes for a in jax.tree.leaves(x))
+            )
+        return get(x)
+
     def _record_search(self, stats: dict[str, Any], isl, router=None) -> None:
         """Fold one search's host-side stats into the registry: fleet
         node-access counters plus the per-island breakdown the sharded
         executor reports (load balance across shards) — and, on the routed
-        layout, the routing tier's dispatch telemetry."""
+        layout, the routing tier's dispatch telemetry.  ``isl`` and
+        ``router`` are host values (fetched in the caller's copy_back)."""
         obs = self.obs
         obs.counter("search.queries").inc(len(stats["buckets_visited"]))
         for name in ("buckets_visited", "distances", "bound_distances"):
             obs.counter(f"search.{name}").inc(int(stats[name].sum()))
         if router is not None:
-            r = jax.device_get(router)
-            mode = "targeted" if bool(r.targeted) else "all"
-            obs.counter("router.queries").inc(len(r.eligible_hosts))
+            mode = "targeted" if bool(router.targeted) else "all"
+            obs.counter("router.queries").inc(len(router.eligible_hosts))
             obs.counter("router.eligible_hosts").inc(
-                int(r.eligible_hosts.sum())
+                int(router.eligible_hosts.sum())
             )
-            obs.counter("router.pruned_hosts").inc(int(r.pruned_hosts.sum()))
+            obs.counter("router.pruned_hosts").inc(
+                int(router.pruned_hosts.sum())
+            )
             obs.counter("router.fanout", mode=mode).inc(
-                len(r.eligible_hosts)
+                len(router.eligible_hosts)
             )
             obs.counter("router.est_bytes", mode="targeted").inc(
-                int(r.wire_targeted)
+                int(router.wire_targeted)
             )
             obs.counter("router.est_bytes", mode="all").inc(
-                int(r.wire_fanall)
+                int(router.wire_fanall)
             )
             obs.emit_event(
                 {
                     "event": "router",
                     "fanout": mode,
-                    "eligible_hosts": r.eligible_hosts.tolist(),
-                    "pruned_hosts": int(r.pruned_hosts.sum()),
-                    "est_bytes_targeted": float(r.wire_targeted),
-                    "est_bytes_fanall": float(r.wire_fanall),
+                    "eligible_hosts": router.eligible_hosts.tolist(),
+                    "pruned_hosts": int(router.pruned_hosts.sum()),
+                    "est_bytes_targeted": float(router.wire_targeted),
+                    "est_bytes_fanall": float(router.wire_fanall),
                 },
                 traced_only=True,
             )
         if isl is None:
             return
-        isl = jax.device_get(isl)
         method = self.cfg.index.method
         for s_id in range(isl.buckets_visited.shape[0]):
             for name in ("buckets_visited", "distances", "bound_distances"):
@@ -389,14 +403,19 @@ class OverlapIndex:
             d, i, s, isl, router, plan = self._search_planned(
                 q, k=k, mode=mode, beam=beam, kernel=kernel
             )
-            with obs.span("host_transfer"):
-                d, i = np.asarray(d), np.asarray(i)
-                stats = stats_to_host(s)
-            if obs.enabled:
-                self._record_search(stats, isl, router)
-        kk = min(plan.key.k, self.n_total)  # Def. 4: |X| <= k -> whole set
-        if d.shape[1] > kk:
-            d, i = d[:, :kk], i[:, :kk]
+            with obs.span("device_wait"):
+                jax.block_until_ready((d, i, s, isl, router))
+            with obs.span("copy_back"):
+                d, i = self._fetch(d, np.asarray), self._fetch(i, np.asarray)
+                stats = self._fetch(s, stats_to_host)
+                if obs.enabled:  # island/router stats only feed the registry
+                    isl, router = self._fetch(isl), self._fetch(router)
+            with obs.span("record"):
+                if obs.enabled:
+                    self._record_search(stats, isl, router)
+                kk = min(plan.key.k, self.n_total)  # Def. 4: |X| <= k -> all
+                if d.shape[1] > kk:
+                    d, i = d[:, :kk], i[:, :kk]
         return SearchResult(dists=d, ids=i, stats=stats, plan=plan)
 
     def explain(
@@ -429,7 +448,7 @@ class OverlapIndex:
                     None if self._delta is None else delta_view(self._delta)
                 )
             qj = jnp.asarray(q, jnp.float32)
-            with obs.span("device_execute"):
+            with obs.span("dispatch"):
                 outs = plan.executor(
                     self.backend.search_operands(self.device), qj, delta
                 )
@@ -443,11 +462,15 @@ class OverlapIndex:
                     jnp.asarray(self.forest.index_centers), qj,
                     kernel=key.kernel,
                 )
-            with obs.span("host_transfer"):
-                d, i = np.asarray(d), np.asarray(i)
-                stats = stats_to_host(s)
-                rows = jax.device_get(rows)
-                home = np.asarray(home)
+            with obs.span("device_wait"):
+                jax.block_until_ready((d, i, s, isl, rows, router, home))
+            with obs.span("copy_back"):
+                d, i = self._fetch(d, np.asarray), self._fetch(i, np.asarray)
+                stats = self._fetch(s, stats_to_host)
+                rows = self._fetch(rows)
+                home = self._fetch(home, np.asarray)
+                if obs.enabled:
+                    isl, router = self._fetch(isl), self._fetch(router)
             if obs.enabled:
                 self._record_search(stats, isl, router)
             kk = min(key.k, self.n_total)
@@ -622,12 +645,12 @@ class OverlapIndex:
         run = self._ingest_executor()
         for _ in range(self.forest.n_indexes + 1):
             self._ingest_calls += 1
-            with self.obs.span("device_execute"):
+            with self.obs.span("dispatch"):
                 self._delta, acc = run(
                     self.device.index_centers, self._delta, xj, ij,
                     jnp.asarray(pending),
                 )
-                pending &= ~np.asarray(acc)
+            pending &= ~np.asarray(acc)
             if not pending.any():
                 return
             # capacity hit: force-rebuild the rejecting indexes, retry rest
@@ -793,8 +816,11 @@ class OverlapIndex:
 
         Sections:
           search       per-phase span histograms (``search``,
-                       ``search/plan_lookup``, ``search/device_execute``,
-                       ``search/host_transfer``) with p50/p95/p99 seconds;
+                       ``search/plan_lookup``, ``search/dispatch``,
+                       ``search/device_wait``, ``search/copy_back``,
+                       ``search/record``) with p50/p95/p99 seconds, the
+                       node-access totals, and the device-to-host fetch
+                       counters (``host_fetches``, ``host_fetch_bytes``);
           plan_cache   compiled-executor table counters (hits/misses/
                        evictions/lifetime traces);
           ingest       write-path counters (compiled traces, executor calls,
@@ -867,6 +893,8 @@ class OverlapIndex:
                 "buckets_visited": obs.value("search.buckets_visited"),
                 "distances": obs.value("search.distances"),
                 "bound_distances": obs.value("search.bound_distances"),
+                "host_fetches": obs.value("search.host_fetches"),
+                "host_fetch_bytes": obs.value("search.host_fetch_bytes"),
             },
             "plan_cache": self.plans.stats(),
             "ingest": {

@@ -4,8 +4,8 @@
 
     reg = Registry()
     with reg.span("search"):
-        with reg.span("device_execute"):
-            ...                       # -> histogram "search/device_execute"
+        with reg.span("dispatch"):
+            ...                       # -> histogram "search/dispatch"
     reg.counter("search.queries").inc(64)
     reg.gauge("serve.queue_depth").set(3)
     reg.snapshot()                    # one nested, JSON-serializable dict

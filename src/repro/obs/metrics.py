@@ -1,5 +1,5 @@
-"""Dependency-free metrics primitives: counters, gauges, streaming
-histograms, and phase-span timers, behind one ``Registry``.
+"""Metrics primitives: counters, gauges, streaming histograms, and
+phase-span timers, behind one ``Registry``.
 
 The paper's whole argument is measured in observability terms — overlap
 reduction is proven by node-access counts and search time — but until this
@@ -26,6 +26,9 @@ Design constraints, in order:
 Spans nest: ``with reg.span("search"): with reg.span("plan_lookup"): ...``
 records a duration histogram under the path ``"search/plan_lookup"`` — the
 nesting stack is per-thread, so concurrent engines don't interleave paths.
+An enabled span is also a ``jax.profiler.TraceAnnotation`` named with that
+path, so under ``jax.profiler.trace`` it lies on the profiler's host line,
+on the same clock as the device's ops.
 """
 from __future__ import annotations
 
@@ -34,6 +37,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Iterator
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import trace as trace_mod
 
@@ -245,6 +250,8 @@ class Registry:
         Yields the full path (or ``None`` when disabled).  The duration is
         observed into ``histogram(path)`` in SECONDS, and — when an event
         log is attached — emitted as one ``{"event": "span", ...}`` line.
+        The span is a ``TraceAnnotation`` named ``path`` too, so a profiler
+        trace shows it on the device trace's clock.
         Exceptions propagate; the stack still unwinds and the (partial)
         duration is still recorded, so a failing phase stays visible.
         """
@@ -265,7 +272,8 @@ class Registry:
             sid, parent = ctx.push()
         t0 = time.perf_counter()
         try:
-            yield path
+            with TraceAnnotation(path):
+                yield path
         finally:
             dur = time.perf_counter() - t0
             stack.pop()

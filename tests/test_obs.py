@@ -254,8 +254,8 @@ def test_facade_metrics_snapshot_shape(blob_data):
     assert m["enabled"] is True
     # per-phase spans under the search root
     spans = m["search"]["spans"]
-    for path in ("search", "search/plan_lookup", "search/device_execute",
-                 "search/host_transfer"):
+    for path in ("search", "search/plan_lookup", "search/dispatch",
+                 "search/device_wait", "search/copy_back", "search/record"):
         assert spans[path]["count"] == 2, path
     assert m["search"]["queries"] == 16
     assert m["search"]["buckets_visited"] > 0
@@ -279,7 +279,7 @@ def test_metrics_events_jsonl(blob_data, tmp_path):
     idx = OverlapIndex.build(blob_data, _cfg(events_path=str(p)))
     idx.search(np.asarray(blob_data[:4]), k=3)
     spans = {r["span"] for r in EventLog.read(str(p))}
-    assert "search" in spans and "search/device_execute" in spans
+    assert "search" in spans and "search/dispatch" in spans
 
 
 # ---------------------------------------------------------------------------
@@ -344,3 +344,106 @@ def test_stats_to_host_single_device_get(monkeypatch):
     assert set(host) == {"buckets_visited", "distances", "bound_distances",
                          "padded_distances", "comparisons", "steps"}
     assert isinstance(host["steps"], int)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, and the device-to-host fetch counters
+# ---------------------------------------------------------------------------
+
+SEARCH_SPANS = ("search", "search/plan_lookup", "search/dispatch",
+                "search/device_wait", "search/copy_back", "search/record")
+
+
+def _traced_host_events(tmp_path, fn) -> list[tuple[str, int, int]]:
+    """Run ``fn`` under ``jax.profiler.trace`` and return the host plane's
+    events as (name, start ns, end ns)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # annotations only, no per-call events
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        fn()
+    (xplane,) = tmp_path.glob("**/*.xplane.pb")
+    events = []
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                events += [(e.name, int(e.start_ns),
+                            int(e.start_ns + e.duration_ns))
+                           for e in line.events]
+    return events
+
+
+def test_search_spans_are_trace_annotations(blob_data, tmp_path):
+    idx = OverlapIndex.build(blob_data, _cfg())
+    q = np.asarray(blob_data[:8])
+    idx.search(q, k=5)  # compile outside the trace
+    events = _traced_host_events(tmp_path, lambda: idx.search(q, k=5))
+    found = {n: (a, b) for n, a, b in events if n in SEARCH_SPANS}
+    assert set(found) == set(SEARCH_SPANS)
+    lo, hi = found["search"]
+    for child in SEARCH_SPANS[1:]:
+        a, b = found[child]
+        assert lo <= a <= b <= hi, child
+    # the children follow one another in the order the search runs them
+    starts = [found[c][0] for c in SEARCH_SPANS[1:]]
+    assert starts == sorted(starts)
+
+
+def test_search_counts_its_host_fetches(blob_data):
+    import jax
+
+    idx = OverlapIndex.build(blob_data, _cfg())
+    q = np.asarray(blob_data[:8])
+    d, i, s, isl, router, _ = idx._search_planned(q, k=5)
+    assert router is None
+    assert len(jax.tree.leaves(s)) == 6 and len(jax.tree.leaves(isl)) == 3
+    nbytes = sum(a.nbytes for a in jax.tree.leaves((d, i, s, isl)))
+    fetches0 = idx.obs.value("search.host_fetches")
+    bytes0 = idx.obs.value("search.host_fetch_bytes")
+    idx.search(q, k=5)
+    # single layout: dists, ids, SearchStats (one batched get), IslandStats
+    assert idx.obs.value("search.host_fetches") - fetches0 == 4
+    assert idx.obs.value("search.host_fetch_bytes") - bytes0 == nbytes
+    m = idx.metrics()["search"]
+    assert m["host_fetches"] == fetches0 + 4
+    assert m["host_fetch_bytes"] == bytes0 + nbytes
+
+
+def test_disabled_obs_adds_no_annotations_or_counters(blob_data, tmp_path):
+    idx_off = OverlapIndex.build(blob_data, _cfg(obs=False))
+    idx_on = OverlapIndex.build(blob_data, _cfg(obs=True))
+    q = np.asarray(blob_data[:8])
+    idx_off.search(q, k=5)
+    got = {}
+    events = _traced_host_events(
+        tmp_path, lambda: got.setdefault("off", idx_off.search(q, k=5)))
+    names = {n for n, _, _ in events}
+    assert not names & set(SEARCH_SPANS)
+    assert not any(n.startswith(("search/", "explain/", "ingest/"))
+                   for n in names)
+    assert idx_off.obs.counters() == {}
+    assert idx_off.metrics()["search"]["host_fetches"] == 0
+    r_on = idx_on.search(q, k=5)
+    assert np.array_equal(got["off"].dists, r_on.dists)
+    assert np.array_equal(got["off"].ids, r_on.ids)
+    assert got["off"].stats.keys() == r_on.stats.keys()
+
+
+def test_explain_and_ingest_spans_split_at_the_device(blob_data):
+    idx = OverlapIndex.build(blob_data, _cfg())
+    q = np.asarray(blob_data[:8])
+    idx.explain(q, k=5)
+    g = np.random.default_rng(1)
+    idx.ingest(g.normal(size=(16, blob_data.shape[1])).astype(np.float32))
+    hists = idx.obs.snapshot()["histograms"]
+    for path in ("explain", "explain/plan_lookup", "explain/dispatch",
+                 "explain/device_wait", "explain/copy_back",
+                 "explain/attribute", "ingest", "ingest/dispatch"):
+        assert hists[path]["count"] >= 1, path
+    old = ("device_execute", "host_transfer")
+    assert not [p for p in hists if p.rsplit("/", 1)[-1] in old]
+    # explain's copy-back counts its fetches too: d, i, stats, rows, home,
+    # and the island stats
+    assert idx.obs.value("search.host_fetches") == 6

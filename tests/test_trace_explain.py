@@ -340,8 +340,9 @@ def test_search_self_sampling_tracing(blob_data, tmp_path):
     # with the per-phase spans and the per-island point event beneath it
     assert len(t.roots) == 1 and t.roots[0].name == "search"
     names = t.span_names()
-    assert {"search", "search/plan_lookup", "search/device_execute",
-            "search/host_transfer", "island"} <= names
+    assert {"search", "search/plan_lookup", "search/dispatch",
+            "search/device_wait", "search/copy_back", "search/record",
+            "island"} <= names
     # untraced searches still recorded their spans, unlinked
     unlinked = [r for r in EventLog.read(p)
                 if r.get("span") == "search" and "trace_id" not in r]
@@ -356,7 +357,7 @@ def test_search_explicit_trace_joins_caller_tree(blob_data, tmp_path):
     t = Trace.reconstruct(p, ctx.trace_id)
     assert len(t.roots) == 1
     assert t.roots[0].record["parent_id"] == ctx.root_id
-    assert "search/device_execute" in t.span_names()
+    assert "search/dispatch" in t.span_names()
 
 
 def test_tracing_off_emits_no_linkage(blob_data, tmp_path):
@@ -468,7 +469,7 @@ def test_export_cli_check_and_snapshot(blob_data, tmp_path, capsys):
     assert obs_export.main(["--events", p, "--check"]) == 0
     out = capsys.readouterr().out
     assert "prometheus render OK" in out
-    assert "search/device_execute" in out  # span latency table
+    assert "search/dispatch" in out  # span latency table
 
     assert obs_export.main(["--snapshot", str(snap_path),
                             "--format", "prometheus"]) == 0
