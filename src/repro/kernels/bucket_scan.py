@@ -14,16 +14,24 @@ This kernel fuses the three stages in VMEM:
   operands, flattened to 1-D, so the grid's DMA engine gathers exactly the
   ``(C, D)`` bucket tiles the step needs straight from the flattened
   ``bucket_x`` in HBM — the (Q, beam, C, D) intermediate never exists;
-* queries move in blocks of ``QB = 8`` rows (one f32 sublane tile): each
-  program computes ``(8, D) x (C, D)^T`` on the MXU for the bucket of ONE
-  row of its block and merges into that row only;
+* queries move in blocks of ``QB = 8`` rows (one f32 sublane tile), and
+  one program serves a whole block: it brings in the 8 bucket tiles its
+  rows selected at this beam slot (one ``BlockSpec`` per row on the same
+  bucket store, each indexed through ``bsel``), computes each row's
+  distances to its own tile (``(8, D) x (C, D)^T`` on the MXU, row r kept),
+  and merges all 8 rows with ONE ``topk.extract_topk`` over
+  ``[running top-k | candidates]``.  The extraction costs as much for 8
+  rows as for 1 (an ``(8, W)`` f32 array is one sublane tile), so one
+  extraction serves 8 rows;
 * the running ``(8, kk)`` top-k block (values + global object ids) stays
-  resident in the output VMEM block across the beam and row axes,
-  maintained with ``topk.extract_topk``'s k-step masked-min extraction.
+  resident in the output VMEM block across the beam axis.
 
-Grid: ``(Q/8, beam, 8)``.  The output block depends only on the first
-axis, so the inner two revisit it (the accumulation pattern of topk.py's
-N axis), and each query merges its buckets in beam order.
+Grid: ``(Q/8, beam)``.  The output block depends only on the first axis,
+so the beam axis revisits it (the accumulation pattern of topk.py's N
+axis), and each query merges its buckets in beam order.  Every block is
+double-buffered, 2 x 8 member tiles among them; where that outgrows the
+compiler's default scoped VMEM the call raises its own limit
+(``_vmem_limit``), from the shapes it is given.
 
 An int8 variant dequantizes the gathered bucket tile in-register against
 per-member scales (``ops.quantize_datastore`` layout), quartering the HBM
@@ -47,63 +55,84 @@ Array = jax.Array
 
 
 # Queries per grid block: one f32 sublane tile.  Every per-query operand
-# moves in (8, .) blocks (the chip's tiling rule); the grid walks the 8 rows
-# of a block one at a time and only that row's state is updated.
+# moves in (8, .) blocks (the chip's tiling rule); each program brings in the
+# 8 bucket tiles its rows selected and merges all 8 rows at once.
 QB = 8
 # Words of SMEM each scalar-prefetch operand (bsel, act) may take per call.
 # A v5e core has 1 MiB of SMEM; larger query batches run as several calls.
 SMEM_WORDS = 32 * 1024
+# VMEM a kernel may take unless its call asks for more (the v5e compiler's
+# scoped default).
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 
 
 def _scan_kernel(
     bsel_ref,  # scalar prefetch (Qp * beam,) i32, row-major (query, beam)
     act_ref,  # scalar prefetch (Qp * beam,) i32
     q_ref,  # (QB, Dp) the query block
-    x_ref,  # (1, Cp, Dp) gathered bucket tile (f32 or int8)
-    ids_ref,  # (1, 1, Cp) i32, -1 pad
-    *rest,  # [scale_ref (1, 1, Cp) f32,] top_d, top_i, o_val, o_idx
+    *rest,  # QB x (1, Cp, Dp) tiles, QB x (1, 1, Cp) ids, [QB x (1, 1, Cp)
+    #         scales,] top_d, top_i, o_val, o_idx
     kk: int,
     beam: int,
     quantized: bool,
 ):
-    if quantized:
-        scale_ref, top_d_ref, top_i_ref, o_val_ref, o_idx_ref = rest
-    else:
-        top_d_ref, top_i_ref, o_val_ref, o_idx_ref = rest
+    x_refs, ids_refs = rest[:QB], rest[QB:2 * QB]
+    scale_refs = rest[2 * QB:3 * QB] if quantized else None
+    top_d_ref, top_i_ref, o_val_ref, o_idx_ref = rest[(3 if quantized else 2) * QB:]
     g = pl.program_id(0)
     b = pl.program_id(1)
-    r = pl.program_id(2)
 
-    @pl.when((b == 0) & (r == 0))
+    @pl.when(b == 0)
     def _init():
         o_val_ref[...] = top_d_ref[...]
         o_idx_ref[...] = top_i_ref[...]
 
-    x = x_ref[0].astype(jnp.float32)  # (Cp, Dp)
-    if quantized:
-        x = x * scale_ref[0].T  # per-member dequant scales as a column
     qv = q_ref[...].astype(jnp.float32)  # (QB, Dp)
     qq = jnp.sum(qv * qv, axis=1, keepdims=True)  # (QB, 1)
-    xx = jnp.sum(x * x, axis=1)  # (Cp,)
-    cross = jax.lax.dot_general(
-        qv, x, (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )  # (QB, Cp)
-    d2 = jnp.maximum(qq + xx[None, :] - 2.0 * cross, 0.0)
-    # only row r of the block owns this bucket; the others stay as they are
-    row = jax.lax.broadcasted_iota(jnp.int32, (QB, 1), 0) == r
-    qi = g * QB + r
-    live = (ids_ref[0] >= 0) & row & (act_ref[qi * beam + b] > 0)
-    d2 = jnp.where(live, d2, jnp.inf)
-    cand_i = jnp.where(live, ids_ref[0], -1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (QB, 1), 0)
+    cp = x_refs[0].shape[1]
+    d2 = jnp.full((QB, cp), jnp.inf, jnp.float32)
+    cand_i = jnp.full((QB, cp), -1, jnp.int32)
+    # Row r's candidates come from its own tile: the whole block's distances
+    # to that tile, of which row r is kept.
+    for r in range(QB):
+        x = x_refs[r][0].astype(jnp.float32)  # (Cp, Dp)
+        if quantized:
+            x = x * scale_refs[r][0].T  # per-member dequant scales as a column
+        xx = jnp.sum(x * x, axis=1)  # (Cp,)
+        cross = jax.lax.dot_general(
+            qv, x, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )  # (QB, Cp)
+        d2_r = jnp.maximum(qq + xx[None, :] - 2.0 * cross, 0.0)
+        ids = ids_refs[r][0]  # (1, Cp)
+        live = (ids >= 0) & (act_ref[(g * QB + r) * beam + b] > 0)
+        mine = row == r
+        d2 = jnp.where(mine, jnp.where(live, d2_r, jnp.inf), d2)
+        cand_i = jnp.where(mine, jnp.where(live, ids, -1), cand_i)
 
     kkp = o_val_ref.shape[1]
     vals = jnp.concatenate([o_val_ref[...], d2], axis=1)  # (QB, kkp + Cp)
     idxs = jnp.concatenate([o_idx_ref[...], cand_i], axis=1)
-    new_v, new_i = extract_topk(vals, idxs, kk, kkp)
-    o_val_ref[...] = jnp.where(row, new_v, o_val_ref[...])
-    o_idx_ref[...] = jnp.where(row, new_i, o_idx_ref[...])
+    o_val_ref[...], o_idx_ref[...] = extract_topk(vals, idxs, kk, kkp)
+
+
+def _vmem_limit(cp: int, dp: int, kkp: int, itemsize: int, quantized: bool) -> int | None:
+    """``vmem_limit_bytes`` for one call, or None where the default will do.
+
+    The pipeline double-buffers every block: QB member tiles with their id
+    (and scale) rows, the query block and the four top-k blocks.  The body
+    adds f32 working copies of a tile and, per row, a 128-lane column of
+    squared norms.  In (Cp, 128) f32 columns, the v5e compiler asked for
+    about 10 beyond the buffers at 128-d and 20 at 576-d (tiles of 1,024 to
+    3,200 members); this counts 11 and 23, and a quarter on top.
+    """
+    rows = QB * cp * 4 * (2 if quantized else 1)
+    blocks = QB * cp * dp * itemsize + rows + QB * dp * 4 + 4 * QB * kkp * 4
+    body = 3 * cp * dp * 4 + QB * cp * 128 * 4
+    limit = (2 * blocks + body) * 5 // 4
+    return None if limit <= SCOPED_VMEM_BYTES else limit
 
 
 def _pad_to(a: Array, axis: int, mult: int, value=0) -> Array:
@@ -207,26 +236,26 @@ def bucket_scan_topk_pallas(
 
     cp, dp = xp.shape[1], xp.shape[2]
 
-    def member(g, b, r, bsel, act):
-        return (bsel[(g * QB + r) * beam + b], 0, 0)
+    def member(r):
+        # row r of the block reads the bucket it selected at this beam slot
+        return lambda g, b, bsel, act: (bsel[(g * QB + r) * beam + b], 0, 0)
 
-    def query_block(g, b, r, bsel, act):
+    def query_block(g, b, bsel, act):
         return (g, 0)
 
-    member_row = pl.BlockSpec((1, 1, cp), member)
     in_specs = [
         pl.BlockSpec((QB, dp), query_block),
-        pl.BlockSpec((1, cp, dp), member),
-        member_row,
-        *([member_row] if quantized else []),
+        *[pl.BlockSpec((1, cp, dp), member(r)) for r in range(QB)],
+        *[pl.BlockSpec((1, 1, cp), member(r)) for r in range(QB)],
+        *([pl.BlockSpec((1, 1, cp), member(r)) for r in range(QB)] if quantized else []),
         pl.BlockSpec((QB, kkp), query_block),
         pl.BlockSpec((QB, kkp), query_block),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         # the output block depends on the first axis only: it stays
-        # resident while each of its 8 rows merges its beam buckets
-        grid=(qpn // QB, beam, QB),
+        # resident while the block merges its beam buckets in order
+        grid=(qpn // QB, beam),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((QB, kkp), query_block),
@@ -240,14 +269,17 @@ def bucket_scan_topk_pallas(
             jax.ShapeDtypeStruct((qpn, kkp), jnp.float32),
             jax.ShapeDtypeStruct((qpn, kkp), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(cp, dp, kkp, xp.dtype.itemsize, quantized)
+        ),
         interpret=interpret,
     )(
         bsel_f,
         act_f,
         qp,
-        xp,
-        idsp,
-        *([scalep] if quantized else []),
+        *[xp] * QB,
+        *[idsp] * QB,
+        *([scalep] * QB if quantized else []),
         top_dp,
         top_ip,
     )

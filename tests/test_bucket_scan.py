@@ -26,6 +26,11 @@ def _problem(rng, qn, nb, cap, dim, beam, kk, *, pad_frac=0.3, seeded_topk=True)
     ids = jnp.where(jnp.asarray(rng.random((nb, cap)) < pad_frac), -1, ids)
     bsel = jnp.asarray(rng.integers(0, nb, size=(qn, beam)), jnp.int32)
     act = jnp.asarray(rng.random((qn, beam)) < 0.75)
+    if qn >= 8:
+        # the first 8-row block (one kernel program) holds every case: rows
+        # 0-3 read one bucket, row 4 is inactive, rows 5-7 read their own
+        bsel = bsel.at[1:4].set(bsel[0])
+        act = act.at[0:4].set(True).at[4].set(False)
     if seeded_topk:
         top_d = jnp.sort(
             jnp.asarray(rng.random((qn, kk)).astype(np.float32) * 40.0), axis=1
@@ -65,6 +70,12 @@ SHAPES = [
     (2, 9, 8, 16, 4, 7),
     (1, 3, 2, 33, 2, 5),
     (5, 6, 4, 8, 6, 11),
+    # several 8-row blocks, the last one partial, at beam 1 and 3 and
+    # k 10 and 100 (see _problem for the first block's selections)
+    (19, 12, 10, 20, 1, 10),
+    (19, 7, 9, 5, 3, 100),
+    (100, 24, 12, 20, 1, 10),
+    (100, 16, 16, 6, 3, 100),
 ]
 
 
@@ -176,6 +187,21 @@ def test_bucket_scan_int8_matches_ref(rng):
         q, bxq, ids, bsel, act, top_d, top_i, bscale, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-4, atol=1e-4)
+
+
+def test_bucket_scan_int8_blocks_match_ref(rng):
+    """int8 members over several query blocks, shared and inactive rows."""
+    qn, nb, cap, dim, beam, kk = 19, 9, 12, 20, 3, 10
+    q, bx, ids, bsel, act, top_d, top_i = _problem(rng, qn, nb, cap, dim, beam, kk)
+    xq, scale = quantize_datastore(bx.reshape(nb * cap, dim))
+    bxq = xq.reshape(nb, cap, dim)
+    bscale = scale.reshape(nb, cap)
+    rd, ri = ref.bucket_scan_topk_ref(q, bxq, ids, bsel, act, top_d, top_i, bscale)
+    kd, ki = bucket_scan_topk_pallas(
+        q, bxq, ids, bsel, act, top_d, top_i, bscale, interpret=True
+    )
+    np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(ki)[4], np.asarray(top_i)[4])  # inactive
 
 
 @pytest.fixture()

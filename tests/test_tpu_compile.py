@@ -90,6 +90,36 @@ def test_bucket_scan_compiles(one_chip, dtype, dim, k):
     _compile(step, *shapes, sharding=one_chip)
 
 
+@pytest.mark.parametrize(
+    "nb,cap,dim,k,own_limit",
+    [
+        (1_634, 707, 5, 10, False),  # the WARD forest (500,000 x 5, c_max 707)
+        (64, 1_000, 128, 100, True),  # the largest tile the tests build
+    ],
+    ids=["ward", "d128-c1000"],
+)
+def test_bucket_scan_compiles_at_forest_shapes(one_chip, nb, cap, dim, k, own_limit):
+    """Each program holds the 8 tiles of its rows, double-buffered; where
+    that outgrows the default scoped VMEM the call sets its own limit."""
+    from repro.kernels import bucket_scan
+
+    def step(q, bx, ids, bsel, act, top_d, top_i):
+        bx, ids, _ = prepad_buckets(bx, ids)
+        return bucket_scan_topk_pallas(q, bx, ids, bsel, act, top_d, top_i)
+
+    cp, dp = -(-cap // 128) * 128, -(-dim // 128) * 128
+    limit = bucket_scan._vmem_limit(cp, dp, -(-k // 128) * 128, 4, False)
+    assert (limit is not None) == own_limit
+    _compile(
+        step,
+        ((QUERIES, dim), jnp.float32), ((nb, cap, dim), jnp.float32),
+        ((nb, cap), jnp.int32), ((QUERIES, 1), jnp.int32),
+        ((QUERIES, 1), jnp.bool_), ((QUERIES, k), jnp.float32),
+        ((QUERIES, k), jnp.int32),
+        sharding=one_chip,
+    )
+
+
 def test_bucket_scan_compiles_past_one_smem_load(one_chip):
     """A query batch whose bucket selections exceed one call's SMEM share
     splits into several kernel calls instead of overflowing SMEM."""
