@@ -24,8 +24,9 @@ def main() -> None:
     pivots, radii, assign = partitions_from_labels(x, res.labels, res.n_clusters)
 
     print("(ii) overlap estimation (paper Defs. 7-11):")
+    rates_of = {}
     for method in ("vbm", "dbm", "obm"):
-        rates = np.asarray(overlap_matrix(
+        rates = rates_of[method] = np.asarray(overlap_matrix(
             method, jnp.asarray(pivots), jnp.asarray(radii),
             x=jnp.asarray(x), assign=jnp.asarray(assign)))
         iu = np.triu_indices_from(rates, 1)
@@ -34,7 +35,7 @@ def main() -> None:
 
     print("(iii) decision-making (xi_min=0.4, xi_max=0.8), VBM:")
     groups, stats = decide(x, pivots, radii, assign,
-                           method="vbm", xi_min=0.4, xi_max=0.8)
+                           method="vbm", xi_min=0.4, xi_max=0.8, rates=rates_of["vbm"])
     print(f"    merged pairs: {stats.n_merged_pairs}, overlap indexes: "
           f"{stats.n_overlap_indexes}, low-overlap moves: {stats.n_low_moves}")
     print(f"    final groups: {stats.n_final}")

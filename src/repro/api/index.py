@@ -84,6 +84,19 @@ def _as_config(cfg: Config | _LegacyIndexConfig | None) -> Config:
     )
 
 
+def _registry(cfg: Config) -> Registry:
+    events_path = cfg.obs.events_path or events_path_from_env()
+    return Registry(
+        enabled=cfg.obs.enabled,
+        window=cfg.obs.window,
+        events=None if events_path is None else EventLog(
+            events_path,
+            max_bytes=cfg.obs.events_max_bytes,
+            backups=cfg.obs.events_backups,
+        ),
+    )
+
+
 def _check_data(x) -> np.ndarray:
     x = np.asarray(x, np.float32)
     if x.ndim != 2 or len(x) == 0:
@@ -117,6 +130,7 @@ class OverlapIndex:
         rebuild_log: list[dict[str, Any]] | None = None,
         monitor_baseline: np.ndarray | None = None,
         clamp_layout: bool = False,
+        obs: Registry | None = None,
     ) -> "OverlapIndex":
         self = object.__new__(cls)
         self.cfg = cfg
@@ -151,16 +165,8 @@ class OverlapIndex:
         # one telemetry registry per index: every layer below (plan cache,
         # spans, ingest/maintenance counters, per-island node accesses)
         # registers here; ``metrics()`` is the single snapshot of it all
-        events_path = cfg.obs.events_path or events_path_from_env()
-        self.obs = Registry(
-            enabled=cfg.obs.enabled,
-            window=cfg.obs.window,
-            events=None if events_path is None else EventLog(
-                events_path,
-                max_bytes=cfg.obs.events_max_bytes,
-                backups=cfg.obs.events_backups,
-            ),
-        )
+        self.obs = _registry(cfg) if obs is None else obs
+        self._set_index_gauges()
         # per-request tracing: self-sampled searches (cfg.obs.trace_sample)
         # get their own TraceContext; an ambient context installed by a
         # caller (ServeEngine) always wins
@@ -177,8 +183,12 @@ class OverlapIndex:
         """The paper's proposed pipeline (§4): overlap-optimized forest."""
         cfg = _as_config(cfg)
         x = _check_data(x)
-        forest, report = build_index_core(x, cfg.index)
-        return cls._wire(x, forest, cfg, report)
+        # the registry comes first, so that the build's phases are spans:
+        # build/dbscan, build/overlap, build/decide, build/forest
+        obs = _registry(cfg)
+        with obs.span("build"):
+            forest, report = build_index_core(x, cfg.index, span=obs.span)
+        return cls._wire(x, forest, cfg, report, obs=obs)
 
     @classmethod
     def baseline(
@@ -216,13 +226,23 @@ class OverlapIndex:
 
         Lazy: host-only consumers (build reports, structure rollups, the
         construction benchmarks) never pay the upload — and build wall time
-        measures the build, not the transfer.  First search/ingest uploads.
+        measures the build, not the transfer.  First search/ingest uploads,
+        inside the span ``build/upload`` whatever span asked for it.
         """
         if self._device is None:
-            self._device = self.backend.upload_forest(
-                self.forest, quantize=self.cfg.search.quantize
-            )
+            with self.obs.span_at("build/upload"):
+                self._device = jax.block_until_ready(self.backend.upload_forest(
+                    self.forest, quantize=self.cfg.search.quantize
+                ))
         return self._device
+
+    def _set_index_gauges(self) -> None:
+        """The forest's shape as gauges: ``index.indexes``,
+        ``index.overlap_indexes`` and ``index.buckets``."""
+        f = self.forest
+        self.obs.gauge("index.indexes").set(f.n_indexes)
+        self.obs.gauge("index.overlap_indexes").set(int(f.is_overlap_index.sum()))
+        self.obs.gauge("index.buckets").set(f.n_buckets)
 
     @property
     def delta(self) -> DeltaBuffer | None:
@@ -741,6 +761,7 @@ class OverlapIndex:
         # hot swap stays atomic (single layout: no-op)
         self.backend.barrier(new_device, new_delta)
         self.forest, self._device, self._delta = new_forest, new_device, new_delta
+        self._set_index_gauges()
         self.monitor = self._make_monitor()
         stats["triggers"] = list(triggers)
         stats["reasons"] = dict(report.reasons) if report is not None else {}
@@ -815,6 +836,12 @@ class OverlapIndex:
         """ONE nested telemetry snapshot of this index (JSON-serializable).
 
         Sections:
+          build        the build's phase spans (``build``, ``build/dbscan``,
+                       ``build/overlap``, ``build/decide``, ``build/forest``;
+                       ``build/upload`` once the first search or ingest has
+                       uploaded the forest) and the forest's shape (gauges
+                       ``index.indexes``, ``index.overlap_indexes``,
+                       ``index.buckets``);
           search       per-phase span histograms (``search``,
                        ``search/plan_lookup``, ``search/dispatch``,
                        ``search/device_wait``, ``search/copy_back``,
@@ -884,6 +911,16 @@ class OverlapIndex:
             }
         return {
             "enabled": obs.enabled,
+            "build": {
+                "spans": {
+                    k: v for k, v in snap["histograms"].items()
+                    if k == "build" or k.startswith("build/")
+                },
+                **{
+                    name: snap["gauges"].get(f"index.{name}")
+                    for name in ("indexes", "overlap_indexes", "buckets")
+                },
+            },
             "search": {
                 "spans": {
                     k: v for k, v in snap["histograms"].items()

@@ -71,9 +71,10 @@ def _recompute(x: np.ndarray, members: np.ndarray) -> tuple[np.ndarray, float]:
     return pivot.astype(np.float32), radius
 
 
-def _rate_matrix(
+def rate_matrix(
     method: str, x: np.ndarray, pivots: np.ndarray, radii: np.ndarray, assign: np.ndarray
 ) -> np.ndarray:
+    """(C, C) overlap rates of the partitions under ``method`` (§4.2)."""
     rates = ovl.overlap_matrix(
         method,
         jnp.asarray(pivots),
@@ -101,8 +102,12 @@ def decide(
     method: str,
     xi_min: float,
     xi_max: float,
+    rates: np.ndarray,
 ) -> tuple[list[Partition], DecisionStats]:
     """Apply §4.3 to DBSCAN partitions. Returns final groups + stats.
+
+    ``rates`` is the partitions' ``rate_matrix(method, ...)``: the overlap
+    estimate (§4.2) that the decision acts on.
 
     ``method`` resolves through the overlap-method registry
     (``core.overlap.register_overlap_method``) — the paper's VBM/DBM/OBM are
@@ -117,8 +122,6 @@ def decide(
     stats.distance_computations += c0 * c0  # pivot-pivot distances
     if entry.needs_objects:
         stats.distance_computations += len(x) * c0  # ball membership pass
-
-    rates = _rate_matrix(method, x, pivots, radii, assign)
 
     # ---- high overlap: merge via union-find --------------------------------
     uf = _UnionFind(c0)
@@ -140,7 +143,7 @@ def decide(
     if len(groups) > 1:
         pv = np.stack([g.pivot for g in groups])
         rd = np.array([g.radius for g in groups], np.float32)
-        rates = _rate_matrix(method, x, pv, rd, assign_g)
+        rates = rate_matrix(method, x, pv, rd, assign_g)
         stats.distance_computations += len(groups) ** 2
         if entry.needs_objects:
             stats.distance_computations += len(x) * len(groups)

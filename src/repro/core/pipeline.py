@@ -13,16 +13,19 @@ wraps the implementations here:
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 import warnings
+from collections.abc import Callable
+from contextlib import AbstractContextManager
 from dataclasses import dataclass, field
 from typing import Any
 
 import numpy as np
 
 from repro.core.dbscan import dbscan, partitions_from_labels
-from repro.core.decision import Partition, decide
+from repro.core.decision import Partition, decide, rate_matrix
 from repro.core.forest import ForestArrays, build_forest
 from repro.deprecation import warn_deprecated
 
@@ -72,32 +75,46 @@ def default_delta_capacity(n: int) -> int:
     return max(64, default_c_max(n))
 
 
-def build_index_core(x, cfg: IndexConfig) -> tuple[ForestArrays, BuildReport]:
-    """The paper's pipeline: DBSCAN -> overlap -> decision -> forest."""
+def _untimed(phase: str) -> AbstractContextManager:
+    return contextlib.nullcontext()
+
+
+def build_index_core(
+    x, cfg: IndexConfig, *, span: Callable[[str], AbstractContextManager] = _untimed
+) -> tuple[ForestArrays, BuildReport]:
+    """The paper's pipeline: DBSCAN -> overlap -> decision -> forest.
+
+    Each phase runs inside ``span(phase)``: ``dbscan``, ``overlap``,
+    ``decide``, ``forest`` (the facade passes its registry's ``span``)."""
     t0 = time.perf_counter()
     x = np.asarray(x, np.float32)
     n = len(x)
     c_max = cfg.c_max or default_c_max(n)
     report = BuildReport(config=cfg, n_objects=n)
 
-    # (i) preprocessing — DBSCAN (§4.1)
-    res = dbscan(x, cfg.eps, cfg.min_pts, block=cfg.dbscan_block)
+    # (i) preprocessing — DBSCAN and its partitions (§4.1, Algorithm 1)
+    with span("dbscan"):
+        res = dbscan(x, cfg.eps, cfg.min_pts, block=cfg.dbscan_block)
+        pivots, radii, assign = partitions_from_labels(x, res.labels, res.n_clusters)
     report.dbscan_distances = res.distance_computations
     report.n_clusters = res.n_clusters
-    pivots, radii, assign = partitions_from_labels(x, res.labels, res.n_clusters)
 
-    # (ii)+(iii) overlap estimation + decision (§4.2, §4.3)
-    groups, dstats = decide(
-        x, pivots, radii, assign,
-        method=cfg.method, xi_min=cfg.xi_min, xi_max=cfg.xi_max,
-    )
+    # (ii) overlap estimation (§4.2), (iii) decision (§4.3)
+    with span("overlap"):
+        rates = rate_matrix(cfg.method, x, pivots, radii, assign)
+    with span("decide"):
+        groups, dstats = decide(
+            x, pivots, radii, assign,
+            method=cfg.method, xi_min=cfg.xi_min, xi_max=cfg.xi_max, rates=rates,
+        )
     report.overlap_distances = dstats.distance_computations
     report.n_overlap_indexes = dstats.n_overlap_indexes
 
     # indexing — one BCCF tree per group, GH pivots (§4.3)
-    forest = build_forest(
-        x, groups, c_max=c_max, pivot_method=cfg.pivot_method, seed=cfg.seed
-    )
+    with span("forest"):
+        forest = build_forest(
+            x, groups, c_max=c_max, pivot_method=cfg.pivot_method, seed=cfg.seed
+        )
     report.n_indexes = forest.n_indexes
     report.tree_distances = forest.build_stats["tree_distances"]
     report.tree_comparisons = forest.build_stats["tree_comparisons"]

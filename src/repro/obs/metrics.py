@@ -290,6 +290,20 @@ class Registry:
                     rec["parent_id"] = parent
                 self.events.emit(rec)
 
+    @contextmanager
+    def span_at(self, path: str, **labels) -> Iterator[str | None]:
+        """``span`` recorded under ``path`` itself, whatever spans are open
+        around it: for a phase that runs lazily inside another one (the
+        index's first upload, inside the first search's dispatch)."""
+        outer = self._stack()
+        *parents, name = path.split("/")
+        self._local.stack = parents
+        try:
+            with self.span(name, **labels) as got:
+                yield got
+        finally:
+            self._local.stack = outer
+
     def record_span(self, name: str, dur_s: float, **labels) -> None:
         """Record a span whose duration was measured externally (e.g. queue
         wait = admission time minus submit time): observes the histogram

@@ -76,6 +76,8 @@ SHAPES = [
     (19, 7, 9, 5, 3, 100),
     (100, 24, 12, 20, 1, 10),
     (100, 16, 16, 6, 3, 100),
+    # D=128: lane-aligned, nothing padded (the SIFT shape)
+    (19, 10, 24, 128, 2, 10),
 ]
 
 

@@ -4,14 +4,16 @@ import pytest
 
 from repro.core import decide, dbscan, partitions_from_labels
 from repro.core.bccf import build_tree
-from repro.core.decision import Partition
+from repro.core.decision import Partition, rate_matrix
 
 
 def _setup(blob_data, method):
     x = blob_data[:1200]
     res = dbscan(x, 1.5, 8)
     pivots, radii, assign = partitions_from_labels(x, res.labels, res.n_clusters)
-    groups, stats = decide(x, pivots, radii, assign, method=method, xi_min=0.3, xi_max=0.7)
+    rates = rate_matrix(method, x, pivots, radii, assign)
+    groups, stats = decide(x, pivots, radii, assign, method=method, xi_min=0.3, xi_max=0.7,
+                           rates=rates)
     return x, groups, stats
 
 
@@ -45,7 +47,9 @@ def test_merge_all_when_thresholds_zero(blob_data):
     x = blob_data[:600]
     res = dbscan(x, 1.5, 8)
     pivots, radii, assign = partitions_from_labels(x, res.labels, res.n_clusters)
-    groups, _ = decide(x, pivots, radii, assign, method="dbm", xi_min=0.0, xi_max=0.0)
+    rates = rate_matrix("dbm", x, pivots, radii, assign)
+    groups, _ = decide(x, pivots, radii, assign, method="dbm", xi_min=0.0, xi_max=0.0,
+                       rates=rates)
     # every group disjoint from every other (or single group)
     for i, g in enumerate(groups):
         for j, h in enumerate(groups):

@@ -447,3 +447,53 @@ def test_explain_and_ingest_spans_split_at_the_device(blob_data):
     # explain's copy-back counts its fetches too: d, i, stats, rows, home,
     # and the island stats
     assert idx.obs.value("search.host_fetches") == 6
+
+
+# ---------------------------------------------------------------------------
+# the build's phases and the forest's shape
+# ---------------------------------------------------------------------------
+
+BUILD_SPANS = ("build", "build/dbscan", "build/overlap", "build/decide",
+               "build/forest")
+
+
+def test_build_phases_are_spans_and_the_forest_shape_is_gauged(blob_data, tmp_path):
+    events = tmp_path / "build.jsonl"
+    q = np.asarray(blob_data[:8])
+    got = {}
+    host = _traced_host_events(tmp_path / "trace", lambda: got.setdefault(
+        "ix", OverlapIndex.build(blob_data, _cfg(events_path=str(events)))))
+    idx = got["ix"]
+    m = idx.metrics()["build"]
+    assert set(m["spans"]) == set(BUILD_SPANS)  # no upload before a search
+    assert all(m["spans"][p]["count"] == 1 for p in BUILD_SPANS)
+    phases = sum(m["spans"][p]["sum"] for p in BUILD_SPANS[1:])
+    assert phases <= m["spans"]["build"]["sum"]
+    assert m["indexes"] == idx.n_indexes >= 2
+    assert m["overlap_indexes"] == int(idx.forest.is_overlap_index.sum())
+    assert m["buckets"] == idx.forest.n_buckets
+    # each phase is a profiler annotation nested in ``build``, in order
+    found = {n: (a, b) for n, a, b in host if n in BUILD_SPANS}
+    assert set(found) == set(BUILD_SPANS)
+    lo, hi = found["build"]
+    starts = [found[p][0] for p in BUILD_SPANS[1:]]
+    assert starts == sorted(starts) and lo <= starts[0]
+    assert all(found[p][1] <= hi for p in BUILD_SPANS[1:])
+    # the first search uploads the forest, under build/upload, once
+    idx.search(q, k=5)
+    idx.search(q, k=5)
+    upload = idx.metrics()["build"]["spans"]["build/upload"]
+    assert upload["count"] == 1
+    assert not [p for p in idx.obs.snapshot()["histograms"] if p.endswith("/upload")
+                and p != "build/upload"]
+    logged = {r["span"] for r in EventLog.read(str(events))}
+    assert set(BUILD_SPANS) | {"build/upload"} <= logged
+
+
+def test_disabled_obs_times_no_build_phase(blob_data, tmp_path):
+    got = {}
+    host = _traced_host_events(tmp_path, lambda: got.setdefault(
+        "ix", OverlapIndex.build(blob_data, _cfg(obs=False))))
+    assert not [n for n, _, _ in host if n.startswith("build")]
+    m = got["ix"].metrics()["build"]
+    assert m == {"spans": {}, "indexes": None, "overlap_indexes": None, "buckets": None}

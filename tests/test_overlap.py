@@ -49,6 +49,25 @@ def test_lens_volume_monte_carlo(n_dim):
     assert np.isclose(mc, closed, rtol=0.08), (mc, closed)
 
 
+@pytest.mark.parametrize(
+    "n_dim, lens_share",
+    # I_{sin^2 theta}((n+1)/2, 1/2) / 2 at cos theta = 1/4 (scipy.special.betainc)
+    [(5, 0.27520752), (20, 0.12497295), (128, 0.0019894313)],
+)
+def test_vbm_vanishes_at_128d_where_dbm_does_not(n_dim, lens_share):
+    """Two equal balls at centre distance d = r / 2.  VBM's rate is each
+    cap's share of its ball, which falls with the dimension: at n = 128 it
+    is 0.002, far below xi_min = 0.4, so the pair takes the decision's
+    "low" branch.  DBM's (2r - d) / d, clipped at 1, does not depend on n:
+    above xi_max = 0.8, the pair merges."""
+    r, d = jnp.float32(1.0), jnp.float32(0.5)
+    vbm = float(ovl.vbm_rate(r, r, d, n_dim))
+    assert vbm == pytest.approx(lens_share, rel=1e-4)
+    assert float(ovl.dbm_rate(r, r, d)) == 1.0
+    if n_dim == 128:
+        assert vbm < 0.01 * 0.4
+
+
 def test_dbm_partial_closed_form():
     # partial case: h1 + h2 == r1 + r2 - d  =>  D = (r1 + r2 - d) / d
     r1, r2, d = 2.0, 1.5, 3.0

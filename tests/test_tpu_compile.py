@@ -94,9 +94,10 @@ def test_bucket_scan_compiles(one_chip, dtype, dim, k):
     "nb,cap,dim,k,own_limit",
     [
         (1_634, 707, 5, 10, False),  # the WARD forest (500,000 x 5, c_max 707)
+        (1_785, 632, 128, 10, False),  # the SIFT forest (400,000 x 128, DBM, c_max 632)
         (64, 1_000, 128, 100, True),  # the largest tile the tests build
     ],
-    ids=["ward", "d128-c1000"],
+    ids=["ward", "sift", "d128-c1000"],
 )
 def test_bucket_scan_compiles_at_forest_shapes(one_chip, nb, cap, dim, k, own_limit):
     """Each program holds the 8 tiles of its rows, double-buffered; where
