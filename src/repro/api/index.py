@@ -346,7 +346,7 @@ class OverlapIndex:
         ``router`` are host values (fetched in the caller's copy_back)."""
         obs = self.obs
         obs.counter("search.queries").inc(len(stats["buckets_visited"]))
-        for name in ("buckets_visited", "distances", "bound_distances"):
+        for name in ("buckets_visited", "distances", "bound_distances", "topk_inserts"):
             obs.counter(f"search.{name}").inc(int(stats[name].sum()))
         if router is not None:
             mode = "targeted" if bool(router.targeted) else "all"
@@ -930,6 +930,7 @@ class OverlapIndex:
                 "buckets_visited": obs.value("search.buckets_visited"),
                 "distances": obs.value("search.distances"),
                 "bound_distances": obs.value("search.bound_distances"),
+                "topk_inserts": obs.value("search.topk_inserts"),
                 "host_fetches": obs.value("search.host_fetches"),
                 "host_fetch_bytes": obs.value("search.host_fetch_bytes"),
             },

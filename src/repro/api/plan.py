@@ -233,4 +233,5 @@ def stats_to_host(s: SearchStats) -> dict[str, Any]:
         "padded_distances": host.padded_distances,
         "comparisons": host.comparisons,
         "steps": int(host.steps),
+        "topk_inserts": host.topk_inserts,
     }

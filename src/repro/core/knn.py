@@ -103,6 +103,7 @@ class SearchStats(NamedTuple):
     padded_distances: Array  # (Q,) i32  object distances incl. padding lanes
     comparisons: Array  # (Q,) i32  routing + bound + top-k comparisons
     steps: Array  # () i32  while-loop trip count
+    topk_inserts: Array  # (Q,) i32  candidates that entered the running top-k
 
 
 class IslandStats(NamedTuple):
@@ -187,6 +188,7 @@ class _Carry(NamedTuple):
     visits: Array
     ndist: Array
     npad: Array
+    inserts: Array
 
 
 class ScanOut(NamedTuple):
@@ -204,6 +206,7 @@ class ScanOut(NamedTuple):
     steps: Array  # () i32
     n_elig: Array  # (Q,) i32 eligible main buckets
     n_elig_d: Array  # (Q,) i32 eligible delta buckets
+    inserts: Array  # (Q,) i32 candidates that entered the top-k carry
     # main-phase-only visit counts (visits - visits_main = delta visits);
     # the attribution layer decodes visited rows from this + the sorted
     # visit order.  Appended with a default so positional/keyword
@@ -271,7 +274,7 @@ def _scan_phase(
         bsel = jax.lax.dynamic_slice_in_dim(order, c.t * beam, beam, axis=1)
         # fused gather -> squared-L2 -> running top-k merge (one kernel step;
         # the (Q, beam, C, D) gather never materializes on the kernel path)
-        new_d, new_i = scan_step(
+        new_d, new_i, n_ins = scan_step(
             q, scan_x, scan_ids, bsel, act, c.top_d, c.top_i, scan_scale
         )
         n_members = jnp.where(act, bucket_count[bsel], 0)  # (Q, beam)
@@ -282,6 +285,7 @@ def _scan_phase(
             visits=c.visits + jnp.sum(act, axis=1, dtype=jnp.int32),
             ndist=c.ndist + jnp.sum(n_members, axis=1, dtype=jnp.int32),
             npad=c.npad + jnp.sum(act, axis=1, dtype=jnp.int32) * cap,
+            inserts=c.inserts + n_ins,
         )
 
     return jax.lax.while_loop(cond, body, carry)
@@ -422,6 +426,7 @@ def scan_sorted(
         visits=jnp.zeros((qn,), jnp.int32),
         ndist=jnp.zeros((qn,), jnp.int32),
         npad=jnp.zeros((qn,), jnp.int32),
+        inserts=jnp.zeros((qn,), jnp.int32),
     )
 
     # real (unpadded) member count per bucket, for the cost instrumentation
@@ -476,6 +481,7 @@ def scan_sorted(
         steps=total_steps,
         n_elig=bounds.n_elig,
         n_elig_d=n_elig_d,
+        inserts=out.inserts,
         visits_main=visits_main,
     )
 
@@ -548,6 +554,7 @@ def scan_stats(
         # (npad carries each phase's own bucket capacity)
         + out.npad * jnp.int32(int(np.ceil(np.log2(max(kk, 2))))),
         steps=out.steps,
+        topk_inserts=out.inserts,
     )
 
 

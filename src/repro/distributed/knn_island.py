@@ -223,7 +223,7 @@ def sharded_search(
         # by the out_spec) instead of psum-replicated totals: the caller
         # sums them for SearchStats AND keeps the per-island breakdown
         outs = (top_d, top_i, out.visits[None], out.ndist[None],
-                out.npad[None], out.steps[None])
+                out.npad[None], out.steps[None], out.inserts[None])
         if explain:
             outs += (out.visits_main[None],)
         return outs
@@ -243,7 +243,7 @@ def sharded_search(
         out_specs=bounds_out,
         check_vma=False,
     )
-    scan_out = (P(), P(), row, row, row, P(axis))
+    scan_out = (P(), P(), row, row, row, P(axis), row)
     if explain:
         per_island = True
         scan_out += (row,)
@@ -264,7 +264,7 @@ def sharded_search(
     if have_delta:
         dorder, dlbs, n_elig_d_s = bout[5:]
     sout = scan_fn(forest, q, delta, order, lbs, dorder, dlbs, host_sel)
-    top_d, top_i, visits_s, ndist_s, npad_s, steps_s = sout[:6]
+    top_d, top_i, visits_s, ndist_s, npad_s, steps_s, inserts_s = sout[:7]
     merged = cknn.ScanOut(
         top_d=top_d,
         top_i=top_i,
@@ -274,6 +274,7 @@ def sharded_search(
         steps=jnp.sum(steps_s, dtype=jnp.int32),
         n_elig=jnp.sum(n_elig, axis=0, dtype=jnp.int32),
         n_elig_d=jnp.sum(n_elig_d_s, axis=0, dtype=jnp.int32),
+        inserts=jnp.sum(inserts_s, axis=0, dtype=jnp.int32),
     )
     stats = cknn.scan_stats(route_d[0], route_c[0], merged, kk=kk)
     if not per_island:
@@ -287,7 +288,7 @@ def sharded_search(
     )
     if not explain:
         return jnp.sqrt(top_d), top_i, stats, island
-    visits_main_s = sout[6]
+    visits_main_s = sout[7]
     rows = cknn.VisitRows(
         order=order,
         visits=visits_main_s,

@@ -19,12 +19,19 @@ This kernel fuses the three stages in VMEM:
   rows selected at this beam slot (one ``BlockSpec`` per row on the same
   bucket store, each indexed through ``bsel``), computes each row's
   distances to its own tile (``(8, D) x (C, D)^T`` on the MXU, row r kept),
-  and merges all 8 rows with ONE ``topk.extract_topk`` over
-  ``[running top-k | candidates]``.  The extraction costs as much for 8
-  rows as for 1 (an ``(8, W)`` f32 array is one sublane tile), so one
-  extraction serves 8 rows;
+  and merges all 8 rows at once into their running top-k;
+* the merge (``topk.insert_topk``) is a gated insertion: while some row
+  holds a candidate lexicographically strictly below its k-th (distance,
+  id) entry, each such row inserts its smallest candidate at its place in
+  the sorted top-k (a one-lane shift right from there) and drops its
+  k-th.  The trip count is the most candidates that enter any of the 8
+  rows, set by the data: 0 once the scan's later buckets hold nothing
+  better.  The result is the ``kk`` smallest (distance, id) pairs of the
+  union, bit for bit, duplicates included;
 * the running ``(8, kk)`` top-k block (values + global object ids) stays
-  resident in the output VMEM block across the beam axis.
+  resident in the output VMEM block across the beam axis, beside a count
+  block: each row's insertions in this call, which the search sums into
+  ``SearchStats.topk_inserts``.
 
 Grid: ``(Q/8, beam)``.  The output block depends only on the first axis,
 so the beam axis revisits it (the accumulation pattern of topk.py's N
@@ -49,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.topk import extract_topk
+from repro.kernels.topk import insert_topk
 
 Array = jax.Array
 
@@ -71,14 +78,14 @@ def _scan_kernel(
     act_ref,  # scalar prefetch (Qp * beam,) i32
     q_ref,  # (QB, Dp) the query block
     *rest,  # QB x (1, Cp, Dp) tiles, QB x (1, 1, Cp) ids, [QB x (1, 1, Cp)
-    #         scales,] top_d, top_i, o_val, o_idx
+    #         scales,] top_d, top_i, o_val, o_idx, o_ins
     kk: int,
     beam: int,
     quantized: bool,
 ):
     x_refs, ids_refs = rest[:QB], rest[QB:2 * QB]
     scale_refs = rest[2 * QB:3 * QB] if quantized else None
-    top_d_ref, top_i_ref, o_val_ref, o_idx_ref = rest[(3 if quantized else 2) * QB:]
+    top_d_ref, top_i_ref, o_val_ref, o_idx_ref, o_ins_ref = rest[(3 if quantized else 2) * QB:]
     g = pl.program_id(0)
     b = pl.program_id(1)
 
@@ -86,6 +93,7 @@ def _scan_kernel(
     def _init():
         o_val_ref[...] = top_d_ref[...]
         o_idx_ref[...] = top_i_ref[...]
+        o_ins_ref[...] = jnp.zeros_like(o_ins_ref)
 
     qv = q_ref[...].astype(jnp.float32)  # (QB, Dp)
     qq = jnp.sum(qv * qv, axis=1, keepdims=True)  # (QB, 1)
@@ -112,24 +120,23 @@ def _scan_kernel(
         d2 = jnp.where(mine, jnp.where(live, d2_r, jnp.inf), d2)
         cand_i = jnp.where(mine, jnp.where(live, ids, -1), cand_i)
 
-    kkp = o_val_ref.shape[1]
-    vals = jnp.concatenate([o_val_ref[...], d2], axis=1)  # (QB, kkp + Cp)
-    idxs = jnp.concatenate([o_idx_ref[...], cand_i], axis=1)
-    o_val_ref[...], o_idx_ref[...] = extract_topk(vals, idxs, kk, kkp)
+    top_v, top_i, n = insert_topk(o_val_ref[...], o_idx_ref[...], d2, cand_i, kk)
+    o_val_ref[...], o_idx_ref[...] = top_v, top_i
+    o_ins_ref[...] += n  # (QB, 1) across the block's lanes
 
 
 def _vmem_limit(cp: int, dp: int, kkp: int, itemsize: int, quantized: bool) -> int | None:
     """``vmem_limit_bytes`` for one call, or None where the default will do.
 
     The pipeline double-buffers every block: QB member tiles with their id
-    (and scale) rows, the query block and the four top-k blocks.  The body
-    adds f32 working copies of a tile and, per row, a 128-lane column of
-    squared norms.  In (Cp, 128) f32 columns, the v5e compiler asked for
+    (and scale) rows, the query block, the four top-k blocks and the count
+    block.  The body adds f32 working copies of a tile and, per row, a
+    128-lane column of squared norms.  In (Cp, 128) f32 columns, the v5e compiler asked for
     about 10 beyond the buffers at 128-d and 20 at 576-d (tiles of 1,024 to
     3,200 members); this counts 11 and 23, and a quarter on top.
     """
     rows = QB * cp * 4 * (2 if quantized else 1)
-    blocks = QB * cp * dp * itemsize + rows + QB * dp * 4 + 4 * QB * kkp * 4
+    blocks = QB * cp * dp * itemsize + rows + QB * dp * 4 + 4 * QB * kkp * 4 + QB * 128 * 4
     body = 3 * cp * dp * 4 + QB * cp * 128 * 4
     limit = (2 * blocks + body) * 5 // 4
     return None if limit <= SCOPED_VMEM_BYTES else limit
@@ -201,8 +208,9 @@ def bucket_scan_topk_pallas(
     scale: Array | None = None,  # (NB, C) f32 when bucket_x is int8
     *,
     interpret: bool = False,
-) -> tuple[Array, Array]:
-    """One fused scan step; returns the merged (top_d, top_i), both (Q, kk)."""
+) -> tuple[Array, Array, Array]:
+    """One fused scan step; returns the merged (top_d, top_i), both (Q, kk),
+    and (Q,) i32: the candidates each query's top-k took in this step."""
     qn = q.shape[0]
     beam = bsel.shape[1]
     kk = top_d.shape[1]
@@ -260,14 +268,16 @@ def bucket_scan_topk_pallas(
         out_specs=[
             pl.BlockSpec((QB, kkp), query_block),
             pl.BlockSpec((QB, kkp), query_block),
+            pl.BlockSpec((QB, lane), query_block),
         ],
     )
-    vals, idxs = pl.pallas_call(
+    vals, idxs, ins = pl.pallas_call(
         functools.partial(_scan_kernel, kk=kk, beam=beam, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((qpn, kkp), jnp.float32),
             jax.ShapeDtypeStruct((qpn, kkp), jnp.int32),
+            jax.ShapeDtypeStruct((qpn, lane), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(cp, dp, kkp, xp.dtype.itemsize, quantized)
@@ -283,4 +293,4 @@ def bucket_scan_topk_pallas(
         top_dp,
         top_ip,
     )
-    return vals[:qn, :kk], idxs[:qn, :kk]
+    return vals[:qn, :kk], idxs[:qn, :kk], ins[:qn, 0]

@@ -147,11 +147,13 @@ def bucket_scan_topk(
     top_d: Array,
     top_i: Array,
     scale: Array | None = None,
-) -> tuple[Array, Array]:
+) -> tuple[Array, Array, Array]:
     """Fused forest-scan step: gather ``bsel`` buckets, distances, top-k merge.
 
-    See kernels/bucket_scan.py for the kernel and kernels/ref.py for the
-    oracle.  ``scale`` enables the int8 bucket storage path.
+    Returns the merged (top_d, top_i) and each query's (Q,) count of
+    candidates that entered its top-k.  See kernels/bucket_scan.py for the
+    kernel and kernels/ref.py for the oracle.  ``scale`` enables the int8
+    bucket storage path.
     """
     if _on_tpu():
         return bucket_scan_topk_pallas(q, bucket_x, bucket_ids, bsel, act, top_d, top_i, scale)

@@ -84,14 +84,16 @@ def bucket_scan_topk_ref(
     top_d: Array,
     top_i: Array,
     scale: Array | None = None,
-) -> tuple[Array, Array]:
+) -> tuple[Array, Array, Array]:
     """One forest-scan step: gather selected buckets, distance, top-k merge.
 
     q (Q, D); bucket_x (NB, C, D) f32 or int8 (then ``scale`` (NB, C) holds
     per-member dequant scales); bsel/act (Q, beam); top_d/top_i (Q, kk) the
     running per-query top-k (squared distances ascending, object ids).
     Members with id < 0 (padding) and buckets with act == False contribute
-    nothing.  Returns the merged (top_d, top_i).
+    nothing.  Returns the merged (top_d, top_i) and (Q,) i32 inserts: the
+    candidates that entered the running top-k, bucket by bucket in beam
+    order, as the kernel's insertion merge counts them (``merge_counting``).
     """
     qn, kk = top_d.shape
     q = q.astype(jnp.float32)
@@ -108,18 +110,36 @@ def bucket_scan_topk_ref(
         - 2.0 * jnp.einsum("qbcd,qd->qbc", bx, q, precision=_HIGHEST)
     )
     d2 = jnp.where(live, jnp.maximum(d2, 0.0), jnp.inf)
-    cand_d = d2.reshape(qn, -1)
-    cand_i = jnp.where(live, bids, -1).reshape(qn, -1)
-    merged_d = jnp.concatenate([top_d, cand_d], axis=1)
-    merged_i = jnp.concatenate([top_i, cand_i], axis=1)
-    return topk_by_distance_then_id(merged_d, merged_i, kk)
+    cand_i = jnp.where(live, bids, -1)
+    inserts = jnp.zeros((qn,), jnp.int32)
+    for b in range(bsel.shape[1]):
+        top_d, top_i, n = merge_counting(top_d, top_i, d2[:, b], cand_i[:, b])
+        inserts = inserts + n
+    return top_d, top_i, inserts
+
+
+def merge_counting(
+    top_d: Array, top_i: Array, cand_d: Array, cand_i: Array
+) -> tuple[Array, Array, Array]:
+    """``topk_by_distance_then_id`` of [top | candidates] at the top's width,
+    and (Q,) i32: how many candidates it holds.  Where a candidate equals an
+    entry of the top in (distance, id) the entry stays: the insertion merge
+    takes only candidates strictly below its k-th entry."""
+    kk = top_d.shape[1]
+    d = jnp.concatenate([top_d, cand_d], axis=1)
+    ids = jnp.concatenate([top_i, cand_i], axis=1)
+    src = jnp.concatenate(
+        [jnp.zeros(top_d.shape, jnp.int32), jnp.ones(cand_d.shape, jnp.int32)], axis=1
+    )
+    d, ids, src = jax.lax.sort((d, ids, src), dimension=1, num_keys=3)
+    return d[:, :kk], ids[:, :kk], jnp.sum(src[:, :kk], axis=1, dtype=jnp.int32)
 
 
 def topk_by_distance_then_id(d: Array, ids: Array, k: int) -> tuple[Array, Array]:
     """The ``k`` smallest (distance, id) pairs per row, ascending.  Equal
     distances go to the smaller id, whatever order the candidates arrived
     in: the tie rule of every top-k merge on the search path (this oracle,
-    ``topk.extract_topk`` in the kernels, the cross-shard merge), so an
+    ``topk.insert_topk`` in the kernels, the cross-shard merge), so an
     answer depends neither on visit order nor on which shard held a member.
     Masked candidates carry (+inf, -1)."""
     d, ids = jax.lax.sort((d, ids), dimension=1, num_keys=2)

@@ -15,11 +15,11 @@ Grid: (Q/bq, N/bn); the N axis is sequential (accumulation over the same
 output block).  D is kept whole inside the block (padded to 128): retrieval
 key dims (<= 8K) fit VMEM comfortably at bq = bn = 256.
 
-Top-k maintenance: per N-tile, iteratively extract the k smallest of
-[running top-k | tile distances] (k is small and static — k extraction
-steps of a (bq, kp + bn) masked min, ``extract_topk``, with the running
-top-k padded to kp, a lane multiple).  Indices are tracked through the same
-selection.
+Top-k maintenance: per N-tile, ``insert_topk`` merges the tile's
+distances into the running top-k (padded to kp, a lane multiple), one
+entering candidate per trip, so a tile whose distances all lie above every
+row's k-th costs one gate and no trip.  Indices travel with their values.
+The bucket-scan kernel merges with the same function.
 """
 from __future__ import annotations
 
@@ -28,50 +28,79 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
 
-def extract_topk(vals, idxs, kk, width):
-    """k-step min-extraction of the ``kk`` smallest ``vals`` per row.
+def insert_topk(top_v, top_i, vals, idxs, kk):
+    """Merge candidates into a sorted running top-k, one insertion per step.
 
-    ``vals``/``idxs`` are (R, W); returns (R, width) ascending values and
-    their ids, ``inf``/``-1`` beyond ``kk`` and wherever the pool ran dry.
-    Equal values go to the smaller id (``ref.topk_by_distance_then_id``'s
-    rule), one occurrence per step.  Written with compares and masked
-    reductions only: no gather, no argmin, no lane concatenation of single
-    columns, so the same body compiles for the chip and runs in the
-    interpreter.
+    ``top_v``/``top_i`` are (R, width): each row ascending by (value, id),
+    lanes ``>= kk`` at (inf, -1).  ``vals``/``idxs`` are (R, W) candidates,
+    (inf, -1) where masked; an inf candidate never enters.  Returns the
+    merged (top_v, top_i), the ``kk`` smallest (value, id) pairs of the
+    union taken in (value, id) order, duplicates included, and (R, 1) i32:
+    each row's candidates inserted.
+
+    The loop runs while some row holds a candidate (value, id)
+    lexicographically strictly below that row's k-th entry. Each step, every
+    such row takes its smallest candidate (smaller value, then smaller id,
+    then first position), shifts its entries from the candidate's place one
+    lane right and writes it there; the other rows stay as they are. The
+    k-th entry only falls, so the trip count is the most candidates that
+    enter any one row, and 0 where none can enter. Written with compares,
+    masked reductions and one lane roll: no gather, no argmin, so the same
+    body compiles for the chip and runs in the interpreter.
     """
     rows, w = vals.shape
+    width = top_v.shape[1]
     pos = jax.lax.broadcasted_iota(jnp.int32, (rows, w), 1)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
     big = jnp.int32(jnp.iinfo(jnp.int32).max)
 
-    def step(t, carry):
-        vals, out_v, out_i = carry
-        m = jnp.min(vals, axis=1, keepdims=True)  # (R, 1)
-        tied = vals == m
-        picked = jnp.min(jnp.where(tied, idxs, big), axis=1, keepdims=True)
-        first = jnp.min(
-            jnp.where(tied & (idxs == picked), pos, w), axis=1, keepdims=True
-        )
-        hit = pos == first
-        # An inf extraction means the pool ran dry: what is left is masked
-        # or padded candidates (id -1, or the flat scan's rows past N),
-        # so inf => -1 matches the oracle's contract.
-        picked = jnp.where(jnp.isinf(m), -1, picked)
-        out_v = jnp.where(col == t, m, out_v)
-        out_i = jnp.where(col == t, picked, out_i)
-        return jnp.where(hit, jnp.inf, vals), out_v, out_i
+    def below(v, i, bv, bi):
+        return (v < bv) | ((v == bv) & (i < bi))
 
-    init = (
-        vals,
-        jnp.full((rows, width), jnp.inf, jnp.float32),
-        jnp.full((rows, width), -1, jnp.int32),
-    )
-    _, out_v, out_i = jax.lax.fori_loop(0, kk, step, init)
-    return out_v, out_i
+    def kth(tv, ti):
+        at = col == kk - 1
+        return (
+            jnp.min(jnp.where(at, tv, jnp.inf), axis=1, keepdims=True),
+            jnp.min(jnp.where(at, ti, big), axis=1, keepdims=True),
+        )
+
+    def cond(c):
+        pool, _, _, kv, ki, _ = c
+        # a full reduction of an f32 mask: what the chip reduces to a scalar
+        return jnp.max(jnp.where(below(pool, idxs, kv, ki), 1.0, 0.0)) > 0.0
+
+    def body(c):
+        pool, tv, ti, kv, ki, n = c
+        m = jnp.min(pool, axis=1, keepdims=True)  # (R, 1)
+        tied = pool == m
+        mi = jnp.min(jnp.where(tied, idxs, big), axis=1, keepdims=True)
+        first = jnp.min(jnp.where(tied & (idxs == mi), pos, w), axis=1, keepdims=True)
+        enter = below(m, mi, kv, ki)  # (R, 1)
+        # entries below the candidate keep their lanes; its place is lane 0
+        # or the first lane whose left neighbour stays; lanes after it take
+        # their left neighbour
+        sv = pltpu.roll(tv, shift=1, axis=1)
+        si = pltpu.roll(ti, shift=1, axis=1)
+        stay = below(tv, ti, m, mi)
+        here = (col == 0) | below(sv, si, m, mi)
+        nv = jnp.where(stay, tv, jnp.where(here, m, sv))
+        ni = jnp.where(stay, ti, jnp.where(here, mi, si))
+        if kk < width:
+            nv = jnp.where(col < kk, nv, jnp.inf)
+            ni = jnp.where(col < kk, ni, -1)
+        tv = jnp.where(enter, nv, tv)
+        ti = jnp.where(enter, ni, ti)
+        pool = jnp.where(enter & (pos == first), jnp.inf, pool)
+        return (pool, tv, ti, *kth(tv, ti), n + enter.astype(jnp.int32))
+
+    init = (vals, top_v, top_i, *kth(top_v, top_i), jnp.zeros((rows, 1), jnp.int32))
+    _, top_v, top_i, _, _, n = jax.lax.while_loop(cond, body, init)
+    return top_v, top_i, n
 
 
 def _knn_topk_kernel(q_ref, x_ref, o_val_ref, o_idx_ref, *, k: int, bn: int, n_real: int):
@@ -95,9 +124,7 @@ def _knn_topk_kernel(q_ref, x_ref, o_val_ref, o_idx_ref, *, k: int, bn: int, n_r
     gidx = j * bn + jax.lax.broadcasted_iota(jnp.int32, (d2.shape[0], bn), 1)
     d2 = jnp.where(gidx < n_real, d2, jnp.inf)
 
-    vals = jnp.concatenate([o_val_ref[...], d2], axis=1)  # (bq, kp + bn)
-    idxs = jnp.concatenate([o_idx_ref[...], gidx], axis=1)
-    new_v, new_i = extract_topk(vals, idxs, k, o_val_ref.shape[1])
+    new_v, new_i, _ = insert_topk(o_val_ref[...], o_idx_ref[...], d2, gidx, k)
     o_val_ref[...] = new_v
     o_idx_ref[...] = new_i
 

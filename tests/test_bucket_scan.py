@@ -6,8 +6,11 @@ distances, D not a multiple of the tile width, beam not dividing NB — plus
 the end-to-end exactness guarantee that the kernelized ``mode='all'``
 search still matches brute force.
 """
-import numpy as np
+import functools
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import IndexConfig, build_baseline, knn_exact, knn_search_host
@@ -15,6 +18,7 @@ from repro.core.knn import device_forest, knn_search
 from repro.kernels import ref
 from repro.kernels.bucket_scan import bucket_scan_topk_pallas
 from repro.kernels.ops import quantize_datastore
+from repro.kernels.topk import insert_topk
 
 
 def _problem(rng, qn, nb, cap, dim, beam, kk, *, pad_frac=0.3, seeded_topk=True):
@@ -84,8 +88,8 @@ SHAPES = [
 @pytest.mark.parametrize("qn,nb,cap,dim,beam,kk", SHAPES)
 def test_bucket_scan_matches_ref(qn, nb, cap, dim, beam, kk, rng):
     q, bx, ids, bsel, act, top_d, top_i = _problem(rng, qn, nb, cap, dim, beam, kk)
-    rd, ri = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
-    kd, ki = bucket_scan_topk_pallas(
+    rd, ri, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    kd, ki, _ = bucket_scan_topk_pallas(
         q, bx, ids, bsel, act, top_d, top_i, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-5, atol=1e-5)
@@ -104,10 +108,10 @@ def test_bucket_scan_splits_query_batches_past_smem(rng, monkeypatch):
     qn, nb, cap, dim, beam, kk = 37, 6, 5, 7, 3, 6  # a shape no other test traces
     q, bx, ids, bsel, act, top_d, top_i = _problem(rng, qn, nb, cap, dim, beam, kk)
     monkeypatch.setattr(bucket_scan, "SMEM_WORDS", 16)  # 8 rows per call
-    kd, ki = bucket_scan_topk_pallas(
+    kd, ki, _ = bucket_scan_topk_pallas(
         q, bx, ids, bsel, act, top_d, top_i, interpret=True
     )
-    rd, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    rd, _, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-5, atol=1e-5)
     _check_ids_achieve_values(q, bx, ids, kd, ki)
 
@@ -117,8 +121,8 @@ def test_bucket_scan_fewer_than_k_reachable(rng):
     q, bx, ids, bsel, act, top_d, top_i = _problem(
         rng, 3, 4, 3, 5, 2, 9, pad_frac=0.8, seeded_topk=False
     )
-    rd, ri = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
-    kd, ki = bucket_scan_topk_pallas(
+    rd, ri, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    kd, ki, _ = bucket_scan_topk_pallas(
         q, bx, ids, bsel, act, top_d, top_i, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-5, atol=1e-5)
@@ -127,8 +131,8 @@ def test_bucket_scan_fewer_than_k_reachable(rng):
 
 def test_bucket_scan_dry_pool_keeps_ids_unique(rng):
     """Partially filled top-k + a step contributing NO live candidates: the
-    kernel's min-extraction must not re-emit an already-extracted id once
-    the pool runs dry (regression: argmin over an all-inf row points at an
+    kernel's merge must not re-emit an id already in the top-k once the
+    pool runs dry (regression: argmin over an all-inf row points at an
     arbitrary slot)."""
     qn, nb, cap, dim, beam, kk = 2, 3, 4, 5, 2, 5
     q = jnp.asarray(rng.normal(size=(qn, dim)), jnp.float32)
@@ -138,8 +142,8 @@ def test_bucket_scan_dry_pool_keeps_ids_unique(rng):
     act = jnp.zeros((qn, beam), bool)  # ...and nothing is active anyway
     top_d = jnp.array([[1.0, 2.5, jnp.inf, jnp.inf, jnp.inf]] * qn, jnp.float32)
     top_i = jnp.array([[42, 7, -1, -1, -1]] * qn, jnp.int32)
-    rd, ri = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
-    kd, ki = bucket_scan_topk_pallas(
+    rd, ri, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    kd, ki, _ = bucket_scan_topk_pallas(
         q, bx, ids, bsel, act, top_d, top_i, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-6, atol=1e-6)
@@ -165,8 +169,8 @@ def test_bucket_scan_duplicate_distances(rng):
     act = jnp.ones((qn, beam), bool)
     top_d = jnp.full((qn, kk), jnp.inf)
     top_i = jnp.full((qn, kk), -1, jnp.int32)
-    rd, ri = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
-    kd, ki = bucket_scan_topk_pallas(
+    rd, ri, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    kd, ki, _ = bucket_scan_topk_pallas(
         q, bx, ids, bsel, act, top_d, top_i, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-5, atol=1e-5)
@@ -184,8 +188,8 @@ def test_bucket_scan_int8_matches_ref(rng):
     xq, scale = quantize_datastore(bx.reshape(nb * cap, dim))
     bxq = xq.reshape(nb, cap, dim)
     bscale = scale.reshape(nb, cap)
-    rd, _ = ref.bucket_scan_topk_ref(q, bxq, ids, bsel, act, top_d, top_i, bscale)
-    kd, _ = bucket_scan_topk_pallas(
+    rd, _, _ = ref.bucket_scan_topk_ref(q, bxq, ids, bsel, act, top_d, top_i, bscale)
+    kd, _, _ = bucket_scan_topk_pallas(
         q, bxq, ids, bsel, act, top_d, top_i, bscale, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-4, atol=1e-4)
@@ -198,12 +202,130 @@ def test_bucket_scan_int8_blocks_match_ref(rng):
     xq, scale = quantize_datastore(bx.reshape(nb * cap, dim))
     bxq = xq.reshape(nb, cap, dim)
     bscale = scale.reshape(nb, cap)
-    rd, ri = ref.bucket_scan_topk_ref(q, bxq, ids, bsel, act, top_d, top_i, bscale)
-    kd, ki = bucket_scan_topk_pallas(
+    rd, ri, _ = ref.bucket_scan_topk_ref(q, bxq, ids, bsel, act, top_d, top_i, bscale)
+    kd, ki, _ = bucket_scan_topk_pallas(
         q, bxq, ids, bsel, act, top_d, top_i, bscale, interpret=True
     )
     np.testing.assert_allclose(np.asarray(kd), np.asarray(rd), rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(np.asarray(ki)[4], np.asarray(top_i)[4])  # inactive
+
+
+MERGE_CASES = ["ties", "duplicate_ids", "first_fill", "inactive_block", "dead_rows"]
+
+
+def _int_problem(rng, case, kk):
+    """Integer coordinates, so every squared distance is exact in f32 and the
+    kernel's distances are the oracle's bit for bit: what is left to differ
+    is the merge.  19 rows (three 8-row blocks, the last partial), C = 13
+    (not a multiple of 8), beam 2; the carry is a sorted top-k of the oracle.
+    """
+    qn, nb, cap, dim, beam = 19, 9, 13, 3, 2
+    span = 1 if case == "ties" else 4  # coordinates in [-1, 1]: many ties
+    q = jnp.asarray(rng.integers(-span, span + 1, (qn, dim)), jnp.float32)
+    bx = jnp.asarray(rng.integers(-span, span + 1, (nb, cap, dim)), jnp.float32)
+    ids = np.arange(nb * cap, dtype=np.int32).reshape(nb, cap)
+    ids[rng.random((nb, cap)) < 0.2] = -1
+    if case == "dead_rows":
+        ids[0] = -1  # rows that select bucket 0 have no live candidate
+    ids = jnp.asarray(ids)
+    bsel = jnp.asarray(rng.integers(0, nb, (qn, beam)), jnp.int32)
+    act = np.ones((qn, beam), bool)
+    if case == "inactive_block":
+        act[8:16] = False  # the second block: no row scans anything
+    elif case == "dead_rows":
+        bsel = bsel.at[::3].set(0)
+    act = jnp.asarray(act)
+    empty_d, empty_i = jnp.full((qn, kk), jnp.inf), jnp.full((qn, kk), -1, jnp.int32)
+    if case == "first_fill":
+        return q, bx, ids, bsel, act, empty_d, empty_i
+    if case == "duplicate_ids":
+        # the carry already holds this very step's candidates: every one of
+        # them arrives again with the same (distance, id)
+        top_d, top_i, _ = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, empty_d, empty_i)
+        return q, bx, ids, bsel, act, top_d, top_i
+    n = max(1, kk // 2)  # a half-full carry of integer distances
+    top_d, top_i, _ = ref.merge_counting(
+        empty_d, empty_i,
+        jnp.asarray(rng.integers(0, 8 * span * span * dim, (qn, n)), jnp.float32),
+        jnp.asarray(rng.integers(0, nb * cap, (qn, n)), jnp.int32),
+    )
+    return q, bx, ids, bsel, act, top_d, top_i
+
+
+@pytest.mark.parametrize("kk", [1, 10, 100])
+@pytest.mark.parametrize("case", MERGE_CASES)
+def test_insertion_merge_matches_ref_bitwise(case, kk, rng):
+    """The gated insertion merge keeps the oracle's (distance, id) top-k bit
+    for bit, duplicates included, and counts the same insertions per row."""
+    q, bx, ids, bsel, act, top_d, top_i = _int_problem(rng, case, kk)
+    rd, ri, rn = ref.bucket_scan_topk_ref(q, bx, ids, bsel, act, top_d, top_i)
+    kd, ki, kn = bucket_scan_topk_pallas(
+        q, bx, ids, bsel, act, top_d, top_i, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(kd), np.asarray(rd))
+    np.testing.assert_array_equal(np.asarray(ki), np.asarray(ri))
+    np.testing.assert_array_equal(np.asarray(kn), np.asarray(rn))
+    kd, ki, kn = np.asarray(kd), np.asarray(ki), np.asarray(kn)
+    assert np.array_equal(np.isinf(kd), ki == -1)
+    if case == "inactive_block":
+        assert (kn[8:16] == 0).all()
+        np.testing.assert_array_equal(ki[8:16], np.asarray(top_i)[8:16])
+    if case == "dead_rows":
+        dead = np.asarray(act).all(1) & (np.asarray(bsel) == 0).all(1)
+        assert dead.any() and (kn[dead] == 0).all()
+    if case == "first_fill":
+        # a cold top-k ends holding min(live, k) candidates, each inserted
+        # once; a later bucket may push out what an earlier one put in
+        live = (np.asarray(ids)[np.asarray(bsel)] >= 0).sum((1, 2))
+        assert (kn >= np.minimum(live, kk)).all() and (kn > 0).all()
+    if case == "duplicate_ids":
+        # a candidate equal to an entry below the k-th enters beside it; one
+        # equal to the k-th itself does not
+        if kk == 1:
+            assert kn.sum() == 0
+        else:
+            assert any(len(set(r[r >= 0])) < (r >= 0).sum() for r in ki)
+
+
+def _merge_kernel(tv_ref, ti_ref, cv_ref, ci_ref, ov_ref, oi_ref, on_ref, *, kk):
+    ov_ref[...], oi_ref[...], n = insert_topk(
+        tv_ref[...], ti_ref[...], cv_ref[...], ci_ref[...], kk
+    )
+    on_ref[...] = jnp.broadcast_to(n, on_ref.shape)
+
+
+@pytest.mark.parametrize("kk", [1, 10, 100])
+def test_insert_topk_keeps_lanes_past_k_empty(kk, rng):
+    """Across the carry's whole lane width: lanes below k are the oracle's
+    top-k of [carry | candidates], lanes from k on stay (inf, -1)."""
+    from jax.experimental import pallas as pl
+
+    rows, width, w = 8, 128, 40
+    cand_d = jnp.asarray(rng.integers(0, 30, (rows, w)), jnp.float32)
+    cand_i = jnp.asarray(rng.integers(0, 50, (rows, w)), jnp.int32)
+    cand_d = jnp.where(cand_i < 5, jnp.inf, cand_d)  # masked candidates
+    cand_i = jnp.where(cand_i < 5, -1, cand_i)
+    seed_d = jnp.asarray(rng.integers(0, 30, (rows, kk)), jnp.float32)
+    seed_i = jnp.asarray(rng.integers(0, 50, (rows, kk)), jnp.int32)
+    empty_d, empty_i = jnp.full((rows, kk), jnp.inf), jnp.full((rows, kk), -1, jnp.int32)
+    top_d, top_i, _ = ref.merge_counting(empty_d, empty_i, seed_d[:, : kk // 2], seed_i[:, : kk // 2])
+    want_d, want_i, want_n = ref.merge_counting(top_d, top_i, cand_d, cand_i)
+    pad = ((0, 0), (0, width - kk))
+    ov, oi, on = pl.pallas_call(
+        functools.partial(_merge_kernel, kk=kk),
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, width), jnp.float32),
+            jax.ShapeDtypeStruct((rows, width), jnp.int32),
+            jax.ShapeDtypeStruct((rows, width), jnp.int32),
+        ],
+        interpret=True,
+    )(jnp.pad(top_d, pad, constant_values=jnp.inf), jnp.pad(top_i, pad, constant_values=-1),
+      cand_d, cand_i)
+    ov, oi = np.asarray(ov), np.asarray(oi)
+    np.testing.assert_array_equal(ov[:, :kk], np.asarray(want_d))
+    np.testing.assert_array_equal(oi[:, :kk], np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(on)[:, 0], np.asarray(want_n))
+    assert np.isinf(ov[:, kk:]).all() and (oi[:, kk:] == -1).all()
 
 
 @pytest.fixture()
@@ -236,6 +358,7 @@ def test_search_beam_not_dividing_nb(small_forest, monkeypatch, beam):
     np.testing.assert_allclose(d_k, d_ref, rtol=1e-4, atol=1e-4)
     assert np.array_equal(s_k["buckets_visited"], s_ref["buckets_visited"])
     assert np.array_equal(s_k["distances"], s_ref["distances"])
+    assert np.array_equal(s_k["topk_inserts"], s_ref["topk_inserts"])
 
 
 def test_kernelized_mode_all_exact(small_forest, monkeypatch):

@@ -55,6 +55,20 @@ def test_knn_topk_matches_ref(q_n, x_n, d, k, rng):
     np.testing.assert_allclose(picked, np.asarray(gv), rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("k", [1, 10, 100])
+def test_knn_topk_ties_go_to_smaller_index(k, rng):
+    # small integers: every distance is exact and most of them tie, within
+    # an N-tile and across tiles, so the merge's tie rule decides the answer
+    q = jnp.asarray(rng.integers(0, 3, size=(20, 6)), jnp.float32)
+    x = jnp.asarray(rng.integers(0, 3, size=(230, 6)), jnp.float32)
+    gv, gi = knn_topk_pallas(q, x, k=k, bq=16, bn=64, interpret=True)
+    d2 = ref.pairwise_sq_l2_ref(q, x)
+    ids = jnp.broadcast_to(jnp.arange(x.shape[0], dtype=jnp.int32), d2.shape)
+    wv, wi = ref.topk_by_distance_then_id(d2, ids, k)
+    np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv))
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
+
+
 def test_knn_topk_fewer_rows_than_k(rng):
     q = jnp.asarray(rng.normal(size=(4, 8)), jnp.float32)
     x = jnp.asarray(rng.normal(size=(3, 8)), jnp.float32)

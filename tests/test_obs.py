@@ -330,6 +330,7 @@ def test_stats_to_host_single_device_get(monkeypatch):
         padded_distances=jnp.ones((4,), jnp.int32),
         comparisons=jnp.ones((4,), jnp.int32),
         steps=jnp.int32(3),
+        topk_inserts=jnp.ones((4,), jnp.int32),
     )
     calls = []
     real = jax.device_get
@@ -342,7 +343,7 @@ def test_stats_to_host_single_device_get(monkeypatch):
     host = stats_to_host(stats)
     assert len(calls) == 1  # ONE batched fetch, not one per field
     assert set(host) == {"buckets_visited", "distances", "bound_distances",
-                         "padded_distances", "comparisons", "steps"}
+                         "padded_distances", "comparisons", "steps", "topk_inserts"}
     assert isinstance(host["steps"], int)
 
 
@@ -398,7 +399,7 @@ def test_search_counts_its_host_fetches(blob_data):
     q = np.asarray(blob_data[:8])
     d, i, s, isl, router, _ = idx._search_planned(q, k=5)
     assert router is None
-    assert len(jax.tree.leaves(s)) == 6 and len(jax.tree.leaves(isl)) == 3
+    assert len(jax.tree.leaves(s)) == 7 and len(jax.tree.leaves(isl)) == 3
     nbytes = sum(a.nbytes for a in jax.tree.leaves((d, i, s, isl)))
     fetches0 = idx.obs.value("search.host_fetches")
     bytes0 = idx.obs.value("search.host_fetch_bytes")
@@ -409,6 +410,25 @@ def test_search_counts_its_host_fetches(blob_data):
     m = idx.metrics()["search"]
     assert m["host_fetches"] == fetches0 + 4
     assert m["host_fetch_bytes"] == bytes0 + nbytes
+
+
+def test_search_counts_topk_inserts(blob_data):
+    """``search.topk_inserts`` adds up each search's per-query count of
+    candidates that entered the running top-k; a disabled registry keeps
+    nothing, though the result still carries the count."""
+    idx = OverlapIndex.build(blob_data, _cfg())
+    off = OverlapIndex.build(blob_data, _cfg(obs=False))
+    q = np.asarray(blob_data[:8])
+    before = idx.obs.value("search.topk_inserts")
+    res = idx.search(q, k=5)
+    got = res.stats["topk_inserts"]
+    assert got.shape == (len(q),) and (got >= 5).all()  # k entries enter a cold top-k
+    assert idx.obs.value("search.topk_inserts") - before == int(got.sum())
+    assert idx.metrics()["search"]["topk_inserts"] == before + int(got.sum())
+    res_off = off.search(q, k=5)
+    np.testing.assert_array_equal(res_off.stats["topk_inserts"], got)
+    assert off.obs.value("search.topk_inserts") == 0
+    assert off.obs.counters() == {}
 
 
 def test_disabled_obs_adds_no_annotations_or_counters(blob_data, tmp_path):

@@ -68,7 +68,7 @@ def _compile(fn, *shapes, sharding):
     return hlo
 
 
-@pytest.mark.parametrize("k", [10, 100])
+@pytest.mark.parametrize("k", [1, 10, 100])
 @pytest.mark.parametrize("dim", [DB1_DIM, 128])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8], ids=["f32", "int8"])
 def test_bucket_scan_compiles(one_chip, dtype, dim, k):
@@ -88,6 +88,26 @@ def test_bucket_scan_compiles(one_chip, dtype, dim, k):
         ((QUERIES, k), jnp.int32),
     ] + ([((nb, cap), jnp.float32)] if quantized else [])
     _compile(step, *shapes, sharding=one_chip)
+
+
+@pytest.mark.parametrize("dim", [DB1_DIM, 128])
+def test_bucket_scan_compiles_with_every_row_inactive(one_chip, dim):
+    """A step in which no row scans its bucket: the merge's loop runs no
+    trip.  The activity mask is a constant the compiler sees."""
+    nb, cap = DB1_BUCKETS, DB1_CAPACITY
+
+    def step(q, bx, ids, bsel, top_d, top_i):
+        bx, ids, _ = prepad_buckets(bx, ids)
+        act = jnp.zeros(bsel.shape, jnp.bool_)
+        return bucket_scan_topk_pallas(q, bx, ids, bsel, act, top_d, top_i)
+
+    _compile(
+        step,
+        ((QUERIES, dim), jnp.float32), ((nb, cap, dim), jnp.float32),
+        ((nb, cap), jnp.int32), ((QUERIES, 1), jnp.int32),
+        ((QUERIES, 10), jnp.float32), ((QUERIES, 10), jnp.int32),
+        sharding=one_chip,
+    )
 
 
 @pytest.mark.parametrize(
