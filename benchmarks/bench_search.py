@@ -102,17 +102,15 @@ def run(
     full: bool = False,
     out: dict | None = None,
     *,
-    kernel: bool = True,
     quantize: bool = False,
     smoke: bool = False,
     shards: int = 1,
     route: int = 0,
     obs: bool = True,
 ) -> None:
-    """``kernel`` routes all search distances through the kernels/ops
-    dispatch layer (fused Pallas bucket scan on TPU); ``quantize`` stores
-    bucket members int8 on device.  Recall is reported either way, so the
-    kernelized path's exactness (mode='all' vs brute force) is visible.
+    """``quantize`` stores bucket members int8 on device.  Recall is
+    reported either way, so the search's exactness (mode='all' vs brute
+    force) is visible.
 
     ``shards > 1`` runs the sweep under the sharded layout and compares
     every result bitwise against a single-device index built over the same
@@ -139,15 +137,14 @@ def run(
             method: OverlapIndex.build(
                 ds.x, facade_config(
                     ds, method, shards=n_hosts, route=routed, obs=obs,
-                    kernel=kernel, quantize=quantize,
+                    quantize=quantize,
                 )
             )
             for method in METHODS
         }
         indexes["bccf"] = OverlapIndex.baseline(
             ds.x, baseline_config(
-                ds, shards=n_hosts, route=routed, obs=obs, kernel=kernel,
-                quantize=quantize,
+                ds, shards=n_hosts, route=routed, obs=obs, quantize=quantize,
             )
         )
         refs: dict = {}
@@ -156,29 +153,26 @@ def run(
             # single-device references for the bitwise divergence gate
             refs = {
                 method: OverlapIndex.build(
-                    ds.x, facade_config(
-                        ds, method, kernel=kernel, quantize=quantize
-                    )
+                    ds.x, facade_config(ds, method, quantize=quantize)
                 )
                 for method in METHODS
             }
             refs["bccf"] = OverlapIndex.baseline(
-                ds.x, baseline_config(ds, kernel=kernel, quantize=quantize)
+                ds.x, baseline_config(ds, quantize=quantize)
             )
         if routed:
             # fan-all references: the plain sharded layout on the same mesh
             refs_fanall = {
                 method: OverlapIndex.build(
                     ds.x, facade_config(
-                        ds, method, shards=n_hosts, kernel=kernel,
-                        quantize=quantize,
+                        ds, method, shards=n_hosts, quantize=quantize,
                     )
                 )
                 for method in METHODS
             }
             refs_fanall["bccf"] = OverlapIndex.baseline(
                 ds.x, baseline_config(
-                    ds, shards=n_hosts, kernel=kernel, quantize=quantize
+                    ds, shards=n_hosts, quantize=quantize
                 )
             )
         for method, ix in indexes.items():
@@ -246,7 +240,7 @@ def run(
             emit(f"search/{ds.name}/{method}/plans", 0.0,
                  f"plan_cache={ix.plans.stats()}")
     write_artifact("search", meta=dict(
-        full=full, smoke=smoke, kernel=kernel, quantize=quantize,
+        full=full, smoke=smoke, quantize=quantize,
         shards=n_hosts, route=route, obs=obs,
     ))
     if diverged:
@@ -263,8 +257,6 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--smoke", action="store_true", help="CI-sized run")
-    ap.add_argument("--no-kernel", action="store_true",
-                    help="bypass kernels/ops dispatch (pure-jnp reference path)")
     ap.add_argument("--quantize", action="store_true",
                     help="int8 bucket member storage (device_forest knob)")
     ap.add_argument("--shards", type=int, default=1,
@@ -279,5 +271,5 @@ if __name__ == "__main__":
                     "measuring the metrics layer's own overhead")
     a = ap.parse_args()
     enable_compile_cache()
-    run(full=a.full, kernel=not a.no_kernel, quantize=a.quantize,
+    run(full=a.full, quantize=a.quantize,
         smoke=a.smoke, shards=a.shards, route=a.route, obs=not a.no_obs)

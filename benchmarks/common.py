@@ -88,13 +88,13 @@ def layout_config(shards: int = 1, route: bool = False) -> LayoutConfig:
 
 def facade_config(
     ds: BenchDataset, method: str, *, shards: int = 1, route: bool = False,
-    obs: bool = True, **search,
+    obs: bool = True, quantize: bool = False,
 ) -> Config:
     """Full Config tree for OverlapIndex.build over a bench dataset.
     ``obs=False`` disables the telemetry registry (overhead comparisons)."""
     return Config(
         index=index_config(ds, method),
-        search=SearchConfig(**search),
+        search=SearchConfig(quantize=quantize),
         layout=layout_config(shards, route),
         obs=ObsConfig(enabled=obs),
     )
@@ -102,7 +102,7 @@ def facade_config(
 
 def baseline_config(
     ds: BenchDataset, *, shards: int = 1, route: bool = False,
-    obs: bool = True, **search,
+    obs: bool = True, quantize: bool = False,
 ) -> Config:
     """BCCF baseline config: documented 'kmeans' pivot semantics, explicit
     so the honored-pivot warning never fires in benchmarks."""
@@ -110,7 +110,7 @@ def baseline_config(
 
     return Config(
         index=dataclasses.replace(index_config(ds, "vbm"), pivot_method="kmeans"),
-        search=SearchConfig(**search),
+        search=SearchConfig(quantize=quantize),
         layout=layout_config(shards, route),
         obs=ObsConfig(enabled=obs),
     )

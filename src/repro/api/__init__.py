@@ -31,7 +31,6 @@ from repro.core.overlap import (
     register_overlap_method,
     unregister_overlap_method,
 )
-from repro.deprecation import RepoDeprecationWarning
 
 __all__ = [
     "Config", "ConfigError", "IndexConfig", "LayoutConfig", "ObsConfig",
@@ -41,5 +40,4 @@ __all__ = [
     "PlanCache", "PlanKey", "SearchPlan", "SearchResult",
     "OverlapMethod", "available_overlap_methods", "get_overlap_method",
     "register_overlap_method", "unregister_overlap_method",
-    "RepoDeprecationWarning",
 ]

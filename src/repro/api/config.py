@@ -1,9 +1,8 @@
 """One frozen configuration tree for the whole index lifecycle.
 
 ``Config(index=IndexConfig, search=SearchConfig, stream=StreamConfig)``
-replaces the scattered constructor kwargs that used to be threaded by hand
-through ``build_index`` / ``knn_search`` / ``StreamingForest`` /
-``ForestDatastore``.  Every field is validated at construction with an
+is the one set of knobs ``OverlapIndex`` takes for build, search, ingest
+and maintenance.  Every field is validated at construction with an
 actionable message (``ConfigError``) — a typo like ``method="vbmm"`` fails
 here, naming the registered alternatives, instead of deep inside the
 decision stage.
@@ -98,7 +97,6 @@ class SearchConfig:
     k: int = 10
     mode: str = "forest"  # forest (Alg. 2 routing) | all (exact, no routing)
     beam: int = 1  # buckets evaluated per scan step
-    kernel: bool = True  # kernels/ops dispatch (Pallas on TPU) vs jnp ref
     quantize: bool = False  # int8 bucket-member storage on device
 
     def __post_init__(self) -> None:

@@ -16,10 +16,12 @@ resolved from ``cfg.layout`` (``make_backend``) owns
                        single backend wraps ``core.knn.knn_search_impl`` /
                        ``stream.ingest.ingest_impl``; the sharded backend
                        returns the ``distributed/knn_island.py`` islands.
-                       Search bodies return ``(dists, ids, SearchStats,
-                       IslandStats)`` — the fourth element is the telemetry
-                       layer's per-island node-access breakdown (one row
-                       per shard; a singleton row on the single layout),
+                       One search body per layout serves both plan kinds:
+                       it returns ``(dists, ids, SearchStats, IslandStats)``
+                       — the fourth element is the telemetry layer's
+                       per-island node-access breakdown (one row per shard;
+                       a singleton row on the single layout) — plus
+                       ``VisitRows`` when ``key.explain`` is set,
   * swap barrier     — ``barrier``: the sharded layout blocks until every
                        shard's new arrays are materialized before a
                        maintenance rebuild swaps them in, keeping
@@ -45,7 +47,6 @@ from repro.core.knn import (
     DeviceForest,
     IslandStats,
     device_forest,
-    knn_search_explain_impl,
     knn_search_impl,
 )
 from repro.kernels import ops as kops
@@ -74,9 +75,8 @@ class SingleDeviceBackend:
 
     def search_body(self, key):
         def body(forest, q, delta):
-            d, i, s = knn_search_impl(
-                forest, q, k=key.k, mode=key.mode, beam=key.beam,
-                kernel=key.kernel, delta=delta,
+            d, i, s, rows = knn_search_impl(
+                forest, q, k=key.k, mode=key.mode, beam=key.beam, delta=delta,
             )
             # one island: the per-island telemetry view is the fleet view
             # with a leading singleton dim (free — no extra device work)
@@ -85,22 +85,8 @@ class SingleDeviceBackend:
                 distances=s.distances[None],
                 bound_distances=s.bound_distances[None],
             )
-            return d, i, s, isl
-
-        return body
-
-    def explain_body(self, key):
-        def body(forest, q, delta):
-            d, i, s, rows = knn_search_explain_impl(
-                forest, q, k=key.k, mode=key.mode, beam=key.beam,
-                kernel=key.kernel, delta=delta,
-            )
-            isl = IslandStats(
-                buckets_visited=s.buckets_visited[None],
-                distances=s.distances[None],
-                bound_distances=s.bound_distances[None],
-            )
-            return d, i, s, isl, rows
+            # a search plan drops the visit rows: dead code under jax.jit
+            return (d, i, s, isl, rows) if key.explain else (d, i, s, isl)
 
         return body
 
@@ -227,18 +213,8 @@ class ShardedBackend:
         def body(forest, q, delta):
             return self._island.sharded_search(
                 self.mesh, self.axis, forest, q, delta,
-                k=key.k, mode=key.mode, beam=key.beam, kernel=key.kernel,
-                per_island=True,
-            )
-
-        return body
-
-    def explain_body(self, key):
-        def body(forest, q, delta):
-            return self._island.sharded_search(
-                self.mesh, self.axis, forest, q, delta,
-                k=key.k, mode=key.mode, beam=key.beam, kernel=key.kernel,
-                per_island=True, explain=True,
+                k=key.k, mode=key.mode, beam=key.beam,
+                per_island=True, explain=key.explain,
             )
 
         return body
@@ -307,21 +283,8 @@ class RoutedBackend(ShardedBackend):
             forest, table = operands
             return self._router.routed_search(
                 self.mesh, self.axis, forest, q, delta, table,
-                k=key.k, mode=key.mode, beam=key.beam, kernel=key.kernel,
-                fanout=fanout, per_island=True,
-            )
-
-        return body
-
-    def explain_body(self, key):
-        fanout = key.fanout or self.routing.fanout
-
-        def body(operands, q, delta):
-            forest, table = operands
-            return self._router.routed_search(
-                self.mesh, self.axis, forest, q, delta, table,
-                k=key.k, mode=key.mode, beam=key.beam, kernel=key.kernel,
-                fanout=fanout, per_island=True, explain=True,
+                k=key.k, mode=key.mode, beam=key.beam,
+                fanout=fanout, per_island=True, explain=key.explain,
             )
 
         return body

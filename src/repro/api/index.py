@@ -18,11 +18,9 @@ Internally the facade owns: the host ``ForestArrays`` (+ fresh tree
 copies), the device ``DeviceForest`` upload (quantized per config), the
 streaming ``DeltaBuffer`` (allocated lazily on first ingest), the overlap
 drift monitor, and a ``PlanCache`` of compiled search executors — repeated
-searches with stable options/shapes never re-trace.
-
-Everything that used to be wired by hand across ``build_index`` /
-``knn_search`` / ``StreamingForest`` / ``ForestDatastore`` hangs off this
-object; those surfaces remain as deprecation shims.
+searches with stable options/shapes never re-trace.  Every search is
+compiled through that cache and the layout backend (``api/executor.py``):
+the facade is the one public read path.
 """
 from __future__ import annotations
 
@@ -37,14 +35,13 @@ from repro.api.config import (
     SEARCH_MODES,
     Config,
     ConfigError,
-    IndexConfig,
     LayoutConfig,
     as_index_config,
 )
 from repro.api.executor import make_backend
 from repro.api.plan import PlanCache, PlanKey, SearchResult, stats_to_host
 from repro.core.forest import ForestArrays
-from repro.core.knn import DeviceForest, SearchStats, route_points
+from repro.core.knn import DeviceForest, route_points
 from repro.core.overlap import get_overlap_method, overlap_matrix
 from repro.core.pipeline import (
     BuildReport,
@@ -262,7 +259,7 @@ class OverlapIndex:
         return self._delta
 
     # -- read path: planner + cached executors -------------------------------
-    def _plan_key(self, k, mode, beam, kernel) -> PlanKey:
+    def _plan_key(self, k, mode, beam, explain) -> PlanKey:
         # per-call overrides get the SAME validation the config tree does —
         # a bad k/beam/mode must fail here with an actionable error, not
         # deep inside the jitted executor (and never poison the plan cache)
@@ -271,10 +268,10 @@ class OverlapIndex:
             k=sc.k if k is None else int(k),
             mode=sc.mode if mode is None else mode,
             beam=sc.beam if beam is None else int(beam),
-            kernel=sc.kernel if kernel is None else bool(kernel),
             quantize=sc.quantize,
             delta_capacity=None if self._delta is None else self.capacity,
             shards=self.backend.shards,
+            explain=explain,
             # routed layout: the dispatch policy is a static compile knob —
             # None elsewhere keeps single/sharded plan keys unchanged
             fanout=(
@@ -293,36 +290,62 @@ class OverlapIndex:
             raise ConfigError(f"search beam={key.beam} must be >= 1")
         return key
 
-    def _search_device(
-        self, q, *, k=None, mode=None, beam=None, kernel=None
-    ) -> tuple[Any, Any, SearchStats]:
-        """Raw device triple (dists, ids, SearchStats) through the plan
-        cache — the serving/benchmark path that stays on device."""
-        with self.obs.span("search"):
-            d, i, s, *_ = self._search_planned(
-                q, k=k, mode=mode, beam=beam, kernel=kernel
-            )
-        return d, i, s
+    def _search_planned(self, q, *, k=None, mode=None, beam=None, explain=False):
+        """The plan_lookup and dispatch phases of one search, as spans under
+        the caller's outer span (``search`` or ``explain``).  dispatch only
+        enqueues the program: the wait for the device is ``_run``'s
+        device_wait span.
 
-    def _search_planned(self, q, *, k=None, mode=None, beam=None, kernel=None):
-        # phase spans nest under whichever outer span is active ("search"
-        # from both public entries), giving search/plan_lookup and
-        # search/dispatch.  dispatch only enqueues the program: the wait
-        # for the device is the caller's device_wait span.
+        Returns the device values ``(d, i, SearchStats, IslandStats,
+        RouterStats | None, plan)``; an explain plan appends its
+        ``VisitRows`` and each query's routed index (``home``)."""
         with self.obs.span("plan_lookup"):
-            key = self._plan_key(k, mode, beam, kernel)
+            key = self._plan_key(k, mode, beam, explain)
             plan = self.plans.plan(key, self.backend)
             plan.calls += 1
             delta = None if self._delta is None else delta_view(self._delta)
         with self.obs.span("dispatch"):
+            qj = jnp.asarray(q, jnp.float32)
             outs = plan.executor(
-                self.backend.search_operands(self.device),
-                jnp.asarray(q, jnp.float32), delta,
+                self.backend.search_operands(self.device), qj, delta
             )
-        # routed executors append RouterStats; everything else is 4 long
-        d, i, s, isl = outs[:4]
-        router = outs[4] if len(outs) > 4 else None
-        return d, i, s, isl, router, plan
+            # (d, i, SearchStats, IslandStats[, VisitRows][, RouterStats])
+            d, i, s, isl = outs[:4]
+            n = 5 if explain else 4
+            router = outs[n] if len(outs) > n else None
+            if not explain:
+                return d, i, s, isl, router, plan
+            # home = the routed index, computed with the DEVICE routing op
+            # so tie-breaks match the executor — on one device: the
+            # compiler cannot partition a Pallas call over the sharded
+            # layouts' replicated centers
+            _, home = route_points(jnp.asarray(self.forest.index_centers), qj)
+        return d, i, s, isl, router, plan, outs[4], home
+
+    def _run(self, q, k, mode, beam, *, explain=False):
+        """One search through the plan cache: ``_search_planned``, then the
+        device_wait and copy_back spans.  Returns ``(SearchResult, isl,
+        router, evidence)`` on the host.  ``isl``/``router`` (island and
+        router telemetry) are fetched only when the registry is enabled;
+        ``evidence`` is ``[VisitRows, home]`` for an explain plan, else
+        empty."""
+        obs = self.obs
+        d, i, s, isl, router, plan, *evidence = self._search_planned(
+            q, k=k, mode=mode, beam=beam, explain=explain
+        )
+        with obs.span("device_wait"):
+            jax.block_until_ready((d, i, s, isl, router, evidence))
+        with obs.span("copy_back"):
+            d, i = self._fetch(d, np.asarray), self._fetch(i, np.asarray)
+            stats = self._fetch(s, stats_to_host)
+            evidence = [self._fetch(x) for x in evidence]
+            if obs.enabled:  # island/router stats only feed the registry
+                isl, router = self._fetch(isl), self._fetch(router)
+        kk = min(plan.key.k, self.n_total)  # Def. 4: |X| <= k -> all
+        if d.shape[1] > kk:
+            d, i = d[:, :kk], i[:, :kk]
+        res = SearchResult(dists=d, ids=i, stats=stats, plan=plan)
+        return res, isl, router, evidence
 
     def _fetch(self, x, get=jax.device_get):
         """One blocking device-to-host fetch of ``x`` (a pytree of device
@@ -400,8 +423,7 @@ class OverlapIndex:
 
     def search(
         self, q, *, k: int | None = None, mode: str | None = None,
-        beam: int | None = None, kernel: bool | None = None,
-        trace: TraceContext | None = None,
+        beam: int | None = None, trace: TraceContext | None = None,
     ) -> SearchResult:
         """kNN over forest + streaming delta.  Defaults come from
         ``cfg.search``; per-call overrides select (or create) the matching
@@ -420,28 +442,15 @@ class OverlapIndex:
         self._searches_since_swap += 1
         obs.gauge("maintenance.rebuild_age").set(self._searches_since_swap)
         with use_trace(ctx), obs.span("search"):
-            d, i, s, isl, router, plan = self._search_planned(
-                q, k=k, mode=mode, beam=beam, kernel=kernel
-            )
-            with obs.span("device_wait"):
-                jax.block_until_ready((d, i, s, isl, router))
-            with obs.span("copy_back"):
-                d, i = self._fetch(d, np.asarray), self._fetch(i, np.asarray)
-                stats = self._fetch(s, stats_to_host)
-                if obs.enabled:  # island/router stats only feed the registry
-                    isl, router = self._fetch(isl), self._fetch(router)
+            res, isl, router, _ = self._run(q, k, mode, beam)
             with obs.span("record"):
                 if obs.enabled:
-                    self._record_search(stats, isl, router)
-                kk = min(plan.key.k, self.n_total)  # Def. 4: |X| <= k -> all
-                if d.shape[1] > kk:
-                    d, i = d[:, :kk], i[:, :kk]
-        return SearchResult(dists=d, ids=i, stats=stats, plan=plan)
+                    self._record_search(res.stats, isl, router)
+        return res
 
     def explain(
         self, q, *, k: int | None = None, mode: str | None = None,
-        beam: int | None = None, kernel: bool | None = None,
-        feed_monitor: bool = True,
+        beam: int | None = None, feed_monitor: bool = True,
     ) -> ExplainReport:
         """Search + overlap attribution: which bucket visits CONTRIBUTED a
         final top-k member, which were WASTED, and which (visited, home)
@@ -458,47 +467,14 @@ class OverlapIndex:
         """
         obs = self.obs
         with obs.span("explain"):
-            with obs.span("plan_lookup"):
-                key = self._plan_key(k, mode, beam, kernel)._replace(
-                    explain=True
-                )
-                plan = self.plans.plan(key, self.backend)
-                plan.calls += 1
-                delta = (
-                    None if self._delta is None else delta_view(self._delta)
-                )
-            qj = jnp.asarray(q, jnp.float32)
-            with obs.span("dispatch"):
-                outs = plan.executor(
-                    self.backend.search_operands(self.device), qj, delta
-                )
-                d, i, s, isl, rows = outs[:5]
-                router = outs[5] if len(outs) > 5 else None
-                # home = the routed index, computed with the DEVICE routing
-                # op (same kernel flag) so tie-breaks match the executor —
-                # on one device: the compiler cannot partition a Pallas call
-                # over the sharded layouts' replicated centers
-                _, home = route_points(
-                    jnp.asarray(self.forest.index_centers), qj,
-                    kernel=key.kernel,
-                )
-            with obs.span("device_wait"):
-                jax.block_until_ready((d, i, s, isl, rows, router, home))
-            with obs.span("copy_back"):
-                d, i = self._fetch(d, np.asarray), self._fetch(i, np.asarray)
-                stats = self._fetch(s, stats_to_host)
-                rows = self._fetch(rows)
-                home = self._fetch(home, np.asarray)
-                if obs.enabled:
-                    isl, router = self._fetch(isl), self._fetch(router)
+            res, isl, router, (rows, home) = self._run(
+                q, k, mode, beam, explain=True
+            )
             if obs.enabled:
-                self._record_search(stats, isl, router)
-            kk = min(key.k, self.n_total)
-            if d.shape[1] > kk:
-                d, i = d[:, :kk], i[:, :kk]
+                self._record_search(res.stats, isl, router)
             with obs.span("attribute"):
-                report = self._attribute(rows, i, home)
-        report.result = SearchResult(dists=d, ids=i, stats=stats, plan=plan)
+                report = self._attribute(rows, res.ids, home)
+        report.result = res
         if obs.enabled:
             obs.counter("explain.queries").inc(report.queries)
             obs.counter("explain.contributing").inc(

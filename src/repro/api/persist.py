@@ -165,9 +165,13 @@ def load_state(path) -> dict[str, Any]:
         # rebuild the dataclass (absent in pre-routing snapshots -> defaults)
         layout_d = dict(cfg_d.get("layout", {}))
         layout_d["routing"] = RoutingConfig(**layout_d.get("routing", {}))
+        # snapshots saved while SearchConfig had a ``kernel`` field carry
+        # it; that retired key is dropped, any other unknown key still fails
+        search_d = dict(cfg_d["search"])
+        search_d.pop("kernel", None)
         cfg = Config(
             index=IndexConfig(**cfg_d["index"]),
-            search=SearchConfig(**cfg_d["search"]),
+            search=SearchConfig(**search_d),
             stream=StreamConfig(**cfg_d["stream"]),
             # absent in pre-layout (v1 era) snapshots -> single-device
             layout=LayoutConfig(**layout_d),

@@ -1,12 +1,11 @@
 """Search planning: one cached, jitted executor per static-option tuple.
 
-``knn_search`` used to be one big ``jax.jit`` whose cache was invisible —
-every caller paid tracing whenever *any* static knob or operand shape moved,
-and nobody could observe it.  The facade splits that into
+Every search is compiled here, and only here — an observable cache in
+place of one big ``jax.jit`` whose cache nobody could see:
 
   * ``PlanKey``     — the static options a compiled executor is specialized
-                      on: ``(k, mode, beam, kernel, quantize, delta
-                      capacity, shards)``;
+                      on: ``(k, mode, beam, quantize, delta capacity,
+                      shards, explain, fanout)``;
   * ``SearchPlan``  — the key plus a ``jax.jit``-wrapped closure over the
                       layout backend's executor body (the single-device
                       ``core.knn.knn_search_impl`` or the sharded
@@ -33,13 +32,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import numpy as np
 
-from repro.core.knn import (
-    DeltaView,
-    DeviceForest,
-    SearchStats,
-    knn_search_explain_impl,
-    knn_search_impl,
-)
+from repro.core.knn import DeltaView, DeviceForest, SearchStats
 
 
 class PlanKey(NamedTuple):
@@ -48,7 +41,6 @@ class PlanKey(NamedTuple):
     k: int
     mode: str
     beam: int
-    kernel: bool
     quantize: bool
     delta_capacity: int | None  # None: no delta phase compiled in
     shards: int = 1  # device layout (1: single; >1: sharded island)
@@ -68,21 +60,19 @@ class SearchPlan:
     """A compiled search program for one ``PlanKey``.
 
     ``executor(device_forest, q, delta)`` returns the raw device 4-tuple
-    ``(dists, ids, SearchStats, IslandStats | None)`` — the fourth element
-    carries per-executor-island node-access counters (leading dim = shard
-    count; the single layout reports one island) for the telemetry layer,
-    or ``None`` on the legacy backend-less path.  Explain plans
-    (``key.explain``) append a fifth element, ``core.knn.VisitRows`` — the
-    per-query visited-row evidence the attribution layer decodes.  Routed
-    executors (``key.fanout`` set) append one further trailing element,
-    ``distributed.router.RouterStats`` — the facade unpacks by position
-    from the front and treats any extra trailing element as router
-    telemetry.  The first operand is whatever the backend's
+    ``(dists, ids, SearchStats, IslandStats)`` — the fourth element carries
+    per-executor-island node-access counters (leading dim = shard count;
+    the single layout reports one island) for the telemetry layer.
+    Explain plans (``key.explain``) append a fifth element,
+    ``core.knn.VisitRows`` — the per-query visited-row evidence the
+    attribution layer decodes.  Routed executors (``key.fanout`` set)
+    append one further trailing element, ``distributed.router.RouterStats``
+    — the facade unpacks by position from the front and treats any extra
+    trailing element as router telemetry.  The first operand is whatever the backend's
     ``search_operands`` wraps (the bare forest, or (forest, table) on the
-    routed layout).
-    ``traces`` counts actual
-    jax traces (option tuple is fixed, so a trace means a new operand
-    shape/dtype); ``calls`` counts executions through this plan.
+    routed layout).  ``traces`` counts actual jax traces (option tuple is
+    fixed, so a trace means a new operand shape/dtype); ``calls`` counts
+    executions through this plan.
     """
 
     key: PlanKey
@@ -91,30 +81,9 @@ class SearchPlan:
     calls: int = 0
 
 
-def _build_plan(key: PlanKey, backend=None) -> SearchPlan:
+def _build_plan(key: PlanKey, backend) -> SearchPlan:
     plan = SearchPlan(key=key)
-    if backend is None:
-        # no layout backend (legacy/direct use): the single-device executor,
-        # normalized to the 4-tuple contract (no island breakdown)
-        if key.explain:
-            def body(forest: DeviceForest, q, delta: DeltaView | None):
-                d, i, s, rows = knn_search_explain_impl(
-                    forest, q, k=key.k, mode=key.mode, beam=key.beam,
-                    kernel=key.kernel, delta=delta,
-                )
-                return d, i, s, None, rows
-        else:
-            def body(forest: DeviceForest, q, delta: DeltaView | None):
-                d, i, s = knn_search_impl(
-                    forest, q, k=key.k, mode=key.mode, beam=key.beam,
-                    kernel=key.kernel, delta=delta,
-                )
-                return d, i, s, None
-    else:
-        body = (
-            backend.explain_body(key) if key.explain
-            else backend.search_body(key)
-        )
+    body = backend.search_body(key)
 
     def _impl(forest: DeviceForest, q, delta: DeltaView | None):
         # Runs only while jax traces (compiled executions skip python):
@@ -152,7 +121,7 @@ class PlanCache:
         if self._obs is not None:
             self._obs.counter(name).inc()
 
-    def plan(self, key: PlanKey, backend=None) -> SearchPlan:
+    def plan(self, key: PlanKey, backend) -> SearchPlan:
         got = self._plans.get(key)
         if got is None:
             self.misses += 1
@@ -219,8 +188,8 @@ class SearchResult:
 
 
 def stats_to_host(s: SearchStats) -> dict[str, Any]:
-    """SearchStats device arrays -> the host dict shape the benchmarks and
-    the legacy ``knn_search_host`` wrapper always reported.
+    """SearchStats device arrays -> the host dict ``SearchResult.stats``
+    and the benchmarks report.
 
     ONE ``jax.device_get`` of the whole NamedTuple: per-field ``np.asarray``
     issued six blocking device->host transfers (each waiting on the same

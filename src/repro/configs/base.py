@@ -53,7 +53,6 @@ class RetrievalConfig:
     datastore_size: int = 65536  # per model shard
     key_dim: int = 0             # 0 -> d_model
     quantized: bool = False      # int8 datastore (beyond-paper)
-    kernel: bool = True          # route distances through kernels/ops dispatch
 
 
 @dataclass(frozen=True)

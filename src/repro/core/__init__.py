@@ -10,10 +10,7 @@ from repro.core.knn import (
     SearchStats,
     device_forest,
     knn_exact,
-    knn_search,
-    knn_search_host,
     knn_search_impl,
-    knn_search_jit,
     route_eligibility,
     route_points,
 )
@@ -35,9 +32,7 @@ from repro.core.overlap import (
 from repro.core.pipeline import (
     BuildReport,
     IndexConfig,
-    build_baseline,
     build_baseline_core,
-    build_index,
     build_index_core,
     default_c_max,
     default_delta_capacity,
@@ -48,14 +43,12 @@ __all__ = [
     "DecisionStats", "Partition", "decide",
     "ForestArrays", "build_forest", "swap_trees",
     "DeltaView", "DeviceForest", "SearchStats", "device_forest",
-    "knn_exact", "knn_search", "knn_search_host", "knn_search_impl",
-    "knn_search_jit",
+    "knn_exact", "knn_search_impl",
     "route_eligibility", "route_points",
     "OverlapMethod", "available_overlap_methods", "get_overlap_method",
     "register_overlap_method", "unregister_overlap_method",
     "ball_log_volume", "cap_log_volume", "dbm_rate", "intersection_log_volume",
     "max_neighbor_rate", "obm_rate", "overlap_matrix", "vbm_rate",
-    "BuildReport", "IndexConfig", "build_baseline", "build_baseline_core",
-    "build_index", "build_index_core",
+    "BuildReport", "IndexConfig", "build_baseline_core", "build_index_core",
     "default_c_max", "default_delta_capacity",
 ]

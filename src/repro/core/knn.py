@@ -28,8 +28,8 @@ nearest leaf).
 prove the scan is EXACT against brute force, and by callers who want exact
 global kNN at higher cost.
 
-Streaming deltas (repro.stream): ``knn_search(..., delta=DeltaView)`` runs a
-SECOND bounded scan phase over the per-index delta tail buckets (the
+Streaming deltas (repro.stream): ``knn_search_impl(..., delta=DeltaView)``
+runs a SECOND bounded scan phase over the per-index delta tail buckets (the
 device-resident append buffers of stream/ingest.py), seeded with the main
 phase's top-k carry.  The delta buckets behave exactly like forest buckets
 (pivot/radius lower bounds, same fused kernel step); because lower bounds are
@@ -59,7 +59,6 @@ import numpy as np
 
 from repro.core.forest import ForestArrays
 from repro.core.metric import pairwise
-from repro.deprecation import warn_deprecated
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
@@ -147,14 +146,14 @@ def device_forest(f: ForestArrays, *, quantize: bool = False) -> DeviceForest:
     )
 
 
-def route_points(centers: Array, q: Array, *, kernel: bool = True) -> tuple[Array, Array]:
+def route_points(centers: Array, q: Array) -> tuple[Array, Array]:
     """Alg. 2 STEP 1 routing: distances to index centers + closest index.
 
-    Shared by the query path (knn_search) and the streaming ingest router
+    Shared by the query path (knn_search_impl) and the streaming ingest router
     (stream/ingest.ingest) — both assign a point to its nearest index center.
     Returns (d_idx (Q, I) squared distances, closest (Q,) i32).
     """
-    d_idx = pairwise(q, centers, metric="sq_l2", use_kernel=kernel)  # (Q, I)
+    d_idx = pairwise(q, centers, metric="sq_l2")  # (Q, I)
     return d_idx, jnp.argmin(d_idx, axis=1).astype(jnp.int32)
 
 
@@ -210,8 +209,8 @@ class ScanOut(NamedTuple):
     # main-phase-only visit counts (visits - visits_main = delta visits);
     # the attribution layer decodes visited rows from this + the sorted
     # visit order.  Appended with a default so positional/keyword
-    # constructions that predate it stay valid; dead on the normal search
-    # path (DCE'd out of compiled executors that don't return it).
+    # constructions that predate it stay valid; dead in a search plan
+    # (DCE'd out of compiled executors that don't return VisitRows).
     visits_main: Array | None = None
 
 
@@ -292,7 +291,7 @@ def _scan_phase(
 
 
 def route_select(
-    forest: DeviceForest, q: Array, *, mode: str = "forest", kernel: bool = True
+    forest: DeviceForest, q: Array, *, mode: str = "forest"
 ) -> tuple[Array, Array, Array]:
     """Alg. 2 STEP 1: per-query index selection + the routing cost counters.
 
@@ -304,7 +303,7 @@ def route_select(
     qn = q.shape[0]
     n_idx = forest.index_centers.shape[0]
     if mode == "forest":
-        _, closest = route_points(forest.index_centers, q, kernel=kernel)
+        _, closest = route_points(forest.index_centers, q)
         sel = route_eligibility(closest, forest.neighbors)  # (Q, I)
         route_dists = jnp.full((qn,), n_idx, jnp.int32)
         route_cmps = jnp.full((qn,), n_idx, jnp.int32)
@@ -354,7 +353,6 @@ def bucket_bounds(
     bucket_sel: Array,
     *,
     beam: int = 1,
-    kernel: bool = True,
 ) -> PhaseBounds:
     """STEP 2a over the main bucket rows: eligibility -> pivot lower bounds
     -> sorted visit order.
@@ -364,14 +362,17 @@ def bucket_bounds(
     SPMD partitioner miscompiles sort-feeds-while on manually sharded
     operands; see ``distributed/knn_island.sharded_search``).  The
     single-device path simply calls both stages back to back — identical
-    ops, identical results.
+    ops, identical results.  ``bucket_sel`` (Q, I') is the selection table
+    indexed by ``forest.bucket_index``; I' may exceed the true index count
+    so that the sharded layout's pad buckets can point at an always-False
+    sentinel column.
     """
     elig = bucket_sel[:, forest.bucket_index]  # (Q, NB) -> sel[q, owner(b)]
     # Bounds are only *used* for eligible buckets (ineligible ones are masked
     # to +inf below), so the paper's Fig. 21 cost metric charges exactly the
     # eligible count per query — not all NB rows of the distance matrix.
     n_elig = jnp.sum(elig, axis=1, dtype=jnp.int32)  # (Q,)
-    d_piv = pairwise(q, forest.bucket_pivot, metric="l2", use_kernel=kernel)  # (Q, NB)
+    d_piv = pairwise(q, forest.bucket_pivot, metric="l2")  # (Q, NB)
     lb = jnp.maximum(d_piv - forest.bucket_radius[None, :], 0.0)
     lb = jnp.where(elig, lb, jnp.inf)
     order, lb_sorted, _ = _sorted_bounds(lb, beam)
@@ -384,14 +385,13 @@ def delta_bounds(
     delta_sel: Array,
     *,
     beam: int = 1,
-    kernel: bool = True,
 ) -> PhaseBounds:
     """STEP 2a over the delta rows (one streaming bucket per index; empty
     buffers are never eligible)."""
     dcount = jnp.sum(delta.mask, axis=1, dtype=jnp.int32)  # (I_d,)
     elig_d = delta_sel & (dcount[None, :] > 0)  # (Q, I_d)
     n_elig_d = jnp.sum(elig_d, axis=1, dtype=jnp.int32)
-    d_piv_d = pairwise(q, delta.pivot, metric="l2", use_kernel=kernel)
+    d_piv_d = pairwise(q, delta.pivot, metric="l2")
     lb_d = jnp.maximum(d_piv_d - delta.radius[None, :], 0.0)
     lb_d = jnp.where(elig_d, lb_d, jnp.inf)
     order_d, lb_d_sorted, _ = _sorted_bounds(lb_d, beam)
@@ -405,7 +405,6 @@ def scan_sorted(
     *,
     kk: int,
     beam: int = 1,
-    kernel: bool = True,
     delta: DeltaView | None = None,
     dbounds: PhaseBounds | None = None,
     qmask: Array | None = None,
@@ -431,24 +430,17 @@ def scan_sorted(
 
     # real (unpadded) member count per bucket, for the cost instrumentation
     bucket_count = jnp.sum(forest.bucket_mask, axis=1, dtype=jnp.int32)  # (NB,)
-    if kernel:
-        # tile-align the datastore-sized operands ONCE, outside the loop —
-        # the kernel wrapper's defensive per-call pads become no-ops
-        scan_x, scan_ids, scan_scale = kops.bucket_scan_prepad(
-            forest.bucket_x, forest.bucket_ids, forest.bucket_scale
-        )
-        scan_step = kops.bucket_scan_topk
-    else:
-        scan_x, scan_ids, scan_scale = (
-            forest.bucket_x, forest.bucket_ids, forest.bucket_scale,
-        )
-        scan_step = kref.bucket_scan_topk_ref
+    # tile-align the datastore-sized operands ONCE, outside the loop — the
+    # kernel wrapper's defensive per-call pads become no-ops
+    scan_x, scan_ids, scan_scale = kops.bucket_scan_prepad(
+        forest.bucket_x, forest.bucket_ids, forest.bucket_scale
+    )
 
     # order/lb_sorted are padded to exactly n_steps*beam (``_sorted_bounds``)
     n_steps = jnp.int32(bounds.order.shape[1] // beam)
     out = _scan_phase(
         init, q, bounds.order, bounds.lb_sorted, n_steps, beam,
-        scan_step, scan_x, scan_ids, scan_scale, bucket_count, cap,
+        kops.bucket_scan_topk, scan_x, scan_ids, scan_scale, bucket_count, cap,
         qmask=qmask,
     )
     total_steps = out.t
@@ -458,15 +450,11 @@ def scan_sorted(
     if delta is not None:
         dcap = delta.x.shape[1]
         dcount = jnp.sum(delta.mask, axis=1, dtype=jnp.int32)  # (I_d,)
-        if kernel:
-            dx, dids, _ = kops.bucket_scan_prepad(delta.x, delta.ids, None)
-            dstep = kops.delta_scan_topk
-        else:
-            dx, dids, dstep = delta.x, delta.ids, kref.bucket_scan_topk_ref
+        dx, dids, _ = kops.bucket_scan_prepad(delta.x, delta.ids, None)
         n_steps_d = jnp.int32(dbounds.order.shape[1] // beam)
         out = _scan_phase(
             out._replace(t=jnp.int32(0)), q, dbounds.order, dbounds.lb_sorted,
-            n_steps_d, beam, dstep, dx, dids, None, dcount, dcap,
+            n_steps_d, beam, kops.delta_scan_topk, dx, dids, None, dcount, dcap,
             qmask=qmask,
         )
         total_steps = total_steps + out.t
@@ -483,43 +471,6 @@ def scan_sorted(
         n_elig_d=n_elig_d,
         inserts=out.inserts,
         visits_main=visits_main,
-    )
-
-
-def local_scan(
-    forest: DeviceForest,
-    q: Array,
-    bucket_sel: Array,
-    *,
-    kk: int,
-    beam: int = 1,
-    kernel: bool = True,
-    delta: DeltaView | None = None,
-    delta_sel: Array | None = None,
-) -> ScanOut:
-    """STEP 2 executor body over the bucket rows AND delta rows it is given.
-
-    The single-device path passes the whole forest; the sharded island calls
-    the split stages (``bucket_bounds``/``delta_bounds`` in one island,
-    ``scan_sorted`` in another) per shard on the LOCAL bucket/delta rows —
-    the scan itself never knows which.  ``bucket_sel`` (Q, I') is the
-    selection table indexed by ``forest.bucket_index``; I' may exceed the
-    true index count so that padded shard-alignment buckets can point at an
-    always-False sentinel column.  ``delta_sel`` (Q, I_d) selects per delta
-    row (defaults to ``bucket_sel``).
-
-    Returns the raw ``ScanOut``: top-kk carry (squared distances) + cost
-    counters, ready for ``merge_shard_topk`` / ``scan_stats``.
-    """
-    bounds = bucket_bounds(forest, q, bucket_sel, beam=beam, kernel=kernel)
-    dbounds = None
-    if delta is not None:
-        if delta_sel is None:
-            delta_sel = bucket_sel
-        dbounds = delta_bounds(delta, q, delta_sel, beam=beam, kernel=kernel)
-    return scan_sorted(
-        forest, q, bounds, kk=kk, beam=beam, kernel=kernel,
-        delta=delta, dbounds=dbounds,
     )
 
 
@@ -558,56 +509,6 @@ def scan_stats(
     )
 
 
-def knn_search_impl(
-    forest: DeviceForest,
-    q: Array,
-    *,
-    k: int,
-    mode: str = "forest",
-    beam: int = 1,
-    kernel: bool = True,
-    delta: DeltaView | None = None,
-) -> tuple[Array, Array, SearchStats]:
-    """Batched kNN over the forest. Returns (dists (Q,k), ids (Q,k), stats).
-
-    This is the EXECUTOR: a pure, un-jitted function.  The facade's planner
-    (``repro.api.plan.SearchPlan``) closes a ``jax.jit`` over it once per
-    static-option tuple ``(k, mode, beam, kernel, quantize, delta shape)``
-    and caches the compiled executable so repeated searches with stable
-    shapes never re-trace.  ``knn_search`` below is the legacy jitted entry,
-    kept as a deprecation shim.
-
-    dists are true L2 distances; ids are global object ids (-1 if fewer than
-    k objects were reachable).
-
-    ``kernel=True`` (default) routes every distance — STEP 1 routing, STEP 2a
-    bucket bounds, and the STEP 2b fused gather+distance+top-k bucket scan —
-    through the ``repro.kernels.ops`` dispatch layer (compiled Pallas on TPU,
-    interpret under REPRO_FORCE_PALLAS=1, jnp reference elsewhere).
-    ``kernel=False`` forces the pure-jnp reference path end to end.
-
-    ``delta`` (a DeltaView) adds the streaming delta buckets as a second scan
-    phase: the same bounded best-first scan, seeded with the main phase's
-    top-k carry, over the per-index append buffers.  Results are then exact
-    over main forest + delta members (within the mode's selection semantics).
-    """
-    n_idx = forest.index_centers.shape[0]
-    nb, cap, _ = forest.bucket_x.shape
-    n_cap = nb * cap
-    if delta is not None:
-        n_cap += n_idx * delta.x.shape[1]
-    kk = min(k, n_cap)
-
-    sel, route_dists, route_cmps = route_select(forest, q, mode=mode, kernel=kernel)
-    sel = widen_underfilled(sel, index_members(forest, delta), kk)
-    out = local_scan(
-        forest, q, sel, kk=kk, beam=beam, kernel=kernel,
-        delta=delta, delta_sel=sel,
-    )
-    stats = scan_stats(route_dists, route_cmps, out, kk=kk)
-    return jnp.sqrt(out.top_d), out.top_i, stats
-
-
 class VisitRows(NamedTuple):
     """Per-query visited-row evidence for the attribution layer
     (``obs/attribution.py``) — one uniform layout across device layouts.
@@ -635,24 +536,37 @@ class VisitRows(NamedTuple):
     dvisits: Array | None  # (S, Q) i32 delta-phase visited counts per shard
 
 
-def knn_search_explain_impl(
+def knn_search_impl(
     forest: DeviceForest,
     q: Array,
     *,
     k: int,
     mode: str = "forest",
     beam: int = 1,
-    kernel: bool = True,
     delta: DeltaView | None = None,
 ) -> tuple[Array, Array, SearchStats, VisitRows]:
-    """``knn_search_impl`` + the visited-row evidence (``VisitRows``).
+    """Batched kNN over the forest.
 
-    Runs the IDENTICAL op sequence as the normal executor — same routing,
-    same bounds, same scan bodies with the same operands — and additionally
-    returns the sorted visit orders and per-phase visit counts that were
-    already computed along the way.  Results are therefore bitwise-identical
-    to ``knn_search_impl`` (gated in-suite); the extra outputs are arrays
-    the normal path computes and discards, not extra device work.
+    Returns (dists (Q,k), ids (Q,k), SearchStats, VisitRows).  dists are
+    true L2 distances; ids are global object ids (-1 if fewer than k
+    objects were reachable).  ``VisitRows`` is the visited-row evidence the
+    attribution layer decodes: arrays the scan computes along the way, so a
+    caller that drops it (inside ``jax.jit``) compiles none of it.
+
+    This is the EXECUTOR: a pure, un-jitted function.  The facade's planner
+    (``repro.api.plan.SearchPlan``) closes a ``jax.jit`` over it once per
+    static-option tuple ``(k, mode, beam, quantize, delta shape)`` and
+    caches the compiled executable so repeated searches with stable shapes
+    never re-trace.  Every distance — STEP 1 routing, STEP 2a bucket bounds
+    and the STEP 2b fused gather+distance+top-k bucket scan — goes through
+    the ``repro.kernels.ops`` dispatch layer, which alone picks the
+    implementation (compiled Pallas on TPU, interpret mode under
+    REPRO_FORCE_PALLAS=1, the jnp reference elsewhere).
+
+    ``delta`` (a DeltaView) adds the streaming delta buckets as a second scan
+    phase: the same bounded best-first scan, seeded with the main phase's
+    top-k carry, over the per-index append buffers.  Results are then exact
+    over main forest + delta members (within the mode's selection semantics).
     """
     n_idx = forest.index_centers.shape[0]
     nb, cap, _ = forest.bucket_x.shape
@@ -661,15 +575,14 @@ def knn_search_explain_impl(
         n_cap += n_idx * delta.x.shape[1]
     kk = min(k, n_cap)
 
-    sel, route_dists, route_cmps = route_select(forest, q, mode=mode, kernel=kernel)
+    sel, route_dists, route_cmps = route_select(forest, q, mode=mode)
     sel = widen_underfilled(sel, index_members(forest, delta), kk)
-    bounds = bucket_bounds(forest, q, sel, beam=beam, kernel=kernel)
+    bounds = bucket_bounds(forest, q, sel, beam=beam)
     dbounds = None
     if delta is not None:
-        dbounds = delta_bounds(delta, q, sel, beam=beam, kernel=kernel)
+        dbounds = delta_bounds(delta, q, sel, beam=beam)
     out = scan_sorted(
-        forest, q, bounds, kk=kk, beam=beam, kernel=kernel,
-        delta=delta, dbounds=dbounds,
+        forest, q, bounds, kk=kk, beam=beam, delta=delta, dbounds=dbounds,
     )
     stats = scan_stats(route_dists, route_cmps, out, kk=kk)
     rows = VisitRows(
@@ -681,83 +594,9 @@ def knn_search_explain_impl(
     return jnp.sqrt(out.top_d), out.top_i, stats, rows
 
 
-# Jitted executor shared by the legacy entry points below.  The facade does
-# NOT use this cache — it owns one executor per SearchPlan (repro.api.plan).
-knn_search_jit = functools.partial(
-    jax.jit, static_argnames=("k", "mode", "beam", "kernel")
-)(knn_search_impl)
-
-
-def knn_search(
-    forest: DeviceForest,
-    q: Array,
-    *,
-    k: int,
-    mode: str = "forest",
-    beam: int = 1,
-    kernel: bool = True,
-    delta: DeltaView | None = None,
-) -> tuple[Array, Array, SearchStats]:
-    """Deprecated jitted entry — use ``repro.api.OverlapIndex.search``.
-
-    Behaviour is unchanged (same executor, same jit cache); only the entry
-    point moved: the facade plans/caches executors per static-option tuple
-    and returns a structured ``SearchResult``.
-    """
-    warn_deprecated("repro.core.knn.knn_search", "repro.api.OverlapIndex.search")
-    return knn_search_jit(
-        forest, q, k=k, mode=mode, beam=beam, kernel=kernel, delta=delta
-    )
-
-
-# legacy escape hatch used by kernel tests to force re-dispatch after
-# flipping REPRO_FORCE_PALLAS (the flag is read at trace time)
-knn_search.clear_cache = knn_search_jit.clear_cache
-
-
-@functools.partial(jax.jit, static_argnames=("k", "kernel"))
-def knn_exact(x: Array, q: Array, *, k: int, kernel: bool = True) -> tuple[Array, Array]:
+@functools.partial(jax.jit, static_argnames=("k",))
+def knn_exact(x: Array, q: Array, *, k: int) -> tuple[Array, Array]:
     """Brute-force oracle: exact kNN of q (Q, D) in x (N, D)."""
-    d2 = pairwise(q, x, metric="sq_l2", use_kernel=kernel)
+    d2 = pairwise(q, x, metric="sq_l2")
     neg, idx = jax.lax.top_k(-d2, min(k, x.shape[0]))
     return jnp.sqrt(jnp.maximum(-neg, 0.0)), idx
-
-
-def knn_search_host(
-    forest: ForestArrays,
-    q,
-    *,
-    k: int,
-    mode: str = "forest",
-    beam: int = 1,
-    kernel: bool = True,
-    quantize: bool = False,
-    delta: DeltaView | None = None,
-):
-    """Deprecated host wrapper — use ``repro.api.OverlapIndex.search``
-    (numpy results + python-int stats, plus plan caching and persistence).
-
-    ``kernel`` selects the kernels/ops dispatch path (see knn_search_impl);
-    ``quantize`` stores bucket members int8 on device (device_forest);
-    ``delta`` scans the streaming delta buckets as a second phase.
-    """
-    warn_deprecated(
-        "repro.core.knn.knn_search_host", "repro.api.OverlapIndex.search"
-    )
-    df = device_forest(forest, quantize=quantize)
-    d, i, s = knn_search_jit(
-        df, jnp.asarray(q, jnp.float32), k=k, mode=mode, beam=beam, kernel=kernel,
-        delta=delta,
-    )
-    # Def. 4: |X| <= k  =>  answer set is the whole dataset.  (Same
-    # truncation as OverlapIndex.search: bucket/delta membership is a
-    # strict partition of the objects, so this count equals its n_total.)
-    n_real = int(forest.bucket_mask.sum())
-    if delta is not None:
-        n_real += int(jnp.sum(delta.mask))
-    if d.shape[1] > min(k, n_real):
-        d = d[:, : min(k, n_real)]
-        i = i[:, : min(k, n_real)]
-    from repro.api.plan import stats_to_host  # lazy: api sits above core
-
-    return np.asarray(d), np.asarray(i), stats_to_host(s)

@@ -8,8 +8,6 @@ wraps the implementations here:
   build_index_core(x, cfg)     — the paper's proposed method (registry
                                  overlap heuristics: VBM / DBM / OBM / ...)
   build_baseline_core(x, cfg)  — the BCCF-tree baseline (single tree)
-
-``build_index`` / ``build_baseline`` remain as thin deprecation shims.
 """
 from __future__ import annotations
 
@@ -27,7 +25,6 @@ import numpy as np
 from repro.core.dbscan import dbscan, partitions_from_labels
 from repro.core.decision import Partition, decide, rate_matrix
 from repro.core.forest import ForestArrays, build_forest
-from repro.deprecation import warn_deprecated
 
 
 @dataclass(frozen=True)
@@ -165,19 +162,3 @@ def build_baseline_core(
     report.wall_time_s = time.perf_counter() - t0
     report.detail = dict(structure=forest.aggregate_structure())
     return forest, report
-
-
-def build_index(x, cfg: IndexConfig) -> tuple[ForestArrays, BuildReport]:
-    """Deprecated — use ``repro.api.OverlapIndex.build(x, cfg)``."""
-    warn_deprecated(
-        "repro.core.pipeline.build_index", "repro.api.OverlapIndex.build"
-    )
-    return build_index_core(x, cfg)
-
-
-def build_baseline(x, cfg: IndexConfig | None = None) -> tuple[ForestArrays, BuildReport]:
-    """Deprecated — use ``repro.api.OverlapIndex.baseline(x, cfg)``."""
-    warn_deprecated(
-        "repro.core.pipeline.build_baseline", "repro.api.OverlapIndex.baseline"
-    )
-    return build_baseline_core(x, cfg)

@@ -4,9 +4,9 @@ The forest's bucket rows and the per-index delta buffers are sharded over
 the ``'model'`` mesh axis (leading dimension, NB and I respectively); the
 routing state — index centers, radii, neighbor lists, queries — is
 replicated.  Inside the island every shard runs the SAME code the
-single-device executor runs (``core.knn.route_select`` + ``local_scan``,
-``stream.ingest.append_routed``) over its local rows only; the collectives
-are confined to the very end:
+single-device executor runs (``core.knn.route_select``, ``bucket_bounds``
+and ``scan_sorted``; ``stream.ingest.append_routed``) over its local rows
+only; the collectives are confined to the very end:
 
   search:  per-shard top-kk carry  -> all_gather + global top-k
            (``core.knn.merge_shard_topk`` — the identical merge
@@ -98,14 +98,13 @@ def sharded_search(
     k: int,
     mode: str = "forest",
     beam: int = 1,
-    kernel: bool = True,
     per_island: bool = False,
     explain: bool = False,
     host_sel: Array | None = None,
 ) -> tuple[Array, ...]:
     """Sharded twin of ``core.knn.knn_search_impl`` — same signature shape,
-    same return triple, bitwise-identical results.  ``per_island=True``
-    appends a fourth element, ``core.knn.IslandStats`` with one row per
+    bitwise-identical results; returns (dists, ids, SearchStats).
+    ``per_island=True`` appends a fourth element, ``core.knn.IslandStats`` with one row per
     shard, exposing which island paid which node accesses (the telemetry
     layer's load-balance view; the summed ``SearchStats`` is unchanged).
     ``explain=True`` (implies ``per_island``) appends a fifth,
@@ -123,7 +122,8 @@ def sharded_search(
     the sorted order into an ordinary sharded operand, which partitions
     correctly —
     and the split costs nothing: both islands fuse into the same jitted
-    executable, and the eager path runs the same ops ``local_scan`` runs.
+    executable, and the eager path runs the same ops
+    ``core.knn.knn_search_impl`` runs.
 
     Each shard routes the (replicated) queries, scans its local bucket rows
     and local delta rows with the shared ``scan_sorted`` body, then the
@@ -157,9 +157,7 @@ def sharded_search(
 
     def bounds_island(forest_l, q_l, delta_l, hs_l):
         n_idx = forest_l.index_centers.shape[0]
-        sel, route_d, route_c = cknn.route_select(
-            forest_l, q_l, mode=mode, kernel=kernel
-        )
+        sel, route_d, route_c = cknn.route_select(forest_l, q_l, mode=mode)
         # under-filled selections widen on the GLOBAL member count, exactly
         # as the single-device executor decides (core.knn.widen_underfilled)
         members = cknn.index_members(forest_l)
@@ -180,9 +178,7 @@ def sharded_search(
             sel = sel & hs_l
         # sentinel column: pad buckets own index I -> always ineligible
         bucket_sel = jnp.pad(sel, ((0, 0), (0, 1)))
-        mb = cknn.bucket_bounds(
-            forest_l, q_l, bucket_sel, beam=beam, kernel=kernel
-        )
+        mb = cknn.bucket_bounds(forest_l, q_l, bucket_sel, beam=beam)
         # replicated values leave as an explicit (1, Q) shard slice — the
         # caller reads shard 0 — rather than a P() output, so correctness
         # never leans on the partitioner's replication bookkeeping
@@ -195,7 +191,7 @@ def sharded_search(
             sel_pad = jnp.pad(sel, ((0, 0), (0, S * i_l - n_idx)))
             off = jax.lax.axis_index(axis) * i_l
             dsel = jax.lax.dynamic_slice_in_dim(sel_pad, off, i_l, axis=1)
-            db = cknn.delta_bounds(delta_l, q_l, dsel, beam=beam, kernel=kernel)
+            db = cknn.delta_bounds(delta_l, q_l, dsel, beam=beam)
             outs += (db.order, db.lb_sorted, db.n_elig[None])
         return outs
 
@@ -212,8 +208,7 @@ def sharded_search(
                 n_elig=jnp.zeros((qn,), jnp.int32),
             )
         out = cknn.scan_sorted(
-            forest_l, q_l, mb, kk=kk, beam=beam, kernel=kernel,
-            delta=delta_l, dbounds=db,
+            forest_l, q_l, mb, kk=kk, beam=beam, delta=delta_l, dbounds=db,
             qmask=None if hs_l is None else hs_l[:, 0],
         )
         top_d, top_i = cknn.merge_shard_topk(
@@ -321,7 +316,7 @@ def sharded_ingest(
     def island(centers_r, delta_l, xb_r, ids_r, valid_r):
         xb_f = xb_r.astype(jnp.float32)
         ids_i = ids_r.astype(jnp.int32)
-        _, idx = cknn.route_points(centers_r, xb_f, kernel=True)  # (B,) global
+        _, idx = cknn.route_points(centers_r, xb_f)  # (B,) global
         i_l = delta_l.count.shape[0]
         off = jax.lax.axis_index(axis) * i_l
         local = idx - off

@@ -57,7 +57,6 @@ def routed_search(
     k: int,
     mode: str = "forest",
     beam: int = 1,
-    kernel: bool = True,
     fanout: str = "auto",
     per_island: bool = False,
     explain: bool = False,
@@ -77,8 +76,8 @@ def routed_search(
     kk = min(k, n_cap)
 
     def routing_island(forest_l, q_l):
-        d_sq_l, _ = cknn.route_points(forest_l.index_centers, q_l, kernel=kernel)
-        sel_l, _, _ = cknn.route_select(forest_l, q_l, mode=mode, kernel=kernel)
+        d_sq_l, _ = cknn.route_points(forest_l.index_centers, q_l)
+        sel_l, _, _ = cknn.route_select(forest_l, q_l, mode=mode)
         return d_sq_l[None], sel_l[None]
 
     # the routing kernels run per shard, as in the islands (the compiler
@@ -125,7 +124,7 @@ def routed_search(
 
     outs = knn_island.sharded_search(
         mesh, axis, forest, q, delta,
-        k=k, mode=mode, beam=beam, kernel=kernel,
+        k=k, mode=mode, beam=beam,
         per_island=per_island, explain=explain, host_sel=host_sel,
     )
     pruned = (

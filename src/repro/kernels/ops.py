@@ -24,7 +24,7 @@ Streaming delta buckets (repro.stream): the per-index append buffers are
 scanned by the SAME fused bucket-scan kernel — a delta buffer is just a
 bucket datastore of shape (I, CAP_d, D) with -1-id padding, so
 ``bucket_scan_prepad`` + ``bucket_scan_topk`` (alias ``delta_scan_topk``)
-cover the delta phase of ``core.knn.knn_search`` with no new kernel.
+cover the delta phase of ``core.knn.knn_search_impl`` with no new kernel.
 Delta members always scan f32 (``scale=None``) even when the main forest
 is int8-quantized: freshly streamed rows have no quantization pass yet —
 they pick up int8 storage when maintenance absorbs them into the tree.

@@ -206,7 +206,7 @@ def ingest_keys(
     re-submitted later.  Returns the updated datastore and the number of
     ACCEPTED pairs (the serving tier reports rejects back to the client
     rather than blocking the decode loop on a rebuild; the offline
-    StreamingForest wrapper is the no-loss path).
+    ``OverlapIndex.ingest`` is the no-loss path).
     """
     if ds.delta is None:
         raise ValueError("datastore built without stream_capacity")
@@ -254,16 +254,14 @@ def _run_ingest(ds: ForestDatastore, keys_j: Array, ids: Array):
     return ingest(ds.forest, ds.delta, keys_j, ids)
 
 
-def forest_knn(
-    hidden: Array, ds: ForestDatastore, k: int, *, kernel: bool = True
-) -> tuple[Array, Array]:
+def forest_knn(hidden: Array, ds: ForestDatastore, k: int) -> tuple[Array, Array]:
     """(distances (B,k), token values (B,k)) via the paper's Alg. 2 search.
 
-    ``kernel`` selects the kernels/ops dispatch path (fused Pallas bucket
-    scan on TPU) vs the pure-jnp reference — see core.knn.knn_search_impl.
-    Streaming deltas, when present, are scanned as the second phase.
-    (Executor, not the legacy jitted entry: this runs INSIDE the engine's
-    jitted decode step, which is the compilation boundary.)
+    Distances go through the kernels/ops dispatch (fused Pallas bucket
+    scan on TPU) — see core.knn.knn_search_impl.  Streaming deltas, when
+    present, are scanned as the second phase.  (Executor, not a jitted
+    entry: this runs INSIDE the engine's jitted decode step, which is the
+    compilation boundary.)
     """
     from repro.core.knn import knn_search_impl
     from repro.stream.ingest import delta_view
@@ -276,8 +274,7 @@ def forest_knn(
         d, ids, *_ = drouter.routed_search(
             knn_island.default_mesh(ds.shards), dctx.MODEL_AXIS,
             ds.forest, hidden.astype(jnp.float32), delta, ds.router_table,
-            k=k, mode="forest", kernel=kernel,
-            fanout=ds.fanout or "auto",
+            k=k, mode="forest", fanout=ds.fanout or "auto",
         )
     elif ds.shards > 1:
         from repro.distributed import knn_island
@@ -285,12 +282,12 @@ def forest_knn(
         d, ids, _ = knn_island.sharded_search(
             knn_island.default_mesh(ds.shards), dctx.MODEL_AXIS,
             ds.forest, hidden.astype(jnp.float32), delta,
-            k=k, mode="forest", kernel=kernel,
+            k=k, mode="forest",
         )
     else:
-        d, ids, _ = knn_search_impl(
+        d, ids, *_ = knn_search_impl(
             ds.forest, hidden.astype(jnp.float32), k=k, mode="forest",
-            kernel=kernel, delta=delta,
+            delta=delta,
         )
     vals = ds.values[jnp.clip(ids, 0, ds.values.shape[0] - 1)]
     vals = jnp.where(ids >= 0, vals, 0)
@@ -316,7 +313,7 @@ def knn_logits(
     """
     r = cfg.retrieval
     if isinstance(ds, ForestDatastore):
-        d2, vals = forest_knn(hidden, ds, r.k, kernel=r.kernel)
+        d2, vals = forest_knn(hidden, ds, r.k)
         w = jax.nn.softmax(-jnp.sqrt(jnp.maximum(d2, 0.0)) / r.temperature, axis=-1)
         p_knn = jnp.zeros((hidden.shape[0], cfg.padded_vocab), jnp.float32)
         return p_knn.at[jnp.arange(hidden.shape[0])[:, None], vals].add(w)

@@ -22,7 +22,6 @@ from repro.stream.maintenance import (
     DriftReport,
     MaintenanceConfig,
     OverlapMonitor,
-    StreamingForest,
     object_assignment,
     rebuild_indexes,
 )
@@ -31,6 +30,6 @@ __all__ = [
     "DeltaBuffer", "alloc_delta", "delta_view", "ingest", "ingest_host",
     "main_index_sums", "pull_delta_meta", "route_batch_host",
     "updated_geometry",
-    "DriftReport", "MaintenanceConfig", "OverlapMonitor", "StreamingForest",
+    "DriftReport", "MaintenanceConfig", "OverlapMonitor",
     "object_assignment", "rebuild_indexes",
 ]

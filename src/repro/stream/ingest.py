@@ -200,7 +200,7 @@ def ingest_impl(
     ids = ids.astype(jnp.int32)
 
     # route (STEP-1; same arithmetic as the query path)
-    _, idx = route_points(centers, xb, kernel=True)  # (B,)
+    _, idx = route_points(centers, xb)  # (B,)
     if valid is None:
         valid = jnp.ones((xb.shape[0],), bool)
     else:

@@ -51,8 +51,6 @@ from repro.core.overlap import (
     max_neighbor_rate,
     overlap_matrix,
 )
-from repro.core.pipeline import IndexConfig
-from repro.deprecation import warn_deprecated
 from repro.stream.ingest import DeltaBuffer, pull_delta_meta, updated_geometry
 
 import jax.numpy as jnp
@@ -299,103 +297,3 @@ def rebuild_indexes(
         wall_time_s=perf_counter() - t0,
     )
     return new_forest, stats
-
-
-class StreamingForest:
-    """Deprecated shim — use ``repro.api.OverlapIndex``.
-
-    The ingest → monitor → rebuild lifecycle this class used to own lives
-    on the facade now (``OverlapIndex.ingest`` / ``.maintain`` /
-    ``.search``); this wrapper only translates the legacy
-    ``(IndexConfig, MaintenanceConfig, delta_capacity)`` argument triple
-    into one ``repro.api.Config`` tree and delegates, preserving the old
-    attribute surface (``forest`` / ``device`` / ``delta`` / ``monitor`` /
-    ``rebuild_log`` / ...) and the old device-tuple ``search`` return.
-    """
-
-    def __init__(
-        self,
-        x0: np.ndarray,
-        index_cfg: IndexConfig | None = None,
-        maint_cfg: MaintenanceConfig | None = None,
-        *,
-        delta_capacity: int | None = None,
-    ):
-        warn_deprecated("repro.stream.StreamingForest", "repro.api.OverlapIndex")
-        from repro.api import Config, OverlapIndex, StreamConfig, as_index_config
-
-        mc = maint_cfg or MaintenanceConfig()
-        cfg = Config(
-            index=as_index_config(index_cfg or IndexConfig()),
-            stream=StreamConfig(
-                capacity=delta_capacity,
-                monitor_method=mc.method,
-                xi_rebuild=mc.xi_rebuild,
-                drift_margin=mc.drift_margin,
-                fill_rebuild=mc.fill_rebuild,
-                wasted_rebuild=mc.wasted_rebuild,
-                pivot_method=mc.pivot_method,
-                c_max=mc.c_max,
-                seed=mc.seed,
-            ),
-        )
-        self._ix = OverlapIndex.build(np.asarray(x0, np.float32), cfg)
-        # legacy semantics: buffers + monitor live from construction
-        self._ix._ensure_delta()
-        self.index_cfg = cfg.index
-        self.maint_cfg = mc
-
-    # --- lifecycle delegation ----------------------------------------------
-    def ingest(self, xb: np.ndarray) -> np.ndarray:
-        return self._ix.ingest(xb)
-
-    def search(self, q, *, k: int, mode: str = "forest", beam: int = 1,
-               kernel: bool = True):
-        """Device triple (dists, ids, SearchStats) — the legacy return."""
-        return self._ix._search_device(q, k=k, mode=mode, beam=beam, kernel=kernel)
-
-    def check(self) -> DriftReport:
-        return self._ix.check()
-
-    def maintain(self) -> DriftReport:
-        return self._ix.maintain()
-
-    def structure(self) -> dict[str, Any]:
-        return self._ix.structure()
-
-    # --- legacy attribute surface -------------------------------------------
-    @property
-    def forest(self) -> ForestArrays:
-        return self._ix.forest
-
-    @property
-    def device(self):
-        return self._ix.device
-
-    @property
-    def delta(self) -> DeltaBuffer:
-        return self._ix.delta
-
-    @property
-    def monitor(self) -> OverlapMonitor:
-        return self._ix.monitor
-
-    @property
-    def capacity(self) -> int:
-        return self._ix.capacity
-
-    @property
-    def build_report(self):
-        return self._ix.build_report
-
-    @property
-    def rebuild_log(self) -> list[dict[str, Any]]:
-        return self._ix.rebuild_log
-
-    @property
-    def x_all(self) -> np.ndarray:
-        return self._ix.x_all
-
-    @property
-    def n_total(self) -> int:
-        return self._ix.n_total

@@ -1,11 +1,11 @@
 """OverlapIndex facade tests: config-tree validation, overlap-method
-registry, plan-cache re-trace behavior, save/load bitwise round-trip, the
-baseline pivot-method contract, and the shim-deprecation gate (shim usage
-inside src/repro itself fails the build)."""
-import re
+registry, plan-cache re-trace behavior, save/load bitwise round-trip
+(including snapshots that carry a retired config key), the baseline
+pivot-method contract, and the whole lifecycle end to end."""
+import json
 import warnings
-from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,7 +16,6 @@ from repro.api import (
     IndexConfig,
     LayoutConfig,
     OverlapIndex,
-    RepoDeprecationWarning,
     SearchConfig,
     StreamConfig,
     available_overlap_methods,
@@ -24,7 +23,7 @@ from repro.api import (
     register_overlap_method,
     unregister_overlap_method,
 )
-from repro.core import knn_exact
+from repro.core import knn_exact, knn_search_impl
 from repro.core.overlap import overlap_matrix
 from repro.core.pipeline import build_baseline_core
 
@@ -175,19 +174,20 @@ def test_plan_cache_lru_evicts_and_counts():
     from repro.api.plan import PlanCache, PlanKey
 
     def key(k):
-        return PlanKey(k=k, mode="forest", beam=1, kernel=True,
+        return PlanKey(k=k, mode="forest", beam=1,
                        quantize=False, delta_capacity=None)
 
+    backend = make_backend(LayoutConfig())
     cache = PlanCache(max_plans=2)
-    cache.plan(key(1))
-    cache.plan(key(2))
-    cache.plan(key(1))  # refresh recency: key(2) is now the LRU entry
-    cache.plan(key(3))  # over the cap -> evicts key(2)
+    cache.plan(key(1), backend)
+    cache.plan(key(2), backend)
+    cache.plan(key(1), backend)  # refresh recency: key(2) is now the LRU entry
+    cache.plan(key(3), backend)  # over the cap -> evicts key(2)
     assert key(2) not in cache and key(1) in cache and key(3) in cache
     st = cache.stats()
     assert (st["plans"], st["max_plans"]) == (2, 2)
     assert (st["hits"], st["misses"], st["evictions"]) == (1, 3, 1)
-    cache.plan(key(2))  # re-request: a plain recompile, not an error
+    cache.plan(key(2), backend)  # re-request: a plain recompile, not an error
     assert cache.stats()["misses"] == 4 and cache.stats()["evictions"] == 2
     assert len(cache) == 2
     with pytest.raises(ValueError, match="max_plans"):
@@ -240,18 +240,18 @@ def test_search_overrides_are_validated(built, rng):
 
 
 def test_search_result_matches_legacy_tuple(built, rng):
-    """SearchResult (facade) must agree with the legacy shim output."""
-    from repro.core import knn_search_host
+    """SearchResult (facade) must agree with the bare executor's tuple."""
+    from repro.stream import delta_view
 
     ix = built
     q = rng.normal(size=(8, 8)).astype(np.float32) * 8
     res = ix.search(q, k=7, mode="all")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", RepoDeprecationWarning)
-        d, i, s = knn_search_host(ix.forest, q, k=7, mode="all")
-    np.testing.assert_array_equal(res.dists, d)
-    np.testing.assert_array_equal(res.ids, i)
-    assert res.stats["steps"] == s["steps"]
+    run = jax.jit(knn_search_impl, static_argnames=("k", "mode", "beam"))
+    delta = None if ix.device_delta is None else delta_view(ix.device_delta)
+    d, i, s, _ = run(ix.device, jnp.asarray(q), k=7, mode="all", delta=delta)
+    np.testing.assert_array_equal(res.dists, np.asarray(d))
+    np.testing.assert_array_equal(res.ids, np.asarray(i))
+    assert res.stats["steps"] == int(s.steps)
     d2, i2, s2 = res  # tuple-unpacking compatibility
     assert d2 is res.dists and i2 is res.ids
 
@@ -321,6 +321,39 @@ def test_load_refuses_newer_format(built, tmp_path):
         OverlapIndex.load(path)
 
 
+def _rewrite_search_config(path, **extra):
+    """Add ``extra`` keys to a saved snapshot's search config, in place."""
+    with np.load(path, allow_pickle=False) as z:
+        payload = dict(z)
+    cfg = json.loads(str(payload["config_json"]))
+    cfg["search"].update(extra)
+    payload["config_json"] = np.array(json.dumps(cfg))
+    with open(path, "wb") as f:
+        np.savez_compressed(f, **payload)
+
+
+def test_load_drops_retired_kernel_key(built, rng, tmp_path):
+    """Snapshots saved while ``SearchConfig`` had a ``kernel`` field carry
+    ``"kernel": true``: load drops that retired key and nothing else."""
+    path = built.save(tmp_path / "old")
+    _rewrite_search_config(path, kernel=True)
+    ix2 = OverlapIndex.load(path)
+    assert ix2.cfg == built.cfg
+    q = rng.normal(size=(12, 8)).astype(np.float32) * 8
+    for k, mode in ((6, "all"), (4, "forest")):
+        a = built.search(q, k=k, mode=mode)
+        b = ix2.search(q, k=k, mode=mode)
+        np.testing.assert_array_equal(a.dists, b.dists)
+        np.testing.assert_array_equal(a.ids, b.ids)
+        np.testing.assert_array_equal(
+            a.stats["buckets_visited"], b.stats["buckets_visited"]
+        )
+    # any other unknown key is still an error
+    _rewrite_search_config(path, kernal=True)
+    with pytest.raises(TypeError, match="kernal"):
+        OverlapIndex.load(path)
+
+
 # ---------------------------------------------------------------------------
 # baseline pivot-method contract (was: silently hardcoded 'kmeans')
 # ---------------------------------------------------------------------------
@@ -346,79 +379,36 @@ def test_baseline_honors_pivot_method_and_warns():
 
 
 # ---------------------------------------------------------------------------
-# deprecation gate: shims warn; src/repro itself must never hit them
+# the whole lifecycle through the facade
 # ---------------------------------------------------------------------------
 
-def test_shims_emit_repo_deprecation_warning(blob_data):
-    from repro.core import build_baseline, build_index, knn_search
-    from repro.core.knn import device_forest
-    from repro.stream import StreamingForest
-
-    x = blob_data[:400]
-    with pytest.warns(RepoDeprecationWarning, match="build_index"):
-        forest, _ = build_index(
-            x, IndexConfig(method="vbm", eps=1.5, min_pts=8))
-    with pytest.warns(RepoDeprecationWarning, match="knn_search"):
-        knn_search(device_forest(forest), jnp.asarray(x[:4]), k=3)
-    with pytest.warns(RepoDeprecationWarning, match="build_baseline"):
-        build_baseline(x)
-    with pytest.warns(RepoDeprecationWarning, match="StreamingForest"):
-        StreamingForest(x, IndexConfig(method="vbm", eps=1.5, min_pts=8))
-
-
-def test_facade_lifecycle_emits_no_deprecation(blob_data, tmp_path):
+def test_facade_lifecycle_runs_end_to_end(blob_data, tmp_path):
     """The whole facade surface — build, search, ingest, maintain, save,
-    load, to_datastore — must run clean of RepoDeprecationWarning: internal
-    code going through a shim fails here (and thereby fails CI)."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", RepoDeprecationWarning)
-        ix = OverlapIndex.build(blob_data, CFG)
-        ix.search(blob_data[:4], k=3)
-        ix.ingest(_stream_points(blob_data, 150, seed=5))
-        ix.search(blob_data[:4], k=3, mode="all")
-        ix.maintain()
-        path = ix.save(tmp_path / "clean")
-        ix2 = OverlapIndex.load(path)
-        ix2.search(blob_data[:4], k=3)
-        ds = ix2.to_datastore(
-            np.arange(ix2.n_total, dtype=np.int32) % 50, stream_capacity=16
-        )
-        # serve-side read+write paths too
-        from repro.serve.retrieval import forest_knn, ingest_keys
-
-        d2, vals = forest_knn(jnp.asarray(blob_data[:4]), ds, 3)
-        assert vals.shape == (4, 3)
-        ds, acc = ingest_keys(
-            ds, jnp.asarray(_stream_points(blob_data, 4, seed=6)),
-            jnp.arange(4, dtype=jnp.int32),
-        )
-        assert acc > 0
-        baseline = OverlapIndex.baseline(blob_data[:300])
-        baseline.search(blob_data[:4], k=3, mode="all")
-
-
-def test_no_shim_usage_inside_src_repro():
-    """Static gate: the deprecated surfaces may be CALLED only by their own
-    defining modules; everything else under src/repro goes through the
-    facade or the *_core/_impl entry points."""
-    src = Path(__file__).resolve().parent.parent / "src" / "repro"
-    allowed = {"core/pipeline.py", "core/knn.py", "stream/maintenance.py"}
-    pat = re.compile(
-        r"\b(build_index|build_baseline|knn_search_host|knn_search|"
-        r"StreamingForest)\s*\("
+    load, to_datastore, the serve-side read and write paths, baseline —
+    runs in one sequence."""
+    ix = OverlapIndex.build(blob_data, CFG)
+    ix.search(blob_data[:4], k=3)
+    ix.ingest(_stream_points(blob_data, 150, seed=5))
+    ix.search(blob_data[:4], k=3, mode="all")
+    ix.maintain()
+    path = ix.save(tmp_path / "clean")
+    ix2 = OverlapIndex.load(path)
+    ix2.search(blob_data[:4], k=3)
+    ds = ix2.to_datastore(
+        np.arange(ix2.n_total, dtype=np.int32) % 50, stream_capacity=16
     )
-    offenders = []
-    for path in sorted(src.rglob("*.py")):
-        rel = path.relative_to(src).as_posix()
-        if rel in allowed:
-            continue
-        for ln, line in enumerate(path.read_text().splitlines(), 1):
-            if pat.search(line):
-                offenders.append(f"{rel}:{ln}: {line.strip()}")
-    assert not offenders, (
-        "deprecated shim usage inside src/repro (use repro.api.OverlapIndex "
-        "or the *_core/_impl functions):\n" + "\n".join(offenders)
+    # serve-side read+write paths too
+    from repro.serve.retrieval import forest_knn, ingest_keys
+
+    d2, vals = forest_knn(jnp.asarray(blob_data[:4]), ds, 3)
+    assert vals.shape == (4, 3)
+    ds, acc = ingest_keys(
+        ds, jnp.asarray(_stream_points(blob_data, 4, seed=6)),
+        jnp.arange(4, dtype=jnp.int32),
     )
+    assert acc > 0
+    baseline = OverlapIndex.baseline(blob_data[:300])
+    baseline.search(blob_data[:4], k=3, mode="all")
 
 
 # ---------------------------------------------------------------------------

@@ -13,8 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import IndexConfig, build_baseline, knn_exact, knn_search_host
-from repro.core.knn import device_forest, knn_search
+from repro.api import Config, IndexConfig, OverlapIndex, SearchConfig
+from repro.core import knn_exact
 from repro.kernels import ref
 from repro.kernels.bucket_scan import bucket_scan_topk_pallas
 from repro.kernels.ops import quantize_datastore
@@ -328,33 +328,34 @@ def test_insert_topk_keeps_lanes_past_k_empty(kk, rng):
     assert np.isinf(ov[:, kk:]).all() and (oi[:, kk:] == -1).all()
 
 
+SMALL = IndexConfig(c_max=8)
+
+
+def _small_index(x, **search):
+    """A fresh index, hence a fresh plan cache: its first search traces the
+    executor anew, and the kernels/ops dispatch reads the environment then."""
+    return OverlapIndex.baseline(x, Config(index=SMALL, search=SearchConfig(**search)))
+
+
 @pytest.fixture()
 def small_forest():
     g = np.random.default_rng(3)
     x = g.normal(size=(90, 5)).astype(np.float32) * 4
-    forest, _ = build_baseline(x, IndexConfig(c_max=8))
-    return x, forest
-
-
-def _forced_pallas(monkeypatch):
-    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
-    # drop traces cached before the env flip (dispatch reads env at trace time)
-    knn_search.clear_cache()
+    return x, _small_index(x)
 
 
 @pytest.mark.parametrize("beam", [4, 7])
 def test_search_beam_not_dividing_nb(small_forest, monkeypatch, beam):
     """Forced-pallas search with beam not dividing NB == jnp-reference search."""
-    x, forest = small_forest
-    assert forest.n_buckets % beam != 0, "shape must exercise the pad lanes"
+    x, ix_ref = small_forest
+    assert ix_ref.forest.n_buckets % beam != 0, "shape must exercise the pad lanes"
     g = np.random.default_rng(5)
     q = g.normal(size=(4, 5)).astype(np.float32) * 4
-    d_ref, _, s_ref = knn_search_host(forest, q, k=6, mode="all", beam=beam, kernel=False)
-    _forced_pallas(monkeypatch)
-    try:
-        d_k, _, s_k = knn_search_host(forest, q, k=6, mode="all", beam=beam, kernel=True)
-    finally:
-        knn_search.clear_cache()
+    d_ref, _, s_ref = ix_ref.search(q, k=6, mode="all", beam=beam)
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    ix_k = _small_index(x)
+    np.testing.assert_array_equal(ix_k.forest.bucket_ids, ix_ref.forest.bucket_ids)
+    d_k, _, s_k = ix_k.search(q, k=6, mode="all", beam=beam)
     np.testing.assert_allclose(d_k, d_ref, rtol=1e-4, atol=1e-4)
     assert np.array_equal(s_k["buckets_visited"], s_ref["buckets_visited"])
     assert np.array_equal(s_k["distances"], s_ref["distances"])
@@ -363,28 +364,25 @@ def test_search_beam_not_dividing_nb(small_forest, monkeypatch, beam):
 
 def test_kernelized_mode_all_exact(small_forest, monkeypatch):
     """Acceptance: kernelized mode='all' still matches brute force."""
-    x, forest = small_forest
+    x, _ = small_forest
     g = np.random.default_rng(11)
     q = g.normal(size=(6, 5)).astype(np.float32) * 4
     de, _ = knn_exact(jnp.asarray(x), jnp.asarray(q), k=10)
-    _forced_pallas(monkeypatch)
-    try:
-        d, ids, _ = knn_search_host(forest, q, k=10, mode="all", kernel=True)
-    finally:
-        knn_search.clear_cache()
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    d, ids, _ = _small_index(x).search(q, k=10, mode="all")
     np.testing.assert_allclose(d, np.asarray(de), rtol=1e-4, atol=1e-4)
     assert (np.asarray(ids) >= 0).all()
 
 
 def test_quantized_bucket_storage_recall(small_forest):
-    """int8 bucket storage (device_forest knob): near-exact neighbors."""
-    x, forest = small_forest
+    """int8 bucket storage (SearchConfig.quantize): near-exact neighbors."""
+    x, _ = small_forest
     g = np.random.default_rng(13)
     q = g.normal(size=(8, 5)).astype(np.float32) * 4
     de, ie = knn_exact(jnp.asarray(x), jnp.asarray(q), k=5)
-    df = device_forest(forest, quantize=True)
-    assert df.bucket_x.dtype == jnp.int8 and df.bucket_scale is not None
-    d, ids, _ = knn_search_host(forest, q, k=5, mode="all", quantize=True)
+    ix = _small_index(x, quantize=True)
+    assert ix.device.bucket_x.dtype == jnp.int8 and ix.device.bucket_scale is not None
+    d, ids, _ = ix.search(q, k=5, mode="all")
     ie = np.asarray(ie)
     recall = np.mean(
         [len(set(ids[i].tolist()) & set(ie[i].tolist())) / 5 for i in range(len(q))]

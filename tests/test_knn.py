@@ -1,4 +1,5 @@
-"""Search correctness: the flattened masked-scan kNN (Alg. 2) vs brute force."""
+"""Search correctness: the flattened masked-scan kNN (Alg. 2) vs brute force,
+through the ``OverlapIndex`` facade (plan cache + layout backend)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,52 +10,46 @@ except ModuleNotFoundError:  # optional dep: degrade to seeded sampling
     from repro.testing.hypothesis_fallback import given, settings
     from repro.testing.hypothesis_fallback import strategies as st
 
-from repro.core import (
-    IndexConfig,
-    build_baseline,
-    build_index,
-    device_forest,
-    knn_exact,
-    knn_search,
-    knn_search_host,
-)
+from repro.api import Config, IndexConfig, OverlapIndex
+from repro.core import knn_exact
 
 
 @pytest.fixture(scope="module")
 def built(blob_data):
     cfg = IndexConfig(method="vbm", eps=1.5, min_pts=8, xi_min=0.3, xi_max=0.7)
-    forest, report = build_index(blob_data, cfg)
-    return blob_data, forest, report
+    ix = OverlapIndex.build(blob_data, Config(index=cfg))
+    return blob_data, ix, ix.build_report
 
 
 def test_mode_all_is_exact(built, rng):
-    x, forest, _ = built
+    x, ix, _ = built
     q = rng.normal(size=(32, x.shape[1])).astype(np.float32) * 8
-    d, i, s = knn_search_host(forest, q, k=12, mode="all")
+    d, i, s = ix.search(q, k=12, mode="all")
     de, ie = knn_exact(jnp.asarray(x), jnp.asarray(q), k=12)
     np.testing.assert_allclose(d, np.asarray(de), rtol=1e-4, atol=1e-4)
     # ids may differ on exact ties; distances must agree
     assert (s["buckets_visited"] > 0).all()
-    assert (s["buckets_visited"] <= forest.n_buckets).all()
+    assert (s["buckets_visited"] <= ix.forest.n_buckets).all()
 
 
 @pytest.mark.parametrize("beam", [1, 4])
 def test_beam_equivalence(built, rng, beam):
-    x, forest, _ = built
+    x, ix, _ = built
     q = rng.normal(size=(16, x.shape[1])).astype(np.float32) * 8
-    d1, _, _ = knn_search_host(forest, q, k=10, mode="all", beam=1)
-    db, _, _ = knn_search_host(forest, q, k=10, mode="all", beam=beam)
+    d1, _, _ = ix.search(q, k=10, mode="all", beam=1)
+    db, _, _ = ix.search(q, k=10, mode="all", beam=beam)
     np.testing.assert_allclose(d1, db, rtol=1e-5, atol=1e-5)
 
 
 def test_forest_mode_exact_within_selected(built):
     """Alg. 2 routing: results must be exact kNN over the SELECTED indexes'
     members (the paper's semantics)."""
-    x, forest, _ = built
+    x, ix, _ = built
+    forest = ix.forest
     # own deterministic stream (order-independent of other tests)
     rng = np.random.default_rng(77)
     q = (x[rng.choice(len(x), 24, replace=False)] + 0.05 * rng.normal(size=(24, x.shape[1]))).astype(np.float32)
-    d, ids, s = knn_search_host(forest, q, k=8, mode="forest")
+    d, ids, s = ix.search(q, k=8, mode="forest")
     # reconstruct selection per query on host
     centers = forest.index_centers
     nbrs = forest.neighbors
@@ -88,11 +83,11 @@ def test_forest_mode_exact_within_selected(built):
 
 
 def test_forest_recall_in_distribution(built, rng):
-    x, forest, _ = built
+    x, ix, _ = built
     qi = rng.choice(len(x), 64, replace=False)
     q = (x[qi] + 0.05 * rng.normal(size=(64, x.shape[1]))).astype(np.float32)
     de, ie = knn_exact(jnp.asarray(x), jnp.asarray(q), k=10)
-    d, ids, _ = knn_search_host(forest, q, k=10, mode="forest")
+    d, ids, _ = ix.search(q, k=10, mode="forest")
     ie = np.asarray(ie)
     recall = np.mean([len(set(ids[j].tolist()) & set(ie[j].tolist())) / 10 for j in range(64)])
     assert recall >= 0.6, recall
@@ -100,19 +95,19 @@ def test_forest_recall_in_distribution(built, rng):
 
 def test_pruning_beats_baseline(built, blob_data, rng):
     """The paper's headline claim: fewer distance computations than BCCF."""
-    x, forest, _ = built
-    bforest, _ = build_baseline(x)
+    x, ix, _ = built
+    bix = OverlapIndex.baseline(x)
     qi = rng.choice(len(x), 32, replace=False)
     q = x[qi].astype(np.float32)
-    _, _, s_f = knn_search_host(forest, q, k=10, mode="forest")
-    _, _, s_b = knn_search_host(bforest, q, k=10, mode="all")
+    _, _, s_f = ix.search(q, k=10, mode="forest")
+    _, _, s_b = bix.search(q, k=10, mode="all")
     assert s_f["distances"].mean() < s_b["distances"].mean()
 
 
 def test_fewer_than_k_objects():
     x = np.random.default_rng(0).normal(size=(7, 4)).astype(np.float32)
-    forest, _ = build_baseline(x, IndexConfig(c_max=4))
-    d, ids, _ = knn_search_host(forest, x[:2], k=20, mode="all")
+    ix = OverlapIndex.baseline(x, Config(index=IndexConfig(c_max=4)))
+    d, ids, _ = ix.search(x[:2], k=20, mode="all")
     assert d.shape[1] == 7  # |X| < k -> returns |X| answers (Def. 4)
     assert (ids >= 0).all()
 
@@ -124,17 +119,17 @@ def test_property_exactness_random(seed, k):
     g = np.random.default_rng(seed)
     x = g.normal(size=(150, 5)).astype(np.float32)
     q = g.normal(size=(4, 5)).astype(np.float32)
-    forest, _ = build_baseline(x, IndexConfig(c_max=16))
-    d, _, _ = knn_search_host(forest, q, k=k, mode="all")
+    ix = OverlapIndex.baseline(x, Config(index=IndexConfig(c_max=16)))
+    d, _, _ = ix.search(q, k=k, mode="all")
     de, _ = knn_exact(jnp.asarray(x), jnp.asarray(q), k=k)
     np.testing.assert_allclose(d, np.asarray(de), rtol=1e-4, atol=1e-4)
 
 
 def test_stats_counters_monotone(built, rng):
     """More neighbors requested -> at least as much work."""
-    x, forest, _ = built
+    x, ix, _ = built
     q = x[rng.choice(len(x), 16, replace=False)].astype(np.float32)
-    _, _, s5 = knn_search_host(forest, q, k=5, mode="forest")
-    _, _, s50 = knn_search_host(forest, q, k=50, mode="forest")
+    _, _, s5 = ix.search(q, k=5, mode="forest")
+    _, _, s50 = ix.search(q, k=50, mode="forest")
     assert s50["buckets_visited"].sum() >= s5["buckets_visited"].sum()
     assert s50["distances"].sum() >= s5["distances"].sum()

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.api import Config, IndexConfig, ObsConfig, OverlapIndex, StreamConfig
+from repro.api.executor import SingleDeviceBackend
 from repro.api.plan import PlanCache, PlanKey, stats_to_host
 from repro.obs import EventLog, Histogram, Registry, events_path_from_env
 
@@ -288,14 +289,18 @@ def test_metrics_events_jsonl(blob_data, tmp_path):
 
 
 def _fake_key(i: int) -> PlanKey:
-    return PlanKey(k=i + 1, mode="exact", beam=4, kernel=True,
+    return PlanKey(k=i + 1, mode="exact", beam=4,
                    quantize=False, delta_capacity=None, shards=1)
+
+
+# building a plan wraps the backend's body in jax.jit; nothing is traced
+_BACKEND = SingleDeviceBackend()
 
 
 def test_plan_cache_eviction_keeps_lifetime_traces():
     cache = PlanCache(max_plans=2)
     for i in range(4):  # 4 misses into a 2-slot cache -> 2 evictions
-        plan = cache.plan(_fake_key(i))
+        plan = cache.plan(_fake_key(i), _BACKEND)
         plan.traces += 1
     st = cache.stats()
     assert st["evictions"] == 2
@@ -307,10 +312,10 @@ def test_plan_cache_eviction_keeps_lifetime_traces():
 def test_plan_cache_counters_flow_into_registry():
     reg = Registry()
     cache = PlanCache(max_plans=2, registry=reg)
-    cache.plan(_fake_key(0))
-    cache.plan(_fake_key(0))
-    cache.plan(_fake_key(1))
-    cache.plan(_fake_key(2))
+    cache.plan(_fake_key(0), _BACKEND)
+    cache.plan(_fake_key(0), _BACKEND)
+    cache.plan(_fake_key(1), _BACKEND)
+    cache.plan(_fake_key(2), _BACKEND)
     assert reg.value("plan_cache.hits") == 1
     assert reg.value("plan_cache.misses") == 3
     assert reg.value("plan_cache.evictions") == 1

@@ -353,7 +353,7 @@ def test_serving_datastore_rides_routed_layout(trio, data):
     np.testing.assert_array_equal(np.asarray(v_r), np.asarray(v_s))
 
     # inside an outer jit — the engine's decode step boundary
-    jit_knn = jax.jit(forest_knn, static_argnames=("k", "kernel"))
+    jit_knn = jax.jit(forest_knn, static_argnames=("k",))
     d_rj, v_rj = jit_knn(q, ds_r, k=5)
     np.testing.assert_array_equal(np.asarray(d_rj), np.asarray(d_s))
     np.testing.assert_array_equal(np.asarray(v_rj), np.asarray(v_s))

@@ -274,7 +274,7 @@ def test_serving_datastore_rides_sharded_layout(pair, datasets):
 
     # the engine's decode step is the compilation boundary: the island must
     # give the same answers from INSIDE an outer jit
-    jit_knn = jax.jit(forest_knn, static_argnames=("k", "kernel"))
+    jit_knn = jax.jit(forest_knn, static_argnames=("k",))
     d_hj, v_hj = jit_knn(q, ds_h, k=5)
     np.testing.assert_array_equal(np.asarray(d_hj), np.asarray(d_s))
     np.testing.assert_array_equal(np.asarray(v_hj), np.asarray(v_s))

@@ -1,34 +1,49 @@
 """Streaming subsystem tests: ingest routing/append, forest+delta search
 exactness, overlap-drift triggers at the ξ threshold, and rebuild hot swaps
 (structure freshness + no correctness gap)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import Config, IndexConfig, OverlapIndex, StreamConfig
 from repro.core import (
-    IndexConfig,
-    build_index,
     device_forest,
     knn_exact,
-    knn_search,
+    knn_search_impl,
     route_eligibility,
 )
 from repro.stream import (
     MaintenanceConfig,
     OverlapMonitor,
-    StreamingForest,
     alloc_delta,
     delta_view,
     ingest,
     pull_delta_meta,
 )
 
+VBM = IndexConfig(method="vbm", eps=1.5, min_pts=8)
+
+# the search executor over a hand-built DeltaView (the facade owns its own)
+_search = jax.jit(knn_search_impl, static_argnames=("k", "mode", "beam"))
+
+
+def _knn(df, q, k, delta=None):
+    d, ids, stats, _ = _search(df, jnp.asarray(q), k=k, mode="all", delta=delta)
+    return d, ids, stats
+
+
+def _streaming_index(x, *, capacity, **stream):
+    """Build over ``x`` with the drift monitor configured from ``stream``."""
+    return OverlapIndex.build(
+        x, Config(index=VBM, stream=StreamConfig(capacity=capacity, **stream))
+    )
+
 
 @pytest.fixture(scope="module")
 def built(blob_data):
     cfg = IndexConfig(method="vbm", eps=1.5, min_pts=8, xi_min=0.3, xi_max=0.7)
-    forest, _ = build_index(blob_data, cfg)
-    return blob_data, forest
+    return blob_data, OverlapIndex.build(blob_data, Config(index=cfg)).forest
 
 
 def _stream_points(x, n, seed):
@@ -86,15 +101,11 @@ def test_forest_plus_delta_matches_brute_force(built, rng):
     assert bool(np.asarray(acc).all())
     x_all = np.concatenate([x, xs])
     q = rng.normal(size=(24, x.shape[1])).astype(np.float32) * 8
-    d, ids, stats = knn_search(
-        df, jnp.asarray(q), k=12, mode="all", delta=delta_view(delta)
-    )
+    d, ids, stats = _knn(df, q, 12, delta_view(delta))
     de, _ = knn_exact(jnp.asarray(x_all), jnp.asarray(q), k=12)
     np.testing.assert_allclose(np.asarray(d), np.asarray(de), rtol=1e-4, atol=1e-4)
     # returned ids must cover delta members too (streamed points are findable)
-    d2, ids2, _ = knn_search(
-        df, jnp.asarray(xs[:8]), k=1, mode="all", delta=delta_view(delta)
-    )
+    d2, ids2, _ = _knn(df, xs[:8], 1, delta_view(delta))
     np.testing.assert_array_equal(
         np.asarray(ids2)[:, 0], np.arange(8) + len(x)
     )
@@ -105,8 +116,8 @@ def test_empty_delta_is_noop(built, rng):
     df = device_forest(forest)
     delta = alloc_delta(forest, capacity=32)
     q = rng.normal(size=(8, x.shape[1])).astype(np.float32) * 8
-    d0, i0, _ = knn_search(df, jnp.asarray(q), k=7, mode="all")
-    d1, i1, _ = knn_search(df, jnp.asarray(q), k=7, mode="all", delta=delta_view(delta))
+    d0, i0, _ = _knn(df, q, 7)
+    d1, i1, _ = _knn(df, q, 7, delta_view(delta))
     np.testing.assert_allclose(np.asarray(d0), np.asarray(d1), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
 
@@ -138,10 +149,9 @@ def test_overlap_drift_trigger_fires_at_xi():
     x0 = np.concatenate(
         [g.normal(size=(300, dim)), c2 + g.normal(size=(300, dim))]
     ).astype(np.float32)
-    sf = StreamingForest(
-        x0, IndexConfig(method="vbm", eps=1.5, min_pts=8),
-        MaintenanceConfig(method="dbm", xi_rebuild=0.99, fill_rebuild=0.99),
-        delta_capacity=512,
+    sf = _streaming_index(
+        x0, capacity=512,
+        monitor_method="dbm", xi_rebuild=0.99, fill_rebuild=0.99,
     )
     assert sf.forest.n_indexes == 2
     # corridor points inflate the conservative radii -> DBM rate rises
@@ -166,10 +176,9 @@ def test_overlap_drift_trigger_fires_at_xi():
 @pytest.mark.parametrize("method", ["dbm", "obm"])
 def test_monitor_methods_run(built, method):
     x, forest = built
-    sf = StreamingForest(
-        x, IndexConfig(method="vbm", eps=1.5, min_pts=8),
-        MaintenanceConfig(method=method, xi_rebuild=0.9, fill_rebuild=0.9),
-        delta_capacity=64,
+    sf = _streaming_index(
+        x, capacity=64,
+        monitor_method=method, xi_rebuild=0.9, fill_rebuild=0.9,
     )
     sf.ingest(_stream_points(x, 30, seed=3))
     rep = sf.check()
@@ -179,11 +188,10 @@ def test_monitor_methods_run(built, method):
 
 def test_rebuild_swap_exactness_and_fresh_structure(built, rng):
     x, forest = built
-    sf = StreamingForest(
-        x, IndexConfig(method="vbm", eps=1.5, min_pts=8),
+    sf = _streaming_index(
+        x, capacity=128,
         # low fill threshold: rebuilds fire quickly
-        MaintenanceConfig(method="dbm", xi_rebuild=0.95, fill_rebuild=0.2),
-        delta_capacity=128,
+        monitor_method="dbm", xi_rebuild=0.95, fill_rebuild=0.2,
     )
     stats0 = dict(sf.forest.build_stats)
     for step in range(4):
@@ -206,9 +214,7 @@ def test_rebuild_swap_exactness_and_fresh_structure(built, rng):
 def test_stale_tree_copies_detected(built):
     """aggregate_structure must refuse to report over stale host trees."""
     x, forest = built
-    forest2, _ = build_index(
-        x[: len(x) // 2], IndexConfig(method="vbm", eps=1.5, min_pts=8)
-    )
+    forest2 = OverlapIndex.build(x[: len(x) // 2], Config(index=VBM)).forest
     broken = type(forest)(
         index_centers=forest.index_centers,
         index_radii=forest.index_radii,
@@ -233,10 +239,9 @@ def test_stale_tree_copies_detected(built):
 def test_ingest_never_loses_points_under_overflow(built, rng):
     """Forced maintenance on capacity rejects: every point stays findable."""
     x, forest = built
-    sf = StreamingForest(
-        x, IndexConfig(method="vbm", eps=1.5, min_pts=8),
-        MaintenanceConfig(method="dbm", xi_rebuild=0.95, fill_rebuild=0.95),
-        delta_capacity=16,  # tiny: guaranteed overflow
+    sf = _streaming_index(
+        x, capacity=16,  # tiny: guaranteed overflow
+        monitor_method="dbm", xi_rebuild=0.95, fill_rebuild=0.95,
     )
     xs = _stream_points(x, 400, seed=21)
     ids = sf.ingest(xs)
