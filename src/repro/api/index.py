@@ -326,40 +326,46 @@ class OverlapIndex:
         """One search through the plan cache: ``_search_planned``, then the
         device_wait and copy_back spans.  Returns ``(SearchResult, isl,
         router, evidence)`` on the host.  ``isl``/``router`` (island and
-        router telemetry) are fetched only when the registry is enabled;
-        ``evidence`` is ``[VisitRows, home]`` for an explain plan, else
-        empty."""
+        router telemetry) are fetched only when the registry is enabled,
+        else ``None``; ``evidence`` is ``[VisitRows, home]`` for an explain
+        plan, else empty.
+
+        device_wait first requests the host copy of every output it will
+        bring back, so each copy queues behind the program and starts as
+        it ends, then blocks on the outputs; copy_back is ONE fetch of them
+        all."""
         obs = self.obs
         d, i, s, isl, router, plan, *evidence = self._search_planned(
             q, k=k, mode=mode, beam=beam, explain=explain
         )
+        # island/router stats only feed the registry
+        tele = (isl, router) if obs.enabled else (None, None)
+        outs = (d, i, s, evidence, tele)
         with obs.span("device_wait"):
+            for a in jax.tree.leaves(outs):
+                a.copy_to_host_async()
             jax.block_until_ready((d, i, s, isl, router, evidence))
         with obs.span("copy_back"):
-            d, i = self._fetch(d, np.asarray), self._fetch(i, np.asarray)
-            stats = self._fetch(s, stats_to_host)
-            evidence = [self._fetch(x) for x in evidence]
-            if obs.enabled:  # island/router stats only feed the registry
-                isl, router = self._fetch(isl), self._fetch(router)
+            d, i, s, evidence, (isl, router) = self._fetch(outs)
+            stats = stats_to_host(s)
         kk = min(plan.key.k, self.n_total)  # Def. 4: |X| <= k -> all
         if d.shape[1] > kk:
             d, i = d[:, :kk], i[:, :kk]
         res = SearchResult(dists=d, ids=i, stats=stats, plan=plan)
         return res, isl, router, evidence
 
-    def _fetch(self, x, get=jax.device_get):
-        """One blocking device-to-host fetch of ``x`` (a pytree of device
-        arrays; ``None`` fetches nothing), counted into
-        ``search.host_fetches`` and ``search.host_fetch_bytes``."""
-        if x is None:
-            return None
+    def _fetch(self, x):
+        """ONE blocking device-to-host fetch of the pytree ``x`` (one
+        ``jax.device_get``, ``None`` leaves fetch nothing), counted as 1
+        into ``search.host_fetches`` and its arrays' ``nbytes`` into
+        ``search.host_fetch_bytes``."""
         obs = self.obs
         if obs.enabled:
             obs.counter("search.host_fetches").inc()
             obs.counter("search.host_fetch_bytes").inc(
                 sum(a.nbytes for a in jax.tree.leaves(x))
             )
-        return get(x)
+        return jax.device_get(x)
 
     def _record_search(self, stats: dict[str, Any], isl, router=None) -> None:
         """Fold one search's host-side stats into the registry: fleet

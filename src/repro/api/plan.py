@@ -188,12 +188,14 @@ class SearchResult:
 
 
 def stats_to_host(s: SearchStats) -> dict[str, Any]:
-    """SearchStats device arrays -> the host dict ``SearchResult.stats``
-    and the benchmarks report.
+    """SearchStats -> the host dict ``SearchResult.stats`` and the
+    benchmarks report.
 
     ONE ``jax.device_get`` of the whole NamedTuple: per-field ``np.asarray``
     issued six blocking device->host transfers (each waiting on the same
-    executor) where a single batched fetch does."""
+    executor) where a single batched fetch does.  The facade's search hands
+    it host values already (``OverlapIndex._run`` fetches every output of a
+    search at once), and then the get moves nothing."""
     host = jax.device_get(s)
     return {
         "buckets_visited": host.buckets_visited,

@@ -409,12 +409,102 @@ def test_search_counts_its_host_fetches(blob_data):
     fetches0 = idx.obs.value("search.host_fetches")
     bytes0 = idx.obs.value("search.host_fetch_bytes")
     idx.search(q, k=5)
-    # single layout: dists, ids, SearchStats (one batched get), IslandStats
-    assert idx.obs.value("search.host_fetches") - fetches0 == 4
+    # ONE fetch of every output: dists, ids, SearchStats and IslandStats
+    assert idx.obs.value("search.host_fetches") - fetches0 == 1
     assert idx.obs.value("search.host_fetch_bytes") - bytes0 == nbytes
     m = idx.metrics()["search"]
-    assert m["host_fetches"] == fetches0 + 4
+    assert m["host_fetches"] == fetches0 + 1
     assert m["host_fetch_bytes"] == bytes0 + nbytes
+
+
+def _assert_run_matches_planned(idx, q, k, explain):
+    """``_run``'s host values equal a per-leaf ``np.asarray`` of
+    ``_search_planned``'s device outputs for the same queries."""
+    import jax
+
+    d, i, s, isl, router, plan, *evidence = idx._search_planned(
+        q, k=k, explain=explain)
+    res, isl_h, router_h, evidence_h = idx._run(q, k, None, None, explain=explain)
+    kk = min(k, idx.n_total)
+    np.testing.assert_array_equal(res.dists, np.asarray(d)[:, :kk])
+    np.testing.assert_array_equal(res.ids, np.asarray(i)[:, :kk])
+    assert res.stats.keys() == set(s._fields)
+    for name in s._fields:
+        np.testing.assert_array_equal(res.stats[name], np.asarray(getattr(s, name)),
+                                      err_msg=name)
+    if idx.obs.enabled:
+        for got, want in zip(jax.tree.leaves(isl_h), jax.tree.leaves(isl), strict=True):
+            np.testing.assert_array_equal(got, np.asarray(want))
+        assert (router_h is None) == (router is None)
+    else:
+        assert isl_h is None and router_h is None
+    for got, want in zip(jax.tree.leaves(evidence_h), jax.tree.leaves(evidence),
+                         strict=True):
+        np.testing.assert_array_equal(got, np.asarray(want))
+    return res
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["obs-on", "obs-off"])
+def test_one_fetch_equals_per_leaf_fetches(blob_data, enabled):
+    idx = OverlapIndex.build(blob_data, _cfg(obs=enabled))
+    q = np.asarray(blob_data[:8])
+    ref = _assert_run_matches_planned(idx, q, 5, explain=False)
+    rep = _assert_run_matches_planned(idx, q, 5, explain=True)
+    np.testing.assert_array_equal(rep.dists, ref.dists)
+    res = idx.search(q, k=5)
+    np.testing.assert_array_equal(res.dists, ref.dists)
+    np.testing.assert_array_equal(res.ids, ref.ids)
+    for name, want in ref.stats.items():
+        np.testing.assert_array_equal(res.stats[name], want, err_msg=name)
+    # with a delta phase too
+    idx.ingest(np.random.default_rng(2).normal(size=(16, blob_data.shape[1]))
+               .astype(np.float32))
+    _assert_run_matches_planned(idx, q, 5, explain=False)
+    _assert_run_matches_planned(idx, q, 5, explain=True)
+
+
+def test_run_requests_copies_early_and_fetches_once(blob_data, monkeypatch):
+    import jax
+    from jax._src.array import ArrayImpl
+
+    idx = OverlapIndex.build(blob_data, _cfg())
+    q = np.asarray(blob_data[:8])
+    idx.explain(q, k=5)  # compile both plans outside the count
+    idx.search(q, k=5)
+    log = []
+    real_get, real_wait = jax.device_get, jax.block_until_ready
+    real_copy = ArrayImpl.copy_to_host_async
+
+    def device_get(x):
+        # a get of host values (stats_to_host's, after the fetch) moves nothing
+        moved = [a for a in jax.tree.leaves(x) if isinstance(a, jax.Array)]
+        if moved:
+            log.append(("get", len(moved)))
+        return real_get(x)
+
+    def block_until_ready(x):
+        log.append(("wait", None))
+        return real_wait(x)
+
+    def copy_to_host_async(self):
+        log.append(("copy", None))
+        return real_copy(self)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(jax, "block_until_ready", block_until_ready)
+    monkeypatch.setattr(ArrayImpl, "copy_to_host_async", copy_to_host_async)
+    idx.search(q, k=5)
+    # d, i, the 7 SearchStats and 3 IslandStats leaves: each copy requested
+    # before the wait, then ONE device_get of all 12 leaves
+    n = 2 + 7 + 3
+    assert log[:n + 1] == [("copy", None)] * n + [("wait", None)]
+    assert [e for e in log if e[0] == "get"] == [("get", n)]
+    log.clear()
+    idx.explain(q, k=5)
+    # explain adds ``order`` and ``visits`` of its VisitRows (no delta
+    # phase: ``dorder``/``dvisits`` are None) and ``home``
+    assert [e for e in log if e[0] == "get"] == [("get", n + 3)]
+    assert log.index(("wait", None)) == n + 3
 
 
 def test_search_counts_topk_inserts(blob_data):
@@ -469,9 +559,9 @@ def test_explain_and_ingest_spans_split_at_the_device(blob_data):
         assert hists[path]["count"] >= 1, path
     old = ("device_execute", "host_transfer")
     assert not [p for p in hists if p.rsplit("/", 1)[-1] in old]
-    # explain's copy-back counts its fetches too: d, i, stats, rows, home,
-    # and the island stats
-    assert idx.obs.value("search.host_fetches") == 6
+    # explain's copy-back counts its fetch too: ONE of d, i, stats, rows,
+    # home and the island stats
+    assert idx.obs.value("search.host_fetches") == 1
 
 
 # ---------------------------------------------------------------------------

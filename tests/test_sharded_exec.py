@@ -405,3 +405,32 @@ def test_island_counters_sum_to_fleet_totals(datasets):
     fleet = m["search"]["bound_distances"]
     summed = sum(isl["bound_distances"] for isl in m["islands"].values())
     assert summed == fleet + (4 - 1) * m["search"]["queries"] * ix.n_indexes
+
+
+def test_sharded_one_fetch_bitwise(datasets):
+    # 4 shards: every output of a search or an explain comes back in ONE
+    # fetch, bitwise equal to a per-leaf np.asarray of the device outputs
+    x, kw = datasets["blobs"]
+    ix = OverlapIndex.build(x, _obs_cfg(kw))
+    ix.ingest(_queries(x, 40, seed=9))
+    q = _queries(x)
+    for explain in (False, True):
+        d, i, s, isl, _, _, *evidence = ix._search_planned(q, k=7, explain=explain)
+        fetches = ix.obs.value("search.host_fetches")
+        nbytes = ix.obs.value("search.host_fetch_bytes")
+        res, isl_h, _, evidence_h = ix._run(q, 7, None, None, explain=explain)
+        assert ix.obs.value("search.host_fetches") - fetches == 1
+        assert ix.obs.value("search.host_fetch_bytes") - nbytes == sum(
+            a.nbytes for a in jax.tree.leaves((d, i, s, isl, evidence)))
+        np.testing.assert_array_equal(res.dists, np.asarray(d))
+        np.testing.assert_array_equal(res.ids, np.asarray(i))
+        for name in s._fields:
+            np.testing.assert_array_equal(
+                res.stats[name], np.asarray(getattr(s, name)), err_msg=name)
+        for got, want in zip(jax.tree.leaves((isl_h, evidence_h)),
+                             jax.tree.leaves((isl, evidence)), strict=True):
+            np.testing.assert_array_equal(got, np.asarray(want))
+    before = ix.obs.value("search.host_fetches")
+    ix.search(q, k=7)
+    ix.explain(q, k=7)
+    assert ix.obs.value("search.host_fetches") - before == 2
