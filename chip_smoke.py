@@ -139,6 +139,8 @@ def stored_points(ix: OverlapIndex) -> np.ndarray:
         scale = np.asarray(dev.bucket_scale).reshape(-1).astype(np.float64)
         live = ids >= 0
         x[ids[live]] = xq[live] * scale[live, None]
+        if dev.bucket_offset is not None:  # the exact format (byte data)
+            x[ids[live]] += np.asarray(dev.bucket_offset, np.float64)
     return x
 
 

@@ -28,9 +28,12 @@ resolved from ``cfg.layout`` (``make_backend``) owns
                        ``swap_trees`` hot-swaps atomic under sharding.
 
 Quantization order matters for exactness: the sharded upload quantizes the
-UNPADDED members first (identical per-member int8 scales to the single
-path) and only then pads — int8 searches stay bitwise-identical across
-layouts.
+UNPADDED members first (``core.knn.quantized_members``: the same int8
+format, scales and offsets as the single path) and only then pads — int8
+searches stay bitwise-identical across layouts.
+
+Every ``upload_forest`` takes ``span`` (the facade's ``Registry.span``),
+under which the int8 step records as ``quantize``.
 """
 from __future__ import annotations
 
@@ -48,8 +51,8 @@ from repro.core.knn import (
     IslandStats,
     device_forest,
     knn_search_impl,
+    quantized_members,
 )
-from repro.kernels import ops as kops
 from repro.stream.ingest import DeltaBuffer, ingest_impl
 
 
@@ -64,8 +67,10 @@ class SingleDeviceBackend:
     kind = "single"
     shards = 1
 
-    def upload_forest(self, forest: ForestArrays, *, quantize: bool) -> DeviceForest:
-        return device_forest(forest, quantize=quantize)
+    def upload_forest(
+        self, forest: ForestArrays, *, quantize: bool, span=None
+    ) -> DeviceForest:
+        return device_forest(forest, quantize=quantize, span=span)
 
     def place_delta(self, delta: DeltaBuffer) -> DeltaBuffer:
         return delta
@@ -125,19 +130,23 @@ class ShardedBackend:
         spec = P(self.axis) if sharded else P()
         return jax.device_put(x, NamedSharding(self.mesh, spec))
 
-    def upload_forest(self, forest: ForestArrays, *, quantize: bool) -> DeviceForest:
+    def upload_forest(
+        self, forest: ForestArrays, *, quantize: bool, span=None
+    ) -> DeviceForest:
         nb, cap, dim = forest.bucket_x.shape
         n_idx = forest.n_indexes
         nb_pad = _ceil_to(nb, self.shards)
 
-        # quantize BEFORE padding: per-member scales identical to the single
-        # path's device_forest, so int8 results stay bitwise-identical
-        bucket_x = jnp.asarray(forest.bucket_x)
-        bucket_scale = None
+        # quantize BEFORE padding: the int8 format, scales and offsets are
+        # the single path's device_forest, so int8 results stay
+        # bitwise-identical
+        bucket_scale = bucket_offset = None
         if quantize:
-            xq, scale = kops.quantize_datastore(bucket_x.reshape(nb * cap, dim))
-            bucket_x = xq.reshape(nb, cap, dim)
-            bucket_scale = scale.reshape(nb, cap)
+            bucket_x, bucket_scale, bucket_offset = quantized_members(
+                forest, span=span
+            )
+        else:
+            bucket_x = jnp.asarray(forest.bucket_x)
 
         pad = nb_pad - nb
         bucket_ids = np.asarray(forest.bucket_ids)
@@ -186,6 +195,10 @@ class ShardedBackend:
             bucket_scale=(
                 None if bucket_scale is None
                 else self._put(bucket_scale, sharded=True)
+            ),
+            bucket_offset=(
+                None if bucket_offset is None
+                else self._put(bucket_offset, sharded=False)
             ),
         )
 
@@ -256,8 +269,10 @@ class RoutedBackend(ShardedBackend):
         self._router = router
         self.table = None  # replicated device RoutingTable
 
-    def upload_forest(self, forest: ForestArrays, *, quantize: bool) -> DeviceForest:
-        dev = super().upload_forest(forest, quantize=quantize)
+    def upload_forest(
+        self, forest: ForestArrays, *, quantize: bool, span=None
+    ) -> DeviceForest:
+        dev = super().upload_forest(forest, quantize=quantize, span=span)
         self.refresh_table(forest, quantize=quantize)
         return dev
 

@@ -41,7 +41,7 @@ from repro.api.config import (
 from repro.api.executor import make_backend
 from repro.api.plan import PlanCache, PlanKey, SearchResult, stats_to_host
 from repro.core.forest import ForestArrays
-from repro.core.knn import DeviceForest, route_points
+from repro.core.knn import DeviceForest, member_bytes, member_format, route_points
 from repro.core.overlap import get_overlap_method, overlap_matrix
 from repro.core.pipeline import (
     BuildReport,
@@ -50,6 +50,7 @@ from repro.core.pipeline import (
     build_index_core,
     default_delta_capacity,
 )
+from repro.kernels.bucket_scan import tile_bytes
 from repro.obs import (
     EventLog,
     Registry,
@@ -224,13 +225,16 @@ class OverlapIndex:
         Lazy: host-only consumers (build reports, structure rollups, the
         construction benchmarks) never pay the upload — and build wall time
         measures the build, not the transfer.  First search/ingest uploads,
-        inside the span ``build/upload`` whatever span asked for it.
+        inside the span ``build/upload`` whatever span asked for it, with
+        the int8 step (``cfg.search.quantize``) as ``build/upload/quantize``.
         """
         if self._device is None:
             with self.obs.span_at("build/upload"):
                 self._device = jax.block_until_ready(self.backend.upload_forest(
-                    self.forest, quantize=self.cfg.search.quantize
+                    self.forest, quantize=self.cfg.search.quantize,
+                    span=self.obs.span,
                 ))
+            self._set_member_gauges()
         return self._device
 
     def _set_index_gauges(self) -> None:
@@ -240,6 +244,13 @@ class OverlapIndex:
         self.obs.gauge("index.indexes").set(f.n_indexes)
         self.obs.gauge("index.overlap_indexes").set(int(f.is_overlap_index.sum()))
         self.obs.gauge("index.buckets").set(f.n_buckets)
+
+    def _set_member_gauges(self) -> None:
+        """The uploaded member store as gauges: ``index.member_bytes`` (its
+        device bytes) and ``index.member_format`` (``core.knn.MEMBER_FORMATS``:
+        0 f32, 1 int8 exact, 2 int8 scaled)."""
+        self.obs.gauge("index.member_bytes").set(member_bytes(self._device))
+        self.obs.gauge("index.member_format").set(member_format(self._device))
 
     @property
     def delta(self) -> DeltaBuffer | None:
@@ -367,16 +378,29 @@ class OverlapIndex:
             )
         return jax.device_get(x)
 
-    def _record_search(self, stats: dict[str, Any], isl, router=None) -> None:
+    def _record_search(
+        self, stats: dict[str, Any], isl, router=None, *, beam: int
+    ) -> None:
         """Fold one search's host-side stats into the registry: fleet
         node-access counters plus the per-island breakdown the sharded
         executor reports (load balance across shards) — and, on the routed
         layout, the routing tier's dispatch telemetry.  ``isl`` and
-        ``router`` are host values (fetched in the caller's copy_back)."""
+        ``router`` are host values (fetched in the caller's copy_back).
+
+        ``search.tile_bytes`` counts the member-tile bytes the compiled
+        bucket scan brings in (``kernels.bucket_scan.tile_bytes`` from
+        ``SearchStats.steps``), every step priced at the forest's tile,
+        a delta phase's steps included."""
         obs = self.obs
-        obs.counter("search.queries").inc(len(stats["buckets_visited"]))
+        queries = len(stats["buckets_visited"])
+        obs.counter("search.queries").inc(queries)
         for name in ("buckets_visited", "distances", "bound_distances", "topk_inserts"):
             obs.counter(f"search.{name}").inc(int(stats[name].sum()))
+        _, cap, dim = self._device.bucket_x.shape
+        obs.counter("search.tile_bytes").inc(tile_bytes(
+            int(stats["steps"]), queries, beam, cap, dim,
+            self._device.bucket_x.dtype.itemsize,
+        ))
         if router is not None:
             mode = "targeted" if bool(router.targeted) else "all"
             obs.counter("router.queries").inc(len(router.eligible_hosts))
@@ -451,7 +475,7 @@ class OverlapIndex:
             res, isl, router, _ = self._run(q, k, mode, beam)
             with obs.span("record"):
                 if obs.enabled:
-                    self._record_search(res.stats, isl, router)
+                    self._record_search(res.stats, isl, router, beam=res.plan.key.beam)
         return res
 
     def explain(
@@ -477,7 +501,7 @@ class OverlapIndex:
                 q, k, mode, beam, explain=True
             )
             if obs.enabled:
-                self._record_search(res.stats, isl, router)
+                self._record_search(res.stats, isl, router, beam=res.plan.key.beam)
             with obs.span("attribute"):
                 report = self._attribute(rows, res.ids, home)
         report.result = res
@@ -720,7 +744,7 @@ class OverlapIndex:
         # members were absorbed into the new trees); ``dropped`` resets —
         # rejected points were never stored and their owners retry them.
         new_device = self.backend.upload_forest(
-            new_forest, quantize=self.cfg.search.quantize
+            new_forest, quantize=self.cfg.search.quantize, span=self.obs.span
         )
         fresh = alloc_delta(new_forest, self.capacity)
         keep = np.ones(self.forest.n_indexes, bool)
@@ -744,6 +768,7 @@ class OverlapIndex:
         self.backend.barrier(new_device, new_delta)
         self.forest, self._device, self._delta = new_forest, new_device, new_delta
         self._set_index_gauges()
+        self._set_member_gauges()
         self.monitor = self._make_monitor()
         stats["triggers"] = list(triggers)
         stats["reasons"] = dict(report.reasons) if report is not None else {}
@@ -900,7 +925,8 @@ class OverlapIndex:
                 },
                 **{
                     name: snap["gauges"].get(f"index.{name}")
-                    for name in ("indexes", "overlap_indexes", "buckets")
+                    for name in ("indexes", "overlap_indexes", "buckets",
+                                 "member_bytes", "member_format")
                 },
             },
             "search": {
@@ -913,6 +939,7 @@ class OverlapIndex:
                 "distances": obs.value("search.distances"),
                 "bound_distances": obs.value("search.bound_distances"),
                 "topk_inserts": obs.value("search.topk_inserts"),
+                "tile_bytes": obs.value("search.tile_bytes"),
                 "host_fetches": obs.value("search.host_fetches"),
                 "host_fetch_bytes": obs.value("search.host_fetch_bytes"),
             },

@@ -50,6 +50,7 @@ visits and returns the same answer.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import NamedTuple
 
@@ -76,6 +77,8 @@ class DeviceForest(NamedTuple):
     bucket_radius: Array  # (NB,)
     bucket_index: Array  # (NB,) i32
     bucket_scale: Array | None = None  # (NB, C) f32 dequant scales (int8 mode)
+    # (D,) f32 dequant offsets of the exact int8 format; None: zero
+    bucket_offset: Array | None = None
 
 
 class DeltaView(NamedTuple):
@@ -121,17 +124,54 @@ class IslandStats(NamedTuple):
     bound_distances: Array  # (S, Q) i32 per-shard routing + bound distances
 
 
-def device_forest(f: ForestArrays, *, quantize: bool = False) -> DeviceForest:
+# ``index.member_format`` codes of a member store (``member_format``)
+MEMBER_FORMATS = ("f32", "int8 exact", "int8 scaled")
+
+
+def quantized_members(
+    f: ForestArrays, *, span=None
+) -> tuple[Array, Array, Array | None]:
+    """The forest's bucket members in ``kops.quantize_members``' int8
+    format: (bucket_x (NB, C, D) int8, bucket_scale (NB, C), bucket_offset
+    (D,) or None), ready on the device.  Padding members are left out of
+    the format's test.  ``span`` (a ``Registry.span``) times the step as
+    ``quantize``."""
+    with span("quantize") if span else contextlib.nullcontext():
+        nb, cap, dim = f.bucket_x.shape
+        m = kops.quantize_members(
+            jnp.asarray(f.bucket_x).reshape(nb * cap, dim),
+            jnp.asarray(f.bucket_mask).reshape(nb * cap),
+        )
+        return jax.block_until_ready(
+            (m.xq.reshape(nb, cap, dim), m.scale.reshape(nb, cap), m.offset)
+        )
+
+
+def member_format(forest: DeviceForest) -> int:
+    """Index into ``MEMBER_FORMATS`` of an uploaded forest's member store."""
+    if forest.bucket_scale is None:
+        return 0
+    return 1 if forest.bucket_offset is not None else 2
+
+
+def member_bytes(forest: DeviceForest) -> int:
+    """Device bytes of an uploaded forest's member store: the members and,
+    in int8, their scales and offsets."""
+    parts = (forest.bucket_x, forest.bucket_scale, forest.bucket_offset)
+    return sum(int(a.nbytes) for a in parts if a is not None)
+
+
+def device_forest(
+    f: ForestArrays, *, quantize: bool = False, span=None
+) -> DeviceForest:
     """Upload the flattened forest; ``quantize=True`` stores bucket members
-    int8 with per-member scales (kernels/ops.quantize_datastore layout) —
-    4x less HBM traffic on the member scan; bounds/pivots stay f32."""
-    bucket_x = jnp.asarray(f.bucket_x)
-    bucket_scale = None
+    int8 (``quantized_members``: exact on byte-valued data, else per-member
+    scales) — 4x less HBM traffic on the member scan; bounds/pivots stay
+    f32."""
     if quantize:
-        nb, cap, dim = bucket_x.shape
-        xq, scale = kops.quantize_datastore(bucket_x.reshape(nb * cap, dim))
-        bucket_x = xq.reshape(nb, cap, dim)
-        bucket_scale = scale.reshape(nb, cap)
+        bucket_x, bucket_scale, bucket_offset = quantized_members(f, span=span)
+    else:
+        bucket_x, bucket_scale, bucket_offset = jnp.asarray(f.bucket_x), None, None
     return DeviceForest(
         index_centers=jnp.asarray(f.index_centers),
         index_radii=jnp.asarray(f.index_radii),
@@ -143,6 +183,7 @@ def device_forest(f: ForestArrays, *, quantize: bool = False) -> DeviceForest:
         bucket_radius=jnp.asarray(f.bucket_radius),
         bucket_index=jnp.asarray(f.bucket_index),
         bucket_scale=bucket_scale,
+        bucket_offset=bucket_offset,
     )
 
 
@@ -238,6 +279,7 @@ def _scan_phase(
     scan_x: Array,
     scan_ids: Array,
     scan_scale: Array | None,
+    scan_offset: Array | None,
     bucket_count: Array,
     cap: int,
     qmask: Array | None = None,
@@ -274,7 +316,8 @@ def _scan_phase(
         # fused gather -> squared-L2 -> running top-k merge (one kernel step;
         # the (Q, beam, C, D) gather never materializes on the kernel path)
         new_d, new_i, n_ins = scan_step(
-            q, scan_x, scan_ids, bsel, act, c.top_d, c.top_i, scan_scale
+            q, scan_x, scan_ids, bsel, act, c.top_d, c.top_i, scan_scale,
+            scan_offset,
         )
         n_members = jnp.where(act, bucket_count[bsel], 0)  # (Q, beam)
         return _Carry(
@@ -432,15 +475,17 @@ def scan_sorted(
     bucket_count = jnp.sum(forest.bucket_mask, axis=1, dtype=jnp.int32)  # (NB,)
     # tile-align the datastore-sized operands ONCE, outside the loop — the
     # kernel wrapper's defensive per-call pads become no-ops
-    scan_x, scan_ids, scan_scale = kops.bucket_scan_prepad(
-        forest.bucket_x, forest.bucket_ids, forest.bucket_scale
+    scan_x, scan_ids, scan_scale, scan_offset = kops.bucket_scan_prepad(
+        forest.bucket_x, forest.bucket_ids, forest.bucket_scale,
+        forest.bucket_offset,
     )
 
     # order/lb_sorted are padded to exactly n_steps*beam (``_sorted_bounds``)
     n_steps = jnp.int32(bounds.order.shape[1] // beam)
     out = _scan_phase(
         init, q, bounds.order, bounds.lb_sorted, n_steps, beam,
-        kops.bucket_scan_topk, scan_x, scan_ids, scan_scale, bucket_count, cap,
+        kops.bucket_scan_topk, scan_x, scan_ids, scan_scale, scan_offset,
+        bucket_count, cap,
         qmask=qmask,
     )
     total_steps = out.t
@@ -450,11 +495,12 @@ def scan_sorted(
     if delta is not None:
         dcap = delta.x.shape[1]
         dcount = jnp.sum(delta.mask, axis=1, dtype=jnp.int32)  # (I_d,)
-        dx, dids, _ = kops.bucket_scan_prepad(delta.x, delta.ids, None)
+        dx, dids, _, _ = kops.bucket_scan_prepad(delta.x, delta.ids)
         n_steps_d = jnp.int32(dbounds.order.shape[1] // beam)
         out = _scan_phase(
             out._replace(t=jnp.int32(0)), q, dbounds.order, dbounds.lb_sorted,
-            n_steps_d, beam, kops.delta_scan_topk, dx, dids, None, dcount, dcap,
+            n_steps_d, beam, kops.delta_scan_topk, dx, dids, None, None,
+            dcount, dcap,
             qmask=qmask,
         )
         total_steps = total_steps + out.t

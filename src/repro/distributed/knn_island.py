@@ -72,6 +72,7 @@ def forest_specs(forest: cknn.DeviceForest, axis: str) -> cknn.DeviceForest:
         bucket_radius=row,
         bucket_index=row,
         bucket_scale=None if forest.bucket_scale is None else row,
+        bucket_offset=None if forest.bucket_offset is None else P(),
     )
 
 

@@ -45,6 +45,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.knn import quantized_members
+from repro.kernels.ops import Int8Members, dequantize_members
+
 Array = jax.Array
 
 # relative inflation applied to host-side covers before the f64 -> f32 cast:
@@ -86,17 +89,15 @@ def _conservative_f32(a: np.ndarray) -> np.ndarray:
     return ((1.0 + _COVER_SLACK) * a + _COVER_SLACK).astype(np.float32)
 
 
-def _dequantized_members(xs: np.ndarray) -> np.ndarray:
-    """Replicate kernels/ops.quantize_datastore's int8 round trip bitwise
-    (same f32 IEEE ops, np.rint == jnp.round half-to-even): the positions
-    a ``quantize=True`` scan actually measures distances to."""
-    nb, cap, dim = xs.shape
-    flat = xs.reshape(nb * cap, dim).astype(np.float32)
-    scale = np.maximum(np.max(np.abs(flat), axis=1), 1e-8) / 127.0
-    xq = np.clip(np.rint(flat / scale[:, None]), -127, 127)
-    return (xq.astype(np.float32) * scale[:, None].astype(np.float32)).reshape(
-        nb, cap, dim
-    )
+def _dequantized_members(f) -> np.ndarray:
+    """(NB, C, D) f32: the forest's members in the int8 format the upload
+    stores (``core.knn.quantized_members``), dequantized as the bucket-scan
+    kernel does (``kernels.ops.dequantize_members``) — the positions a
+    ``quantize=True`` scan actually measures distances to."""
+    xq, scale, offset = quantized_members(f)
+    nb, cap, dim = xq.shape
+    m = Int8Members(xq.reshape(nb * cap, dim), scale.reshape(nb * cap), offset)
+    return np.asarray(dequantize_members(m)).reshape(nb, cap, dim)
 
 
 def build_routing_table(
@@ -125,7 +126,7 @@ def build_routing_table(
     owner = shard_owners(nb, shards)
     members = np.asarray(f.bucket_x, np.float32)  # (NB, C, D)
     if quantize:
-        members = _dequantized_members(members)
+        members = _dequantized_members(f)
         # per-bucket cover of the dequantized members around the pivot
         d_pm = np.linalg.norm(
             members.astype(np.float64) - pivots[:, None, :], axis=2

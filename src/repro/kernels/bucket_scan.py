@@ -40,9 +40,13 @@ double-buffered, 2 x 8 member tiles among them; where that outgrows the
 compiler's default scoped VMEM the call raises its own limit
 (``_vmem_limit``), from the shapes it is given.
 
-An int8 variant dequantizes the gathered bucket tile in-register against
-per-member scales (``ops.quantize_datastore`` layout), quartering the HBM
-traffic of the member gather — the memory-roofline lever for serving.
+An int8 variant brings in int8 member tiles, a quarter of the f32 tile's
+bytes, and dequantizes each in VMEM as ``x = xq * scale[member] +
+offset[dim]`` (``ops.quantize_members``' format: per-member scales as
+``(1, 1, C)`` rows beside the ids, the per-dimension offset as one ``(1,
+D)`` block whose index map is constant) before the same f32 ``HIGHEST``
+distance math as the f32 variant.  On byte-valued members the format is
+exact (scale 1), so the distances are the f32 variant's, bit for bit.
 
 Validated against ``ref.bucket_scan_topk_ref`` in tests/test_bucket_scan.py
 (interpret mode on CPU, compiled on real TPU).
@@ -78,14 +82,15 @@ def _scan_kernel(
     act_ref,  # scalar prefetch (Qp * beam,) i32
     q_ref,  # (QB, Dp) the query block
     *rest,  # QB x (1, Cp, Dp) tiles, QB x (1, 1, Cp) ids, [QB x (1, 1, Cp)
-    #         scales,] top_d, top_i, o_val, o_idx, o_ins
+    #         scales, (1, Dp) offset,] top_d, top_i, o_val, o_idx, o_ins
     kk: int,
     beam: int,
     quantized: bool,
 ):
     x_refs, ids_refs = rest[:QB], rest[QB:2 * QB]
     scale_refs = rest[2 * QB:3 * QB] if quantized else None
-    top_d_ref, top_i_ref, o_val_ref, o_idx_ref, o_ins_ref = rest[(3 if quantized else 2) * QB:]
+    offset_ref = rest[3 * QB] if quantized else None
+    top_d_ref, top_i_ref, o_val_ref, o_idx_ref, o_ins_ref = rest[(3 * QB + 1 if quantized else 2 * QB):]
     g = pl.program_id(0)
     b = pl.program_id(1)
 
@@ -106,7 +111,8 @@ def _scan_kernel(
     for r in range(QB):
         x = x_refs[r][0].astype(jnp.float32)  # (Cp, Dp)
         if quantized:
-            x = x * scale_refs[r][0].T  # per-member dequant scales as a column
+            # per-member scales as a column, per-dimension offsets as a row
+            x = x * scale_refs[r][0].T + offset_ref[...]
         xx = jnp.sum(x * x, axis=1)  # (Cp,)
         cross = jax.lax.dot_general(
             qv, x, (((1,), (1,)), ((), ())),
@@ -129,14 +135,17 @@ def _vmem_limit(cp: int, dp: int, kkp: int, itemsize: int, quantized: bool) -> i
     """``vmem_limit_bytes`` for one call, or None where the default will do.
 
     The pipeline double-buffers every block: QB member tiles with their id
-    (and scale) rows, the query block, the four top-k blocks and the count
-    block.  The body adds f32 working copies of a tile and, per row, a
-    128-lane column of squared norms.  In (Cp, 128) f32 columns, the v5e compiler asked for
+    (and scale) rows, the int8 variant's offset block (one 8-sublane f32
+    tile), the query block, the four top-k blocks and the count block.  The
+    body adds f32 working copies of a tile and, per row, a 128-lane column
+    of squared norms.  In (Cp, 128) f32 columns, the v5e compiler asked for
     about 10 beyond the buffers at 128-d and 20 at 576-d (tiles of 1,024 to
     3,200 members); this counts 11 and 23, and a quarter on top.
     """
     rows = QB * cp * 4 * (2 if quantized else 1)
     blocks = QB * cp * dp * itemsize + rows + QB * dp * 4 + 4 * QB * kkp * 4 + QB * 128 * 4
+    if quantized:
+        blocks += QB * dp * 4  # the offset block
     body = 3 * cp * dp * 4 + QB * cp * 128 * 4
     limit = (2 * blocks + body) * 5 // 4
     return None if limit <= SCOPED_VMEM_BYTES else limit
@@ -163,6 +172,16 @@ def _pad_multiples(interpret: bool) -> tuple[int, int]:
     return (8, 2) if interpret else (128, 128)
 
 
+def tile_bytes(steps: int, queries: int, beam: int, cap: int, dim: int, itemsize: int) -> int:
+    """Member-tile bytes the compiled scan brings in over ``steps`` scan
+    steps of ``queries`` queries: ceil(Q / 8) x beam programs a step, each
+    8 tiles of Cp x Dp members of ``itemsize`` bytes, C and D padded as
+    the compiled kernel pads them."""
+    lane, cmult = _pad_multiples(False)
+    cp, dp = -(-cap // cmult) * cmult, -(-dim // lane) * lane
+    return steps * -(-queries // QB) * beam * QB * cp * dp * itemsize
+
+
 def _member_rows(a: Array, cmult: int, value) -> Array:
     """(NB, C) per-member operand -> (NB, 1, Cp): one lane-major row per
     bucket, so a one-bucket block spans the array's last two dims.
@@ -176,13 +195,16 @@ def prepad_buckets(
     bucket_x: Array,
     bucket_ids: Array,
     scale: Array | None = None,
+    offset: Array | None = None,
     *,
     interpret: bool = False,
-) -> tuple[Array, Array, Array | None]:
+) -> tuple[Array, Array, Array | None, Array | None]:
     """Lay out the per-datastore operands for the kernel's blocks ONCE.
 
     Pads ``bucket_x`` to (NB, Cp, Dp) and turns ``bucket_ids``/``scale``
-    into (NB, 1, Cp) rows.  ``bucket_scan_topk_pallas`` does the same on
+    into (NB, 1, Cp) rows.  An int8 store (``scale`` given) gets its (D,)
+    ``offset`` as a (1, Dp) row, zero-padded, or zeros where the format
+    has none.  ``bucket_scan_topk_pallas`` does the same on
     every call, as a no-op on operands prepared here; done inside a search
     while-loop it would copy the whole datastore each step, so callers that
     loop (core/knn.py) prepare at upload time.
@@ -190,10 +212,13 @@ def prepad_buckets(
     lane, cmult = _pad_multiples(interpret)
     xp = _pad_to(_pad_to(bucket_x, 2, lane), 1, cmult)
     idsp = _member_rows(bucket_ids, cmult, -1)
-    scalep = None if scale is None else _member_rows(
-        scale.astype(jnp.float32), cmult, 0.0
-    )
-    return xp, idsp, scalep
+    scalep = offsetp = None
+    if scale is not None:
+        scalep = _member_rows(scale.astype(jnp.float32), cmult, 0.0)
+        if offset is None:
+            offset = jnp.zeros((bucket_x.shape[2],), jnp.float32)
+        offsetp = _pad_to(offset.astype(jnp.float32).reshape(1, -1), 1, lane)
+    return xp, idsp, scalep, offsetp
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -206,6 +231,7 @@ def bucket_scan_topk_pallas(
     top_d: Array,  # (Q, kk) running top-k squared distances (ascending)
     top_i: Array,  # (Q, kk) running top-k object ids
     scale: Array | None = None,  # (NB, C) f32 when bucket_x is int8
+    offset: Array | None = None,  # (D,) f32 with scale, or None: zero
     *,
     interpret: bool = False,
 ) -> tuple[Array, Array, Array]:
@@ -221,15 +247,15 @@ def bucket_scan_topk_pallas(
             bucket_scan_topk_pallas(
                 q[lo:lo + rows], bucket_x, bucket_ids, bsel[lo:lo + rows],
                 act[lo:lo + rows], top_d[lo:lo + rows], top_i[lo:lo + rows],
-                scale, interpret=interpret,
+                scale, offset, interpret=interpret,
             )
             for lo in range(0, qn, rows)
         ]
         return tuple(jnp.concatenate(p, axis=0) for p in zip(*parts))
 
     lane, _ = _pad_multiples(interpret)
-    xp, idsp, scalep = prepad_buckets(
-        bucket_x, bucket_ids, scale, interpret=interpret
+    xp, idsp, scalep, offsetp = prepad_buckets(
+        bucket_x, bucket_ids, scale, offset, interpret=interpret
     )
     qp = _pad_to(_pad_to(q.astype(jnp.float32), 1, lane), 0, QB)
     qpn = qp.shape[0]
@@ -251,11 +277,15 @@ def bucket_scan_topk_pallas(
     def query_block(g, b, bsel, act):
         return (g, 0)
 
+    def whole(g, b, bsel, act):
+        return (0, 0)
+
     in_specs = [
         pl.BlockSpec((QB, dp), query_block),
         *[pl.BlockSpec((1, cp, dp), member(r)) for r in range(QB)],
         *[pl.BlockSpec((1, 1, cp), member(r)) for r in range(QB)],
         *([pl.BlockSpec((1, 1, cp), member(r)) for r in range(QB)] if quantized else []),
+        *([pl.BlockSpec((1, dp), whole)] if quantized else []),
         pl.BlockSpec((QB, kkp), query_block),
         pl.BlockSpec((QB, kkp), query_block),
     ]
@@ -289,7 +319,7 @@ def bucket_scan_topk_pallas(
         qp,
         *[xp] * QB,
         *[idsp] * QB,
-        *([scalep] * QB if quantized else []),
+        *([scalep] * QB + [offsetp] if quantized else []),
         top_dp,
         top_ip,
     )

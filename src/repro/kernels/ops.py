@@ -14,11 +14,18 @@ Dispatch policy (each wrapper below):
   tests/test_kernels_pairwise.py and tests/test_bucket_scan.py.
 
 Datastore storage knobs:
-* ``quantize_datastore`` produces the symmetric per-row int8 layout; the
-  ``*_int8`` kernels and the ``scale=`` argument of ``bucket_scan_topk``
-  dequantize in-register (4x less HBM traffic than f32 on the scan).
-  The forest equivalent is ``core.knn.device_forest(..., quantize=True)``,
-  which stores ``bucket_x`` int8 with per-member scales.
+* ``quantize_datastore`` produces the symmetric per-row int8 layout of the
+  flat kNN-LM datastore; the ``*_int8`` kernels dequantize it in-register
+  (4x less HBM traffic than f32 on the scan).
+* ``quantize_members`` owns the forest's int8 member format, which
+  ``core.knn.device_forest(..., quantize=True)``, the sharded upload and
+  the routing table's covers all use: ``x = xq * scale[member] +
+  offset[dim]``.  Byte-valued members (integers, each dimension spanning
+  at most 256 consecutive values) are held exactly: scale 1, offset the
+  dimension's minimum + 128.  Any other data keeps ``quantize_datastore``'s
+  per-member scales, bit for bit, with no offset (zero).  The ``scale=`` and
+  ``offset=`` arguments of ``bucket_scan_topk`` dequantize in VMEM before
+  the f32 distance math.
 
 Streaming delta buckets (repro.stream): the per-index append buffers are
 scanned by the SAME fused bucket-scan kernel — a delta buffer is just a
@@ -32,6 +39,7 @@ they pick up int8 storage when maintenance absorbs them into the tree.
 from __future__ import annotations
 
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -122,8 +130,11 @@ def knn_topk(q: Array, x: Array, *, k: int) -> tuple[Array, Array]:
 
 
 def bucket_scan_prepad(
-    bucket_x: Array, bucket_ids: Array, scale: Array | None = None
-) -> tuple[Array, Array, Array | None]:
+    bucket_x: Array,
+    bucket_ids: Array,
+    scale: Array | None = None,
+    offset: Array | None = None,
+) -> tuple[Array, Array, Array | None, Array | None]:
     """Apply ``bucket_scan_topk``'s padding policy once, at upload time.
 
     Looping callers (core/knn.py's while-loop) pre-pad the datastore-sized
@@ -132,10 +143,10 @@ def bucket_scan_prepad(
     jnp-reference path (no tiling there).
     """
     if _on_tpu():
-        return prepad_buckets(bucket_x, bucket_ids, scale, interpret=False)
+        return prepad_buckets(bucket_x, bucket_ids, scale, offset, interpret=False)
     if _force_pallas():
-        return prepad_buckets(bucket_x, bucket_ids, scale, interpret=True)
-    return bucket_x, bucket_ids, scale
+        return prepad_buckets(bucket_x, bucket_ids, scale, offset, interpret=True)
+    return bucket_x, bucket_ids, scale, offset
 
 
 def bucket_scan_topk(
@@ -147,21 +158,21 @@ def bucket_scan_topk(
     top_d: Array,
     top_i: Array,
     scale: Array | None = None,
+    offset: Array | None = None,
 ) -> tuple[Array, Array, Array]:
     """Fused forest-scan step: gather ``bsel`` buckets, distances, top-k merge.
 
     Returns the merged (top_d, top_i) and each query's (Q,) count of
     candidates that entered its top-k.  See kernels/bucket_scan.py for the
-    kernel and kernels/ref.py for the oracle.  ``scale`` enables the int8
-    bucket storage path.
+    kernel and kernels/ref.py for the oracle.  ``scale`` (and ``offset``)
+    enable the int8 bucket storage path (``quantize_members``' format).
     """
+    args = (q, bucket_x, bucket_ids, bsel, act, top_d, top_i, scale, offset)
     if _on_tpu():
-        return bucket_scan_topk_pallas(q, bucket_x, bucket_ids, bsel, act, top_d, top_i, scale)
+        return bucket_scan_topk_pallas(*args)
     if _force_pallas():
-        return bucket_scan_topk_pallas(
-            q, bucket_x, bucket_ids, bsel, act, top_d, top_i, scale, interpret=True
-        )
-    return ref.bucket_scan_topk_ref(q, bucket_x, bucket_ids, bsel, act, top_d, top_i, scale)
+        return bucket_scan_topk_pallas(*args, interpret=True)
+    return ref.bucket_scan_topk_ref(*args)
 
 
 # The streaming delta phase dispatches through the identical kernel step —
@@ -174,3 +185,55 @@ def quantize_datastore(x: Array) -> tuple[Array, Array]:
     scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1), 1e-8) / 127.0
     xq = jnp.clip(jnp.round(x / scale[:, None]), -127, 127).astype(jnp.int8)
     return xq, scale.astype(jnp.float32)
+
+
+class Int8Members(NamedTuple):
+    """The forest's int8 member store: ``x = xq * scale[member] + offset``.
+
+    ``offset`` is None (zero) in the scaled format; its presence marks the
+    exact format, in which every dequantized member is its input, bit for
+    bit."""
+
+    xq: Array  # (N, D) int8
+    scale: Array  # (N,) f32
+    offset: Array | None  # (D,) f32, or None: zero
+
+
+def quantize_members(x: Array, valid: Array | None = None) -> Int8Members:
+    """The one int8 format of the forest's bucket members.
+
+    Looks at the ``valid`` (N,) rows of ``x`` (N, D) (all rows when None;
+    padding rows are stored but never read back).  Where every coordinate
+    is an integer and each dimension's values span at most 256 consecutive
+    integers, as byte data (int8, uint8) does, the store is exact: scale
+    1, ``offset[d] = min_d + 128`` and ``xq = x - offset`` in [-128, 127].
+    The test is the round trip itself: the format is exact only where
+    ``xq + offset`` gives back every valid coordinate.  Any other data keeps
+    ``quantize_datastore``'s symmetric per-member scale, the same int8
+    bytes and scales, with no offset.
+    """
+    x = jnp.asarray(x, jnp.float32)
+    valid = jnp.ones(x.shape[:1], bool) if valid is None else jnp.asarray(valid, bool)
+    xq, offset, exact = _exact_members(x, valid)
+    if bool(exact):
+        return Int8Members(xq, jnp.ones(x.shape[:1], jnp.float32), offset)
+    xq, scale = quantize_datastore(x)
+    return Int8Members(xq, scale, None)
+
+
+@jax.jit
+def _exact_members(x: Array, valid: Array) -> tuple[Array, Array, Array]:
+    """The exact format's int8 bytes and (D,) offsets, and whether they
+    give back every valid coordinate: one program over the whole store."""
+    lo = jnp.min(jnp.where(valid[:, None], x, jnp.inf), axis=0)
+    # a dimension with no valid row (an empty store) gets offset 0
+    offset = jnp.where(jnp.isfinite(lo), lo, -128.0) + 128.0
+    xq = jnp.clip(jnp.round(x - offset), -128, 127)
+    exact = jnp.all((xq + offset == x) | ~valid[:, None])
+    return xq.astype(jnp.int8), offset, exact
+
+
+def dequantize_members(m: Int8Members) -> Array:
+    """(N, D) f32 members as the bucket-scan kernel dequantizes them."""
+    x = m.xq.astype(jnp.float32) * m.scale[:, None]
+    return x if m.offset is None else x + m.offset[None, :]

@@ -84,14 +84,16 @@ def bucket_scan_topk_ref(
     top_d: Array,
     top_i: Array,
     scale: Array | None = None,
+    offset: Array | None = None,
 ) -> tuple[Array, Array, Array]:
     """One forest-scan step: gather selected buckets, distance, top-k merge.
 
     q (Q, D); bucket_x (NB, C, D) f32 or int8 (then ``scale`` (NB, C) holds
-    per-member dequant scales); bsel/act (Q, beam); top_d/top_i (Q, kk) the
-    running per-query top-k (squared distances ascending, object ids).
-    Members with id < 0 (padding) and buckets with act == False contribute
-    nothing.  Returns the merged (top_d, top_i) and (Q,) i32 inserts: the
+    per-member dequant scales and ``offset`` (D,), or None for zero, the
+    per-dimension offsets: ``x = xq * scale + offset``); bsel/act (Q, beam);
+    top_d/top_i (Q, kk) the running per-query top-k (squared distances
+    ascending, object ids).  Members with id < 0 (padding) and buckets with
+    act == False contribute nothing.  Returns the merged (top_d, top_i) and (Q,) i32 inserts: the
     candidates that entered the running top-k, bucket by bucket in beam
     order, as the kernel's insertion merge counts them (``merge_counting``).
     """
@@ -100,6 +102,8 @@ def bucket_scan_topk_ref(
     bx = bucket_x[bsel]  # (Q, beam, C, D)
     if scale is not None:
         bx = bx.astype(jnp.float32) * scale[bsel][..., None].astype(jnp.float32)
+        if offset is not None:
+            bx = bx + offset.astype(jnp.float32)
     else:
         bx = bx.astype(jnp.float32)
     bids = bucket_ids[bsel]  # (Q, beam, C)

@@ -15,6 +15,7 @@ import pytest
 
 from repro.api import Config, IndexConfig, OverlapIndex, SearchConfig
 from repro.core import knn_exact
+from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.bucket_scan import bucket_scan_topk_pallas
 from repro.kernels.ops import quantize_datastore
@@ -389,3 +390,169 @@ def test_quantized_bucket_storage_recall(small_forest):
     )
     assert recall >= 0.9, recall
     np.testing.assert_allclose(d, np.asarray(de), rtol=0.05, atol=0.05)
+
+
+# ---------------------------------------------------------------------------
+# the int8 member format (ops.quantize_members): exact on byte data
+# ---------------------------------------------------------------------------
+
+def _bytes(g, n, dim, signed):
+    """Byte-valued f32 rows: int8 or uint8 values, as published datasets
+    hold them (SPACEV int8, BIGANN uint8)."""
+    lo, hi = (-128, 128) if signed else (0, 256)
+    return g.integers(lo, hi, (n, dim)).astype(np.float32)
+
+
+@pytest.mark.parametrize("signed", [True, False], ids=["int8", "uint8"])
+@pytest.mark.parametrize("dim", [100, 5])
+def test_member_format_round_trips_bytes_exactly(dim, signed, rng):
+    x = _bytes(rng, 300, dim, signed)
+    valid = rng.random(300) < 0.8
+    x[~valid] = 0.0  # padding rows, outside the valid set, as a forest pads
+    if not signed:
+        x[valid] += 7.0  # values 7..262: 256 consecutive integers, zero outside
+    m = kops.quantize_members(x, valid)
+    assert m.xq.dtype == jnp.int8 and m.offset is not None
+    assert (np.asarray(m.scale) == 1.0).all()
+    back = np.asarray(kops.dequantize_members(m))
+    np.testing.assert_array_equal(back[valid], x[valid])
+
+
+@pytest.mark.parametrize(
+    "make",
+    [
+        lambda g: g.normal(size=(200, 12)).astype(np.float32) * 4,
+        lambda g: np.rint(g.normal(size=(200, 12)) * 300).astype(np.float32),  # spans > 256
+        lambda g: _bytes(g, 200, 12, True) + g.random((200, 12)).astype(np.float32),
+    ],
+    ids=["gaussian", "wide-integers", "fractions"],
+)
+def test_member_format_keeps_per_member_scale_elsewhere(make, rng):
+    """Data that is not byte-valued keeps the symmetric per-member int8
+    scale, the same bytes and scales as ``quantize_datastore``."""
+    x = make(rng)
+    m = kops.quantize_members(x)
+    xq, scale = quantize_datastore(jnp.asarray(x))
+    assert m.offset is None
+    np.testing.assert_array_equal(np.asarray(m.xq), np.asarray(xq))
+    np.testing.assert_array_equal(np.asarray(m.scale), np.asarray(scale))
+
+
+@pytest.mark.parametrize("dim", [100, 5])
+def test_bucket_scan_exact_int8_matches_ref_bitwise(dim, rng):
+    """The kernel's int8 branch dequantizes with the offset as the oracle
+    does: on integer queries, whose distances are exact in f32, the two
+    agree bit for bit; on any queries, byte members scan in int8 to the f32
+    scan's answer, bit for bit, in the kernel and in the oracle alike."""
+    qn, nb, cap, beam, kk = 19, 9, 12, 3, 10
+    q, _, ids, bsel, act, top_d, top_i = _problem(rng, qn, nb, cap, dim, beam, kk)
+    bx = _bytes(rng, nb * cap, dim, True)
+    m = kops.quantize_members(bx)
+    assert m.offset is not None
+    bxq, bscale = m.xq.reshape(nb, cap, dim), m.scale.reshape(nb, cap)
+    bx = jnp.asarray(bx.reshape(nb, cap, dim))
+
+    def same(a, b):
+        for u, v in zip(a, b):
+            np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+
+    rest = (ids, bsel, act, top_d, top_i)
+    qi = jnp.asarray(rng.integers(-128, 128, (qn, dim)), jnp.float32)
+    same(bucket_scan_topk_pallas(qi, bxq, *rest, bscale, m.offset, interpret=True),
+         ref.bucket_scan_topk_ref(qi, bxq, *rest, bscale, m.offset))
+    same(bucket_scan_topk_pallas(q, bxq, *rest, bscale, m.offset, interpret=True),
+         bucket_scan_topk_pallas(q, bx, *rest, interpret=True))
+    same(ref.bucket_scan_topk_ref(q, bxq, *rest, bscale, m.offset),
+         ref.bucket_scan_topk_ref(q, bx, *rest))
+
+
+def _byte_index(x, **search):
+    cfg = Config(index=IndexConfig(method="vbm", eps=40.0, min_pts=6, c_max=8),
+                 search=SearchConfig(**search))
+    return OverlapIndex.build(x, cfg)
+
+
+@pytest.fixture(scope="module")
+def byte_data():
+    """Three int8 blobs, 100-d (SPACEV's published form)."""
+    g = np.random.default_rng(17)
+    centres = g.integers(-60, 60, (3, 100))
+    x = np.concatenate([c + g.integers(-9, 10, (40, 100)) for c in centres])
+    q = x[g.choice(len(x), 10)] + g.normal(0, 4, (10, 100))
+    return x.astype(np.float32), q.astype(np.float32)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["ref", "interpret"])
+def test_int8_search_bitwise_on_byte_data(byte_data, kernel, tmp_path, monkeypatch):
+    """quantize=True over byte data returns the quantize=False search's
+    dists, ids and stats bit for bit, and so does its save/load restart."""
+    if kernel:
+        monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    x, q = byte_data
+    f32, i8 = _byte_index(x), _byte_index(x, quantize=True)
+    assert i8.device.bucket_x.dtype == jnp.int8
+    assert i8.metrics()["build"]["member_format"] == 1
+    want = f32.search(q, k=7, mode="all")
+    loaded = OverlapIndex.load(i8.save(tmp_path / "i8"))
+    for got in (i8.search(q, k=7, mode="all"), loaded.search(q, k=7, mode="all")):
+        np.testing.assert_array_equal(got.dists, want.dists)
+        np.testing.assert_array_equal(got.ids, want.ids)
+        assert set(got.stats) == set(want.stats)
+        for name in want.stats:
+            np.testing.assert_array_equal(got.stats[name], want.stats[name])
+
+
+def test_member_gauges_and_tile_bytes(byte_data):
+    """``index.member_bytes`` is the store's device bytes, a quarter of f32's
+    plus the scales and offsets; ``search.tile_bytes`` is the hand count
+    programs x 8 x Cp x Dp x itemsize of one batch."""
+    x, q = byte_data
+    f32, i8 = _byte_index(x), _byte_index(x, quantize=True)
+    for ix in (f32, i8):
+        ix.search(q, k=5, mode="all", beam=2)
+    nb, cap, dim = f32.device.bucket_x.shape
+    b32, b8 = (ix.metrics()["build"] for ix in (f32, i8))
+    assert (b32["member_format"], b8["member_format"]) == (0, 1)
+    assert b32["member_bytes"] == nb * cap * dim * 4
+    assert b8["member_bytes"] == nb * cap * dim + nb * cap * 4 + dim * 4
+    for ix, itemsize in ((f32, 4), (i8, 1)):
+        steps = int(ix.search(q, k=5, mode="all", beam=2).stats["steps"])
+        # 10 queries: 2 blocks of 8 a beam slot; C 8 -> 128, D 100 -> 128
+        programs = steps * 2 * 2
+        assert ix.metrics()["search"]["tile_bytes"] == (
+            2 * programs * 8 * 128 * 128 * itemsize  # two equal searches
+        )
+
+
+@pytest.mark.parametrize("data", ["bytes", "gaussian"])
+def test_routed_covers_enclose_the_kernels_members(data, byte_data):
+    """The routing table's int8 covers are built around the members as the
+    kernel dequantizes them (``x = xq * scale + offset``), bit for bit, in
+    both formats, and enclose every one of them."""
+    from repro.distributed.router.table import _dequantized_members, build_routing_table
+
+    x = byte_data[0] if data == "bytes" else (
+        np.random.default_rng(4).normal(size=(120, 100)).astype(np.float32) * 9
+    )
+    ix = _byte_index(x, quantize=True)
+    f, dev = ix.forest, ix.device
+    assert (dev.bucket_offset is not None) == (data == "bytes")
+    kernel = np.asarray(dev.bucket_x, np.float32) * np.asarray(dev.bucket_scale)[..., None]
+    if dev.bucket_offset is not None:
+        kernel = kernel + np.asarray(dev.bucket_offset)
+    members = _dequantized_members(f)
+    np.testing.assert_array_equal(members, kernel)
+    shards = 3
+    tab = build_routing_table(f, shards, quantize=True)
+    mask = np.asarray(f.bucket_mask)
+    owner = np.arange(f.n_buckets) // (-(-f.n_buckets // shards))
+    centers = np.asarray(f.index_centers, np.float64)
+    for b in range(f.n_buckets):
+        pts = members[b][mask[b]].astype(np.float64)
+        if not len(pts):
+            continue
+        h, i = owner[b], int(f.bucket_index[b])
+        r_hi = float(np.asarray(tab.radius_hi)[h, i])
+        assert (np.linalg.norm(pts - centers[i], axis=1) <= r_hi).all()
+        d_host = np.linalg.norm(pts - np.asarray(tab.host_centers[h], np.float64), axis=1)
+        assert (d_host <= float(tab.host_radii[h])).all()

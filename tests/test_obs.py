@@ -611,4 +611,5 @@ def test_disabled_obs_times_no_build_phase(blob_data, tmp_path):
         "ix", OverlapIndex.build(blob_data, _cfg(obs=False))))
     assert not [n for n, _, _ in host if n.startswith("build")]
     m = got["ix"].metrics()["build"]
-    assert m == {"spans": {}, "indexes": None, "overlap_indexes": None, "buckets": None}
+    assert m == {"spans": {}, "indexes": None, "overlap_indexes": None, "buckets": None,
+                 "member_bytes": None, "member_format": None}

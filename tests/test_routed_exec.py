@@ -374,6 +374,22 @@ def test_plan_keys_carry_fanout(trio, data):
     assert "routed" in repr(routed)
 
 
+def test_routed_int8_on_byte_data_bitwise_to_f32(data, tmp_path):
+    """Byte-valued members stored int8 are held exactly, so the routed int8
+    search, and its save/load restart, return the single f32 search's
+    dists and ids bit for bit: the covers enclose the members as stored."""
+    x = np.rint(data)
+    q = _queries(x)
+    want = OverlapIndex.build(x, _cfg()).search(q, k=9, mode="forest")
+    routed = OverlapIndex.build(x, _cfg(quantize=True, layout=ROUTED4))
+    assert routed.device.bucket_offset is not None
+    loaded = OverlapIndex.load(routed.save(tmp_path / "routed_i8"))
+    for ix in (routed, loaded):
+        assert ix.backend.kind == "routed"
+        _assert_same_results(ix.search(q, k=9, mode="forest"), want)
+    assert routed.metrics()["router"]["pruned_hosts"] > 0
+
+
 def test_routed_explain_bitwise_with_router_stats(trio, data):
     single, _, routed = trio()
     q = _queries(data, 8)

@@ -158,6 +158,24 @@ def test_search_bitwise_across_layouts(pair, datasets, name, quantize):
 # ingest: collective scatter == single-device routing, rejects aggregate
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("name", ["blobs", "tracks"])
+def test_int8_on_byte_data_bitwise_to_f32(datasets, name):
+    """Byte-valued members (the data rounded to integers) stored int8 are
+    held exactly: the sharded int8 search returns the f32 search's dists,
+    ids and stats on either layout, bit for bit."""
+    x, kw = datasets[name]
+    x = np.rint(x * 4)  # integers spanning well under 256 a dimension
+    want = OverlapIndex.build(x, _cfg(kw)).search(_queries(x), k=9, mode="all")
+    for layout in (LayoutConfig(), SHARDED4):
+        ix = OverlapIndex.build(x, _cfg(kw, quantize=True, layout=layout))
+        assert ix.device.bucket_offset is not None
+        got = ix.search(_queries(x), k=9, mode="all")
+        _assert_same_results(got, want, what=f"{name}/{layout.kind}")
+        if layout.kind == "single":
+            for stat in want.stats:
+                np.testing.assert_array_equal(got.stats[stat], want.stats[stat])
+
+
 def test_sharded_ingest_matches_single_with_capacity_rejects(pair, datasets):
     # capacity 16 + batches up to 64: ragged power-of-two padding, chunking,
     # AND the capacity-reject -> forced-rebuild -> retry loop all fire; both

@@ -77,16 +77,16 @@ def test_bucket_scan_compiles(one_chip, dtype, dim, k):
     nb, cap = DB1_BUCKETS, DB1_CAPACITY
     quantized = dtype == jnp.int8
 
-    def step(q, bx, ids, bsel, act, top_d, top_i, *scale):
-        bx, ids, sc = prepad_buckets(bx, ids, *scale)
-        return bucket_scan_topk_pallas(q, bx, ids, bsel, act, top_d, top_i, sc)
+    def step(q, bx, ids, bsel, act, top_d, top_i, *dequant):
+        bx, ids, sc, off = prepad_buckets(bx, ids, *dequant)
+        return bucket_scan_topk_pallas(q, bx, ids, bsel, act, top_d, top_i, sc, off)
 
     shapes = [
         ((QUERIES, dim), jnp.float32), ((nb, cap, dim), dtype),
         ((nb, cap), jnp.int32), ((QUERIES, 1), jnp.int32),
         ((QUERIES, 1), jnp.bool_), ((QUERIES, k), jnp.float32),
         ((QUERIES, k), jnp.int32),
-    ] + ([((nb, cap), jnp.float32)] if quantized else [])
+    ] + ([((nb, cap), jnp.float32), ((dim,), jnp.float32)] if quantized else [])
     _compile(step, *shapes, sharding=one_chip)
 
 
@@ -97,7 +97,7 @@ def test_bucket_scan_compiles_with_every_row_inactive(one_chip, dim):
     nb, cap = DB1_BUCKETS, DB1_CAPACITY
 
     def step(q, bx, ids, bsel, top_d, top_i):
-        bx, ids, _ = prepad_buckets(bx, ids)
+        bx, ids, _, _ = prepad_buckets(bx, ids)
         act = jnp.zeros(bsel.shape, jnp.bool_)
         return bucket_scan_topk_pallas(q, bx, ids, bsel, act, top_d, top_i)
 
@@ -111,32 +111,40 @@ def test_bucket_scan_compiles_with_every_row_inactive(one_chip, dim):
 
 
 @pytest.mark.parametrize(
-    "nb,cap,dim,k,own_limit",
+    "nb,cap,dim,k,dtype,own_limit",
     [
-        (1_634, 707, 5, 10, False),  # the WARD forest (500,000 x 5, c_max 707)
-        (1_785, 632, 128, 10, False),  # the SIFT forest (400,000 x 128, DBM, c_max 632)
-        (64, 1_000, 128, 100, True),  # the largest tile the tests build
+        # the WARD forest (500,000 x 5, c_max 707)
+        (1_634, 707, 5, 10, jnp.float32, False),
+        # the SIFT forest (400,000 x 128, DBM, c_max 632)
+        (1_785, 632, 128, 10, jnp.float32, False),
+        (64, 1_000, 128, 100, jnp.float32, True),  # the largest tile the tests build
+        # an int8 SPACEV forest (400,000 x 100 bytes, c_max 632)
+        (1_785, 632, 100, 10, jnp.int8, False),
     ],
-    ids=["ward", "sift", "d128-c1000"],
+    ids=["ward", "sift", "d128-c1000", "spacev-int8"],
 )
-def test_bucket_scan_compiles_at_forest_shapes(one_chip, nb, cap, dim, k, own_limit):
+def test_bucket_scan_compiles_at_forest_shapes(one_chip, nb, cap, dim, k, dtype, own_limit):
     """Each program holds the 8 tiles of its rows, double-buffered; where
     that outgrows the default scoped VMEM the call sets its own limit."""
     from repro.kernels import bucket_scan
 
-    def step(q, bx, ids, bsel, act, top_d, top_i):
-        bx, ids, _ = prepad_buckets(bx, ids)
-        return bucket_scan_topk_pallas(q, bx, ids, bsel, act, top_d, top_i)
+    quantized = dtype == jnp.int8
+
+    def step(q, bx, ids, bsel, act, top_d, top_i, *dequant):
+        bx, ids, sc, off = prepad_buckets(bx, ids, *dequant)
+        return bucket_scan_topk_pallas(q, bx, ids, bsel, act, top_d, top_i, sc, off)
 
     cp, dp = -(-cap // 128) * 128, -(-dim // 128) * 128
-    limit = bucket_scan._vmem_limit(cp, dp, -(-k // 128) * 128, 4, False)
+    itemsize = jnp.dtype(dtype).itemsize
+    limit = bucket_scan._vmem_limit(cp, dp, -(-k // 128) * 128, itemsize, quantized)
     assert (limit is not None) == own_limit
     _compile(
         step,
-        ((QUERIES, dim), jnp.float32), ((nb, cap, dim), jnp.float32),
+        ((QUERIES, dim), jnp.float32), ((nb, cap, dim), dtype),
         ((nb, cap), jnp.int32), ((QUERIES, 1), jnp.int32),
         ((QUERIES, 1), jnp.bool_), ((QUERIES, k), jnp.float32),
         ((QUERIES, k), jnp.int32),
+        *([((nb, cap), jnp.float32), ((dim,), jnp.float32)] if quantized else []),
         sharding=one_chip,
     )
 
